@@ -12,10 +12,6 @@ from __future__ import annotations
 
 __version__ = "0.1.0"
 
-# jax version shims (installs jax.shard_map on 0.4.x) — must run before
-# any submodule does `from jax import shard_map` at module scope
-from . import _jax_compat  # noqa: F401
-
 # core
 from .core import dtype as _dtype_mod
 from .core.dtype import (float16, bfloat16, float32, float64, int8, int16,

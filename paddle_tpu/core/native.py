@@ -20,6 +20,7 @@ from __future__ import annotations
 import ctypes
 import os
 import subprocess
+import sys
 import threading
 
 _LIB = None
@@ -33,12 +34,19 @@ def _src_path() -> str:
 
 
 def _lib_path() -> str:
+    """The built library, named by the source's content hash: only a
+    build of THIS csrc/runtime.cc is ever loaded — a .so left in the
+    checkout by another tree or toolchain is not an input."""
+    import hashlib
+    with open(_src_path(), "rb") as f:
+        tag = hashlib.sha256(f.read()).hexdigest()[:12]
     return os.path.join(os.path.dirname(_src_path()),
-                        "libpaddle_tpu_runtime.so")
+                        f"libpaddle_tpu_runtime-{tag}.so")
 
 
 def load_native():
-    """Build (once) and dlopen the runtime; None if unavailable."""
+    """Build (once) and dlopen the runtime; None if unavailable — said
+    once on stderr, so a missing toolchain is not a silent downgrade."""
     global _LIB, _BUILD_FAILED
     if _LIB is not None:
         return _LIB
@@ -47,12 +55,11 @@ def load_native():
     with _LIB_LOCK:
         if _LIB is not None:
             return _LIB
-        src, lib = _src_path(), _lib_path()
         try:
-            if (not os.path.exists(lib)
-                    or os.path.getmtime(lib) < os.path.getmtime(src)):
+            src, lib = _src_path(), _lib_path()
+            if not os.path.exists(lib):
                 # pid-unique scratch: concurrently-launched ranks all see
-                # the stale .so and rebuild; a shared ".tmp" makes them
+                # the missing .so and build; a shared ".tmp" makes them
                 # clobber each other's half-written output (os.replace of
                 # a file another rank is still writing), taking the
                 # native runtime down for the whole job
@@ -67,8 +74,15 @@ def load_native():
                     if os.path.exists(tmp):
                         os.remove(tmp)
             L = ctypes.CDLL(lib)
-        except Exception:
+        except Exception as e:
             _BUILD_FAILED = True
+            detail = getattr(e, "stderr", None)
+            detail = detail.decode(errors="replace").strip()[-400:] \
+                if detail else ""
+            print(f"paddle_tpu: native runtime unavailable "
+                  f"({type(e).__name__}: {e}) {detail}— building "
+                  "csrc/runtime.cc needs g++; pure-Python fallbacks "
+                  "take over", file=sys.stderr)
             return None
         # signatures
         L.pd_store_master_start.restype = ctypes.c_void_p

@@ -7,6 +7,11 @@ contract, captures per-rank logs (workerlog.N), restarts on failure up to
 
 TPU-native: one process per HOST (not per chip) — inside each process JAX owns
 all local chips; rendezvous is the JAX coordination service, not TCPStore.
+A chip belongs to one process at a time and the launcher does not partition
+chips between children, so --nproc_per_node > 1 is refused on a TPU host
+(it stays the way to run CPU ranks: JAX_PLATFORMS=cpu). The launcher itself
+never initialises a JAX backend: a parent that has would hold the chips its
+child needs.
 
 Gang supervision (SURVEY §5.3 failure detection): children are POLLED, not
 serially wait()ed — the first non-zero exit (a crash, or a watchdog-initiated
@@ -28,6 +33,28 @@ import sys
 import time
 
 from ..logjson import log_event
+
+
+def _local_tpu_present() -> bool:
+    """TPU device nodes on this host, found WITHOUT touching JAX."""
+    import glob
+    return bool(glob.glob("/dev/accel[0-9]*")
+                or glob.glob("/dev/vfio/[0-9]*"))
+
+
+def _refuse_shared_chips(nproc_per_node: int, env) -> str | None:
+    """The refusal message when several children of one host would each
+    try to claim the same TPU chips (all but one fail or hang), else
+    None. Children pinned off the TPU (JAX_PLATFORMS=cpu) are fine."""
+    if nproc_per_node <= 1 or env.get("JAX_PLATFORMS", "") == "cpu" \
+            or not _local_tpu_present():
+        return None
+    return (f"paddle_tpu.distributed.launch: --nproc_per_node="
+            f"{nproc_per_node} on a TPU host: a chip belongs to ONE "
+            "process at a time and the launcher does not partition chips "
+            "between children. One process driving all local chips "
+            "(--nproc_per_node 1, the default) is the supported "
+            "single-host mode; for CPU ranks set JAX_PLATFORMS=cpu.")
 
 
 def _free_port():
@@ -190,13 +217,18 @@ def main():
         default=float(os.environ.get("PADDLE_LAUNCH_GRACE_S", "5")),
         help="SIGTERM->SIGKILL grace when tearing down a failed gang")
     parser.add_argument("--devices", "--gpus", default=None,
-                        help="accepted for reference-CLI parity; device "
-                             "placement is XLA-managed")
+                        help="accepted for reference-CLI parity and "
+                             "ignored: each process owns all the chips "
+                             "it can see")
     parser.add_argument("--job_id", default="default")
     parser.add_argument("training_script")
     parser.add_argument("training_script_args", nargs=argparse.REMAINDER)
     args = parser.parse_args()
 
+    refusal = _refuse_shared_chips(args.nproc_per_node, os.environ)
+    if refusal:
+        print(refusal, file=sys.stderr)
+        sys.exit(2)
     os.makedirs(args.log_dir, exist_ok=True)
     poll_s = float(os.environ.get("PADDLE_LAUNCH_POLL_S", "0.2"))
     backoff_cap = float(os.environ.get("PADDLE_RESTART_BACKOFF_MAX_S", "30"))

@@ -69,11 +69,12 @@ def fused_feedforward(x, linear1_weight, linear2_weight, linear1_bias=None,
     # inference — not inert)
     drop_inert = (dropout1_rate == 0.0 and dropout2_rate == 0.0) or (
         not training and mode == "upscale_in_train")
-    from ...parallel import no_mp_mesh   # mesh query only, no pallas
+    # mesh query only, no pallas import
+    from ...parallel import no_multi_device_mesh
     if (os.environ.get("PADDLE_TPU_FUSED_FFN") == "1"
             and activation == "gelu" and drop_inert
             and linear1_bias is not None and linear2_bias is not None
-            and no_mp_mesh()):   # pallas_call is an SPMD barrier
+            and no_multi_device_mesh()):  # pallas can't auto-partition
         from ...ops.pallas.fused_ffn import fused_ffn
         out = apply_op(lambda a, w1, b1, w2, b2: fused_ffn(
             a, w1, b1, w2, b2, "gelu"), x, linear1_weight, linear1_bias,

@@ -945,7 +945,13 @@ def snapshot(engine):
                     "bytes_replicated": m["weight_bytes_replicated"],
                     "weight_quant": engine.dec._weight_quant_mode(),
                     "kv_quant": ("int8" if engine.dec._int8_cache()
-                                 else "none")},
+                                 else "none"),
+                    # which implementation the compiled step took at
+                    # each gate that can fall through to an XLA path
+                    # (FusedDecoder.step_paths; "" before the first
+                    # dispatch) — benchmarks assert on this instead of
+                    # inferring the path from env vars and shapes
+                    "step_paths": engine.dec.step_paths()},
         "spans_logged": len(tele.spans),
         "steps_logged": len(tele.steps),
         "telemetry_ring": tele.ring,

@@ -92,12 +92,12 @@ class GPTMLP(Layer):
             # pending the on-TPU A/B vs the XLA composite (LN lesson:
             # pallas_call is a fusion barrier — measure first). Guarded
             # like the llama fast paths: plain Linear layers only, and
-            # no model-parallel mesh — a pallas_call is an SPMD barrier
-            # that would force replication of sharded operands. The mesh
+            # no multi-device mesh — jax cannot auto-partition a
+            # pallas_call, whichever axis shards its operands. The mesh
             # query lives in ..parallel so the pallas import chain only
             # loads once the flag AND the guard pass.
-            from ..parallel import no_mp_mesh
-            if no_mp_mesh():
+            from ..parallel import no_multi_device_mesh
+            if no_multi_device_mesh():
                 from ..ops.pallas.fused_ffn import fused_ffn
                 from ..tensor.tensor import apply_op
                 return apply_op(fused_ffn, x, self.fc1.weight,

@@ -56,11 +56,47 @@ def _use_pallas(q_shape, k_shape, dtype) -> bool:
         return False
     if q_shape[2] % k_shape[2] != 0:   # GQA requires kv_heads | q_heads
         return False
-    try:
-        from ...ops.pallas import flash_attention as fa
-        return fa.is_supported(q_shape, dtype)
-    except Exception:
-        return False
+    # no blanket except: an import or gate error must surface, not
+    # silently downgrade every attention call to the O(S^2) composite
+    from ...ops.pallas import flash_attention as fa
+    return fa.is_supported(q_shape, dtype)
+
+
+def _per_shard(q_shape, k_shape):
+    """How the flash kernel runs under the active mesh. A pallas_call
+    cannot sit under GSPMD auto-partitioning — on more than one device jax
+    refuses to lower it ("Mosaic kernels cannot be automatically
+    partitioned"; interpret mode on a virtual CPU mesh hides this) — so
+    there the kernel runs PER SHARD through shard_map: attention is
+    independent across batch rows and heads, so the batch splits over the
+    data axes (dp x sharding) and the heads over 'mp', with no collective.
+    Returns a wrapper for ``kern(q, k, v, seed)``: the identity off-mesh,
+    the shard_map form on a mesh, or None when this layout cannot split
+    that way (pipeline/sequence axes in use, indivisible batch or heads)
+    and the caller takes the XLA composite."""
+    from ...parallel import current_mesh
+    mesh = current_mesh()
+    if mesh is None or mesh.devices.size == 1:
+        return lambda kern: kern
+    dims = dict(mesh.shape)
+    data, mp = dims["dp"] * dims["sharding"], dims["mp"]
+    if dims["pp"] > 1 or dims["sep"] > 1 or q_shape[0] % data \
+            or q_shape[2] % mp or k_shape[2] % mp:
+        return None
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+    spec = P(("dp", "sharding"), None, "mp", None)
+
+    def wrap(kern):
+        def body(q, k, v, seed):
+            # a different dropout stream on every shard
+            shard = jax.lax.axis_index(("dp", "sharding", "mp"))
+            return kern(q, k, v, seed + shard * 7919)
+        # check_vma=False: the kernel's out_shape carries no vma (same
+        # as the decode kernels under the serving mesh)
+        return shard_map(body, mesh=mesh, in_specs=(spec, spec, spec, P()),
+                         out_specs=spec, check_vma=False)
+    return wrap
 
 
 def scaled_dot_product_attention(query, key, value, attn_mask=None,
@@ -77,18 +113,24 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
     import os
     flash_drop_ok = drop_p == 0.0 or \
         os.environ.get("PADDLE_TPU_FLASH_DROPOUT", "1") != "0"
+    wrap = None
     if mask_arr is None and flash_drop_ok and \
             _use_pallas(tuple(query.shape), tuple(key.shape), query.dtype):
+        wrap = _per_shard(tuple(query.shape), tuple(key.shape))
+    if wrap is not None:
         from ...ops.pallas import flash_attention as fa
-        seed = None
+        seed = jnp.zeros((), jnp.int32)
         if drop_p > 0.0:
             import jax.random as jrandom
             seed = jrandom.randint(next_key(), (), 0, 2 ** 31 - 1,
                                    dtype=jnp.int32)
 
-        def f(q, k, v):
+        def kern(q, k, v, s):
             return fa.flash_attention(q, k, v, causal=is_causal,
-                                      dropout_p=drop_p, dropout_seed=seed)
+                                      dropout_p=drop_p, dropout_seed=s)
+
+        def f(q, k, v):
+            return wrap(kern)(q, k, v, seed)
         return apply_op(f, query, key, value)
 
     key_ = next_key() if drop_p > 0.0 else None

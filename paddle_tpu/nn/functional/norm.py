@@ -28,26 +28,28 @@ def _pallas_ln_ok(x, normalized_shape, weight, bias, need_bias=True) -> bool:
     the composite default + fused flash bwd in the same run; GPT-2
     (layer_norm) was neutral-to-positive. The kernels stay (capability
     parity for layer_norm_kernel.cu + direct callers/tests)."""
-    try:
-        import jax
-        import os
-        if os.environ.get("PADDLE_TPU_PALLAS_LN") != "1" and \
-                os.environ.get("PADDLE_TPU_FORCE_PALLAS") != "1":
-            return False
-        if jax.default_backend() != "tpu" and \
-                os.environ.get("PADDLE_TPU_FORCE_PALLAS") != "1":
-            return False
-        from ...ops.pallas import layer_norm as pln
-        if len(tuple(normalized_shape)) != 1 or weight is None:
-            return False
-        if need_bias and bias is None:
-            return False
-        if weight.dtype != x.dtype or (bias is not None
-                                       and bias.dtype != x.dtype):
-            return False
-        return pln.is_supported(tuple(x.shape), x.dtype)
-    except Exception:
+    import jax
+    import os
+    if os.environ.get("PADDLE_TPU_PALLAS_LN") != "1" and \
+            os.environ.get("PADDLE_TPU_FORCE_PALLAS") != "1":
         return False
+    if jax.default_backend() != "tpu" and \
+            os.environ.get("PADDLE_TPU_FORCE_PALLAS") != "1":
+        return False
+    from ...parallel import no_multi_device_mesh
+    # a pallas_call cannot be auto-partitioned
+    if not no_multi_device_mesh():
+        return False
+    # an import or gate error raises (no silent composite downgrade)
+    from ...ops.pallas import layer_norm as pln
+    if len(tuple(normalized_shape)) != 1 or weight is None:
+        return False
+    if need_bias and bias is None:
+        return False
+    if weight.dtype != x.dtype or (bias is not None
+                                   and bias.dtype != x.dtype):
+        return False
+    return pln.is_supported(tuple(x.shape), x.dtype)
 
 
 def layer_norm(x, normalized_shape, weight=None, bias=None, epsilon=1e-5,

@@ -72,6 +72,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import pallas as _pallas
+
 __all__ = ["decode_attention", "decode_attention_stacked",
            "decode_attention_stacked_i8", "decode_attention_stacked_write",
            "decode_attention_stacked_i8_write",
@@ -84,10 +86,6 @@ __all__ = ["decode_attention", "decode_attention_stacked",
            "paged_flat_i8_is_supported", "FLAT_CHUNK"]
 
 NEG_INF = -1e30
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def is_supported(q_shape, cache_shape, dtype) -> bool:
@@ -251,7 +249,7 @@ def decode_attention_bhsd(qt, kt, vt, cache_lens, scale=None):
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, h, bq, d), qt.dtype),
-        interpret=_interpret(),
+        interpret=_pallas._interpret(),
     )(lens, qt, kt, vt)
     return out[:, :, :sq]
 
@@ -416,7 +414,7 @@ def decode_attention_stacked(qt, caches, layer, cache_lens, scale=None):
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, h, bq, d), caches.dtype),
-        interpret=_interpret(),
+        interpret=_pallas._interpret(),
     )(lay, lens, qt, caches)
     return out[:, :, :sq].astype(out_dtype)
 
@@ -518,7 +516,7 @@ def decode_attention_stacked_i8(qt, caches_i8, cache_scales, layer,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, h, bq, d), out_dtype),
-        interpret=_interpret(),
+        interpret=_pallas._interpret(),
     )(lay, lens, qt, caches_i8, cache_scales)
     return out[:, :, :sq]
 
@@ -556,8 +554,12 @@ def _stacked_write_kernel(lay_ref, len_ref, q_ref, kvn_ref, kv_ref,
         # seed the running flash stats with the NEW token's own column
         # (its k/v ride in via kvn_ref — the cache block's bytes at the
         # write slot are stale until this kernel writes them)
-        q = q_ref[0, 0]                                  # [bq, d]
-        kn = kvn_ref[0, 0, 0, 0]                         # [1, d]
+        # fp32 operands: Mosaic lowers the one-row rhs as a broadcast,
+        # which must not change element type (bf16 -> f32 fails to
+        # verify); bf16 products are exact in fp32, so the score is the
+        # same number the bf16 dot accumulates
+        q = q_ref[0, 0].astype(jnp.float32)              # [bq, d]
+        kn = kvn_ref[0, 0, 0, 0].astype(jnp.float32)     # [1, d]
         vn = kvn_ref[0, 1, 0, 0]                         # [1, d]
         s = jax.lax.dot_general(q, kn, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
@@ -692,7 +694,7 @@ def decode_attention_stacked_write(qt, kv_new, caches, layer, cache_lens,
             jax.ShapeDtypeStruct((b, h, bq, d), out_dtype),
         ],
         input_output_aliases={4: 0},   # caches operand -> caches output
-        interpret=_interpret(),
+        interpret=_pallas._interpret(),
     )(lay, lens, qt, kv_new.astype(caches.dtype), caches)
     return caches_out, out[:, :, :sq].astype(out_dtype)
 
@@ -727,10 +729,15 @@ def _stacked_i8_write_kernel(lay_ref, len_ref, q_ref, kvn_ref, kv_ref,
         # values in the query dtype (all of [-127, 127] is exact in
         # bf16), apply the k scale to the SCORE, fold the v scale into p
         # and cast p to the operand dtype before the v dot
+        # The two seed dots have a ONE-row operand, which Mosaic lowers
+        # as a broadcast that must not change element type — so they
+        # take fp32 operands. Same numbers: q and p are rounded to the
+        # query dtype first, and their products with the int values
+        # are exact in fp32 either way.
         q = q_ref[0, 0]                                  # [bq, d]
         kq, ksc = _quant(kvn_ref[0, 0, 0, 0])
         vq, vsc = _quant(kvn_ref[0, 1, 0, 0])
-        s = jax.lax.dot_general(q, kq.astype(q.dtype),
+        s = jax.lax.dot_general(q.astype(jnp.float32), kq,
                                 (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) \
             * scale * ksc
@@ -740,7 +747,7 @@ def _stacked_i8_write_kernel(lay_ref, len_ref, q_ref, kvn_ref, kv_ref,
         l_sc[:] = jnp.where(valid, 1.0, 0.0)
         pv = (jnp.where(valid, 1.0, 0.0) * vsc).astype(q.dtype)
         acc_sc[:] = jax.lax.dot_general(
-            pv, vq.astype(q.dtype), (((1,), (0,)), ((), ())),
+            pv.astype(jnp.float32), vq, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
     k_start = ki * bk
@@ -869,7 +876,7 @@ def decode_attention_stacked_i8_write(qt, kv_new, caches_i8, cache_scales,
             jax.ShapeDtypeStruct((b, h, bq, d), out_dtype),
         ],
         input_output_aliases={4: 0, 5: 1},
-        interpret=_interpret(),
+        interpret=_pallas._interpret(),
     )(lay, lens, qt, kv_new.astype(jnp.float32), caches_i8, cache_scales)
     return caches_out, scales_out, out[:, :, :sq].astype(out_dtype)
 
@@ -1042,7 +1049,7 @@ def decode_attention_paged(qt, pool, tables, layer, cache_lens,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, h, bq, d), pool.dtype),
-        interpret=_interpret(),
+        interpret=_pallas._interpret(),
     )(lay, lens, tbl, qt, pool)
     return out[:, :, :sq].astype(out_dtype)
 
@@ -1131,7 +1138,7 @@ def decode_attention_paged_i8(qt, pool_i8, pool_scales, tables, layer,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, h, bq, d), out_dtype),
-        interpret=_interpret(),
+        interpret=_pallas._interpret(),
     )(lay, lens, tbl, qt, pool_i8, pool_scales)
     return out[:, :, :sq]
 
@@ -1291,7 +1298,7 @@ def decode_attention_paged_flat(q, pool, tables, chunk_slot, chunk_base,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((h, t, d), pool.dtype),
-        interpret=_interpret(),
+        interpret=_pallas._interpret(),
     )(lay, chunk_slot.astype(jnp.int32), chunk_base.astype(jnp.int32),
       chunk_n.astype(jnp.int32), tables.astype(jnp.int32), qt, pool)
     return jnp.swapaxes(out, 0, 1).astype(out_dtype)
@@ -1422,7 +1429,7 @@ def decode_attention_paged_flat_i8(q, pool_i8, pool_scales, tables,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((h, t, d), out_dtype),
-        interpret=_interpret(),
+        interpret=_pallas._interpret(),
     )(lay, chunk_slot.astype(jnp.int32), chunk_base.astype(jnp.int32),
       chunk_n.astype(jnp.int32), tables.astype(jnp.int32), qt, pool_i8,
       pool_scales)

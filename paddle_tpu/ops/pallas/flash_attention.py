@@ -30,13 +30,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import pallas as _pallas
+
 __all__ = ["flash_attention", "is_supported"]
 
 NEG_INF = -1e30  # large-negative instead of -inf: keeps exp()/max() NaN-free
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def is_supported(q_shape, dtype) -> bool:
@@ -236,7 +234,7 @@ def _fwd(q, k, v, drop=None, *, causal, scale, bq, bk):
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=_pallas._interpret(),
     )(*args)
     return o[:, :, :sq], lse[:, :, :sq]        # lse: [B, H, Sq, 1]
 
@@ -483,7 +481,7 @@ def _bwd_fused(q_, k_, v_, do_, lse_, delta_, drop, drop_arg, *,
             jax.ShapeDtypeStruct((b, h, sk_p, d), jnp.float32),
             jax.ShapeDtypeStruct((b, h, sk_p, d), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=_pallas._interpret(),
     )(*args)
     return dq, dk, dv
 
@@ -570,7 +568,7 @@ def _bwd(q, k, v, o, lse, do, drop=None, *, causal, scale, bq, bk):
             pltpu.VMEM((bk, d), jnp.float32),
             pltpu.VMEM((bk, d), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=_pallas._interpret(),
     )(*dkv_args)
 
     qspec2 = pl.BlockSpec((1, 1, bq, d), lambda b_, h_, i, j: (b_, h_, i, 0))
@@ -596,7 +594,7 @@ def _bwd(q, k, v, o, lse, do, drop=None, *, causal, scale, bq, bk):
                                lambda b_, h_, i, j: (b_, h_, i, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, sq_p, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        interpret=_interpret(),
+        interpret=_pallas._interpret(),
     )(*dq_args)
 
     dq = dq[:, :, :sq]
@@ -638,7 +636,7 @@ def _make_drop(q, k, seed, dropout_p):
     seed-regenerated mask array (prng_* primitives have no CPU lowering)."""
     if dropout_p <= 0.0:
         return None
-    if not _interpret():
+    if not _pallas._interpret():
         return ("prng", seed, dropout_p)
     bq, bk, sq_p, sk_p = _padded_sizes(q.shape[2], k.shape[2])
     return ("mask",

@@ -29,32 +29,53 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import pallas as _pallas
+
 __all__ = ["fused_dequant_matmul", "fused_dequant_matmul_is_supported"]
 
 # fp32 sublane minimum for the activation block / output tile
 _SUBLANE = 8
 
 
-def _interpret() -> bool:
-    """Pallas interpret mode everywhere but real TPU (same gate as
-    decode_attention — CPU/GPU CI runs the kernel through the
-    interpreter, so tests exercise the identical code path)."""
-    return jax.default_backend() != "tpu"
+def _k_block(k2):
+    """Packed-axis block: the largest of 256/128/64/32 that tiles K/2
+    (the whole axis when none does)."""
+    for cand in (256, 128, 64, 32):
+        if k2 > cand and k2 % cand == 0:
+            return cand
+    return k2
 
 
-def fused_dequant_matmul_is_supported(m, k, o) -> bool:
+# the kernel holds whole-M x whole-O tiles; Mosaic's scoped VMEM limit on
+# v5e is 16 MiB (the compiler reports it). The estimate in the gate below
+# misses some compiler temporaries: over a 100-shape sweep against the
+# v5e compiler, 10 MiB is where a yes was always a compile
+_VMEM_BUDGET = 10 << 20
+
+
+def fused_dequant_matmul_is_supported(m, k, o, itemsize=2) -> bool:
     """Whether the fused kernel can serve an [m, k] @ [k, o] contraction
     with the weight int4-packed along k. The pack itself only needs an
     even k; on real TPU the packed sublane axis additionally wants the
-    int8 sublane minimum (K/2 % 32) and a lane-aligned out axis
-    (O % 128). Interpret mode (CPU CI) has no tiling constraint."""
+    int8 sublane minimum (K/2 % 32), a lane-aligned out axis (O % 128)
+    and tiles that fit VMEM: the fp32 accumulator and the output tile
+    ([M, O] each, whole), the widened weight block ([bk2, O] int32) and
+    the double-buffered activation halves. Interpret mode (CPU CI) has
+    none of these constraints. A yes is checked against the real
+    compiler in tests/test_chip_compile.py."""
     if k % 2:
         return False
     if m <= 0 or o <= 0:
         return False
-    if _interpret():
+    if _pallas._interpret():
         return True
-    return (k // 2) % 32 == 0 and o % 128 == 0
+    k2 = k // 2
+    if k2 % 32 or o % 128:
+        return False
+    mp = -(-m // _SUBLANE) * _SUBLANE
+    bk2 = _k_block(k2)
+    vmem = mp * o * (4 + itemsize) + 4 * bk2 * o + 4 * mp * bk2 * itemsize
+    return vmem <= _VMEM_BUDGET
 
 
 def _fused_dequant_mm_kernel(ae_ref, ao_ref, w_ref, s_ref, o_ref, acc_sc,
@@ -65,9 +86,11 @@ def _fused_dequant_mm_kernel(ae_ref, ao_ref, w_ref, s_ref, o_ref, acc_sc,
     def _():
         acc_sc[:] = jnp.zeros_like(acc_sc)
 
-    w = w_ref[...]                                   # [bk2, O] int8 packed
-    # sign-extending nibble unpack: arithmetic shifts on int8
-    lo = jnp.right_shift(jnp.left_shift(w, 4), 4)    # even k
+    # sign-extending nibble unpack. Widen FIRST: Mosaic on v5e has no
+    # int8 vector shift (arith.shli on vector<...xi8> fails to legalize),
+    # and on int32 the same arithmetic shifts give the same values
+    w = w_ref[...].astype(jnp.int32)                 # [bk2, O] packed
+    lo = jnp.right_shift(jnp.left_shift(w, 28), 28)  # even k
     hi = jnp.right_shift(w, 4)                       # odd k
     ae = ae_ref[...].astype(jnp.float32)
     ao = ao_ref[...].astype(jnp.float32)
@@ -116,11 +139,7 @@ def fused_dequant_matmul(a, w_packed, scales, *, out_dtype=None):
     a_even = a2[:, 0::2]                             # [mp, K2]
     a_odd = a2[:, 1::2]                              # [mp, K2]
 
-    bk2 = k2
-    for cand in (256, 128, 64, 32):
-        if k2 > cand and k2 % cand == 0:
-            bk2 = cand
-            break
+    bk2 = _k_block(k2)
     nk = k2 // bk2
 
     out = pl.pallas_call(
@@ -135,7 +154,7 @@ def fused_dequant_matmul(a, w_packed, scales, *, out_dtype=None):
         out_specs=pl.BlockSpec((mp, o), lambda ki: (0, 0)),
         scratch_shapes=[pltpu.VMEM((mp, o), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct((mp, o), out_dtype),
-        interpret=_interpret(),
+        interpret=_pallas._interpret(),
     )(a_even, a_odd, w_packed, s2)
     if mp != m:
         out = out[:m]

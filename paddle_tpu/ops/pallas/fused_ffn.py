@@ -27,11 +27,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import pallas as _pallas
+
 __all__ = ["fused_ffn", "ffn_is_supported"]
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _gelu_tanh(x):
@@ -100,7 +98,7 @@ def _fwd_kernel_call(x, w1, b1, w2, b2, bm, bf, act):
         out_specs=pl.BlockSpec((bm, k), lambda mi, fi: (mi, 0)),
         scratch_shapes=[pltpu.VMEM((bm, k), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct((m, k), x.dtype),
-        interpret=_interpret(),
+        interpret=_pallas._interpret(),
     )(x, w1, b1.reshape(1, f), w2, b2.reshape(1, k))
 
 
@@ -283,7 +281,7 @@ def _bwd_kernel_calls(x2, g2, w1, b1, w2, bm_dx, bm_dw, bf, act):
         out_specs=pl.BlockSpec((bm, k), lambda mi, fi: (mi, 0)),
         scratch_shapes=[pltpu.VMEM((bm, k), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct((m, k), x2.dtype),
-        interpret=_interpret(),
+        interpret=_pallas._interpret(),
     )(x2, g2, w1, b1r, w2)
     bm, nm = bm_dw, m // bm_dw
     dw1, dw2, db1 = pl.pallas_call(
@@ -307,7 +305,7 @@ def _bwd_kernel_calls(x2, g2, w1, b1, w2, bm_dx, bm_dw, bf, act):
         out_shape=[jax.ShapeDtypeStruct((k, f), w1.dtype),
                    jax.ShapeDtypeStruct((f, k), w2.dtype),
                    jax.ShapeDtypeStruct((1, f), jnp.float32)],
-        interpret=_interpret(),
+        interpret=_pallas._interpret(),
     )(x2, g2, w1, b1r, w2)
     return dx, dw1, dw2, db1.reshape(f)
 

@@ -17,11 +17,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .. import pallas as _pallas
+
 __all__ = ["layer_norm", "rms_norm", "is_supported"]
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def is_supported(shape, dtype) -> bool:
@@ -106,7 +104,7 @@ def _ln_fwd(x2, gamma, beta, eps):
             jax.ShapeDtypeStruct((n, 1), jnp.float32),
             jax.ShapeDtypeStruct((n, 1), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=_pallas._interpret(),
     )(x2, gamma[None, :], beta[None, :])
     return y, mean, rstd
 
@@ -146,7 +144,7 @@ def _ln_vjp_bwd(eps, res, dy):
             jax.ShapeDtypeStruct((nb, 1, d), jnp.float32),
             jax.ShapeDtypeStruct((nb, 1, d), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=_pallas._interpret(),
     )(x2, gamma[None, :], mean, rstd, dy)
     dgamma = jnp.sum(dg_part, axis=(0, 1)).astype(gamma.dtype)
     dbeta = jnp.sum(db_part, axis=(0, 1)).astype(gamma.dtype)
@@ -212,7 +210,7 @@ def _rms_fwd(x2, gamma, eps):
             jax.ShapeDtypeStruct((n, d), x2.dtype),
             jax.ShapeDtypeStruct((n, 1), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=_pallas._interpret(),
     )(x2, gamma[None, :])
     return y, rstd
 
@@ -244,7 +242,7 @@ def _rms_vjp_bwd(eps, res, dy):
             jax.ShapeDtypeStruct((n, d), x2.dtype),
             jax.ShapeDtypeStruct((nb, 1, d), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=_pallas._interpret(),
     )(x2, gamma[None, :], rstd, dy)
     return dx, jnp.sum(dg_part, axis=(0, 1)).astype(gamma.dtype)
 

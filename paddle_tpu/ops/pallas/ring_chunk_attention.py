@@ -36,7 +36,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import NEG_INF, _block_sizes, _interpret
+from .. import pallas as _pallas
+from .flash_attention import NEG_INF, _block_sizes
 
 __all__ = ["ring_chunk_attention", "is_supported"]
 
@@ -255,7 +256,7 @@ def _fwd(q, k, v, offset, scale):
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=_pallas._interpret(),
     )(jnp.reshape(offset.astype(jnp.int32), (1,)), q_, k_, v_)
     return o[:, :, :sq], lse[:, :, :sq, 0]        # lse: [B, H, Sq]
 
@@ -314,7 +315,7 @@ def _vjp_bwd(scale, res, cts):
             pltpu.VMEM((bk, d), jnp.float32),
             pltpu.VMEM((bk, d), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=_pallas._interpret(),
     )(off, q_, k_, v_, do_, lse_, delta_)
 
     qspec, kspec, rowspec = _specs(bq, bk, d, group)
@@ -327,7 +328,7 @@ def _vjp_bwd(scale, res, cts):
         out_specs=qspec,
         out_shape=_sds((b, h, sq_p, d), q.dtype, q_, k_, v_, do_),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        interpret=_interpret(),
+        interpret=_pallas._interpret(),
     )(off, q_, k_, v_, do_, lse_, delta_)
 
     dq = dq[:, :, :sq]

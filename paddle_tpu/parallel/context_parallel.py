@@ -182,7 +182,7 @@ def ring_attention(q, k, v, axis_name: str = "sep", causal: bool = False,
     # initial accumulators must carry the same varying-over-axes type as the
     # per-step outputs (jax>=0.8 vma typing inside shard_map); deriving them
     # from q inherits q's full vma set (e.g. (pp, sep) when nested inside a
-    # pipeline shard_map), which a bare pvary over axis_name would not
+    # pipeline shard_map), which a bare pcast over axis_name would not
     zero_q = q.astype(jnp.float32) * 0.0
     o0 = zero_q
     lse0 = jnp.swapaxes(zero_q[..., 0], 1, 2) + _NEG_INF   # [B,H,Sq]

@@ -37,11 +37,8 @@ __all__ = ["gpipe", "gpipe_interleaved", "make_gpipe_fn", "microbatch",
 
 
 def _pvary(x, axis_name):
-    """Mark x as varying over axis_name (pcast where available; pvary on
-    older jax)."""
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, (axis_name,), to="varying")
-    return jax.lax.pvary(x, (axis_name,))
+    """Mark x as varying over axis_name."""
+    return jax.lax.pcast(x, (axis_name,), to="varying")
 
 
 def microbatch(x, num_micro: int):

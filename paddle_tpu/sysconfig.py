@@ -17,6 +17,6 @@ def get_include() -> str:
 
 def get_lib() -> str:
     """Directory containing the framework's compiled shared libraries
-    (libpaddle_tpu_runtime.so is built on demand next to its source — see
+    (libpaddle_tpu_runtime-<hash>.so is built on demand next to its source — see
     paddle_tpu/core/native.py::_lib_path)."""
     return os.path.join(os.path.dirname(_PKG), "csrc")

@@ -1,0 +1,401 @@
+"""AOT-compile the main-path Pallas kernels for a DESCRIBED TPU v5e.
+
+Interpret mode (what every other kernel test runs on the CPU) cannot see
+what the chip's compiler refuses: an int8 vector shift, a mis-typed
+broadcast, a block that overflows VMEM. The TPU compiler is installed in
+the sandbox and compiles for a topology that is described, not attached
+(on-chip-measurement guide, section 2.3), so one case per kernel entry
+point at the widths ``chip_smoke.py`` drives (GPT-2-124M serving: B 8,
+H 12, D 64, L 12, Smax 1024, bf16) guards every later PR at no chip time.
+
+A compile that passes is NOT a chip run: nothing executes here, so these
+cases say nothing about results or times.
+"""
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
+
+import functools
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+
+def _describe_topology():
+    try:
+        from jax.experimental import topologies
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu / unknown topology: skip, say why
+        return e
+
+
+_TOPO = _describe_topology()
+pytestmark = pytest.mark.skipif(
+    isinstance(_TOPO, Exception),
+    reason=f"cannot describe a v5e:2x2 topology here: {_TOPO!r}")
+
+# chip_smoke.py's serving widths
+B, H, D, L, SMAX = 8, 12, 64, 12, 1024
+BF = jnp.bfloat16
+F32 = jnp.float32
+I8 = jnp.int8
+I32 = jnp.int32
+
+
+@pytest.fixture(autouse=True)
+def _compile_for_the_chip(monkeypatch):
+    """Kernels lower through Mosaic (not the interpreter), and the
+    persistent compile cache stays off: an AOT TPU executable written
+    from the CPU cannot be read back and would warn on the next run."""
+    import paddle_tpu.ops.pallas as pallas
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    monkeypatch.setattr(pallas, "_interpret", lambda: False)
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _one(shape, dtype):
+    return jax.ShapeDtypeStruct(
+        shape, dtype, sharding=SingleDeviceSharding(_TOPO.devices[0]))
+
+
+def _mesh4(axis):
+    return Mesh(np.array(_TOPO.devices).reshape(4), (axis,))
+
+
+def _on(mesh, shape, dtype, *spec):
+    return jax.ShapeDtypeStruct(shape, dtype,
+                                sharding=NamedSharding(mesh, P(*spec)))
+
+
+def _compile(fn, *args):
+    """Lower + compile for the described chip; the kernel must be IN the
+    program (a stale interpret-mode trace would compile trivially)."""
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def _sum_grad(fn, argnums):
+    return jax.grad(lambda *a: jnp.sum(fn(*a).astype(F32)), argnums=argnums)
+
+
+# ------------------------------------------------------------ case builders
+def _pool(bt):
+    nb = B * (SMAX // bt)
+    return ((L, 2, nb, H, bt, D), (L, 2, nb, H, 1, bt), (B, SMAX // bt))
+
+
+def _paged(sq, bt):
+    from paddle_tpu.ops.pallas.decode_attention import decode_attention_paged
+    pool, _, tbl = _pool(bt)
+    return decode_attention_paged, (
+        _one((B, H, sq, D), BF), _one(pool, BF), _one(tbl, I32),
+        _one((), I32), _one((B,), I32))
+
+
+def _paged_i8(sq, bt):
+    from paddle_tpu.ops.pallas.decode_attention import (
+        decode_attention_paged_i8)
+    pool, sc, tbl = _pool(bt)
+    return decode_attention_paged_i8, (
+        _one((B, H, sq, D), BF), _one(pool, I8), _one(sc, F32),
+        _one(tbl, I32), _one((), I32), _one((B,), I32))
+
+
+def _flat_args(t, bt, quant):
+    from paddle_tpu.ops.pallas.decode_attention import FLAT_CHUNK
+    pool, sc, tbl = _pool(bt)
+    nc = t // FLAT_CHUNK
+    pools = ((_one(pool, I8), _one(sc, F32)) if quant
+             else (_one(pool, BF),))
+    return (_one((t, H, D), BF),) + pools + (
+        _one(tbl, I32), _one((nc,), I32), _one((nc,), I32),
+        _one((nc,), I32), _one((), I32))
+
+
+def _paged_flat(t, bt):
+    from paddle_tpu.ops.pallas.decode_attention import (
+        decode_attention_paged_flat)
+    return decode_attention_paged_flat, _flat_args(t, bt, False)
+
+
+def _paged_flat_i8(t, bt):
+    from paddle_tpu.ops.pallas.decode_attention import (
+        decode_attention_paged_flat_i8)
+    return decode_attention_paged_flat_i8, _flat_args(t, bt, True)
+
+
+_RING = (L, 2, B, H, SMAX, D)
+_RING_SC = (L, 2, B, H, 1, SMAX)
+
+
+def _stacked(sq):
+    from paddle_tpu.ops.pallas.decode_attention import (
+        decode_attention_stacked)
+    return decode_attention_stacked, (
+        _one((B, H, sq, D), BF), _one(_RING, BF), _one((), I32),
+        _one((B,), I32))
+
+
+def _stacked_i8(sq):
+    from paddle_tpu.ops.pallas.decode_attention import (
+        decode_attention_stacked_i8)
+    return decode_attention_stacked_i8, (
+        _one((B, H, sq, D), BF), _one(_RING, I8), _one(_RING_SC, F32),
+        _one((), I32), _one((B,), I32))
+
+
+def _stacked_write():
+    from paddle_tpu.ops.pallas.decode_attention import (
+        decode_attention_stacked_write)
+    return decode_attention_stacked_write, (
+        _one((B, H, 1, D), BF), _one((2, B, H, 1, D), BF),
+        _one(_RING, BF), _one((), I32), _one((B,), I32))
+
+
+def _stacked_i8_write():
+    from paddle_tpu.ops.pallas.decode_attention import (
+        decode_attention_stacked_i8_write)
+    return decode_attention_stacked_i8_write, (
+        _one((B, H, 1, D), BF), _one((2, B, H, 1, D), BF),
+        _one(_RING, I8), _one(_RING_SC, F32), _one((), I32),
+        _one((B,), I32))
+
+
+def _dense_decode():
+    from paddle_tpu.ops.pallas.decode_attention import decode_attention
+    return decode_attention, (
+        _one((B, 1, H, D), BF), _one((B, SMAX, H, D), BF),
+        _one((B, SMAX, H, D), BF), _one((B,), I32))
+
+
+_QKV = (B, 1024, H, D)     # the gpt2_124m train step: 8 x 1024 x 12 x 64
+
+
+def _flash(grad, dropout_p=0.0):
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention
+    fn = functools.partial(flash_attention, causal=True,
+                           dropout_p=dropout_p)
+    if grad:
+        fn = _sum_grad(fn, (0, 1, 2))
+    return fn, (_one(_QKV, BF),) * 3
+
+
+def _ffn(grad, monkeypatch):
+    from paddle_tpu.ops.pallas.fused_ffn import fused_ffn
+    fn = fused_ffn
+    if grad:
+        # the two-kernel backward is opt-in (the default backward is XLA
+        # matmuls); compile the kernels it would run
+        monkeypatch.setenv("PADDLE_TPU_FUSED_FFN_BWD", "1")
+        fn = _sum_grad(fused_ffn, (0, 1, 2, 3, 4))
+    return fn, (_one((8192, 768), BF), _one((768, 3072), BF),
+                _one((3072,), BF), _one((3072, 768), BF),
+                _one((768,), BF))
+
+
+def _layer_norm(grad):
+    from paddle_tpu.ops.pallas.layer_norm import layer_norm
+    fn = _sum_grad(layer_norm, (0, 1, 2)) if grad else layer_norm
+    return fn, (_one((8192, 768), BF), _one((768,), BF), _one((768,), BF))
+
+
+def _rms_norm(grad):
+    from paddle_tpu.ops.pallas.layer_norm import rms_norm
+    fn = _sum_grad(rms_norm, (0, 1)) if grad else rms_norm
+    return fn, (_one((8192, 1024), BF), _one((1024,), BF))
+
+
+def _ring_chunk(grad):
+    from paddle_tpu.ops.pallas.ring_chunk_attention import (
+        ring_chunk_attention)
+
+    def fwd(q, k, v, off):
+        return ring_chunk_attention(q, k, v, off)[0]
+    fn = _sum_grad(fwd, (0, 1, 2)) if grad else fwd
+    # one ring step at seq 4096 over 4 chips: local chunk 1024
+    return fn, (_one((1, 16, 1024, 64), BF),) * 3 + (_one((), I32),)
+
+
+def _dequant(m, k, o):
+    from paddle_tpu.ops.pallas.fused_dequant_matmul import (
+        fused_dequant_matmul)
+    return fused_dequant_matmul, (
+        _one((m, k), BF), _one((k // 2, o), I8), _one((1, o), F32))
+
+
+# --- shard_map forms: the mp=4 serving mesh splits the 12 heads 3 a chip
+def _mp_specs(mesh, quant, bt):
+    pool, sc, tbl = _pool(bt)
+    psp = (None, None, None, "mp", None, None)
+    pools = ((_on(mesh, pool, I8, *psp), _on(mesh, sc, F32, *psp))
+             if quant else (_on(mesh, pool, BF, *psp),))
+    return pools, _on(mesh, tbl, I32)
+
+
+def _paged_mp(sq, bt, quant):
+    from jax import shard_map
+    from paddle_tpu.ops.pallas import decode_attention as da
+    mesh = _mesh4("mp")
+    hsp = P(None, "mp", None, None)
+    psp = P(None, None, None, "mp", None, None)
+    pools, tbl = _mp_specs(mesh, quant, bt)
+    kern = (da.decode_attention_paged_i8 if quant
+            else da.decode_attention_paged)
+    fn = shard_map(kern, mesh=mesh,
+                   in_specs=(hsp,) + (psp,) * len(pools) + (P(), P(), P()),
+                   out_specs=hsp, check_vma=False)
+    return fn, (_on(mesh, (B, H, sq, D), BF, None, "mp"),) + pools + (
+        tbl, _on(mesh, (), I32), _on(mesh, (B,), I32))
+
+
+def _paged_flat_mp(t, bt, quant):
+    from jax import shard_map
+    from paddle_tpu.ops.pallas import decode_attention as da
+    mesh = _mesh4("mp")
+    qsp = P(None, "mp", None)
+    psp = P(None, None, None, "mp", None, None)
+    pools, tbl = _mp_specs(mesh, quant, bt)
+    kern = (da.decode_attention_paged_flat_i8 if quant
+            else da.decode_attention_paged_flat)
+    nc = t // da.FLAT_CHUNK
+    fn = shard_map(kern, mesh=mesh,
+                   in_specs=(qsp,) + (psp,) * len(pools) + (P(),) * 5,
+                   out_specs=qsp, check_vma=False)
+    meta = tuple(_on(mesh, (nc,), I32) for _ in range(3))
+    return fn, (_on(mesh, (t, H, D), BF, None, "mp"),) + pools + (
+        tbl,) + meta + (_on(mesh, (), I32),)
+
+
+def _stacked_mp(quant):
+    from jax import shard_map
+    from paddle_tpu.ops.pallas import decode_attention as da
+    mesh = _mesh4("mp")
+    hsp = P(None, "mp", None, None)
+    csp = (None, None, None, "mp", None, None)
+    caches = ((_on(mesh, _RING, I8, *csp), _on(mesh, _RING_SC, F32, *csp))
+              if quant else (_on(mesh, _RING, BF, *csp),))
+    kern = (da.decode_attention_stacked_i8 if quant
+            else da.decode_attention_stacked)
+    fn = shard_map(kern, mesh=mesh,
+                   in_specs=(hsp,) + (P(*csp),) * len(caches) + (P(), P()),
+                   out_specs=hsp, check_vma=False)
+    return fn, (_on(mesh, (B, H, 1, D), BF, None, "mp"),) + caches + (
+        _on(mesh, (), I32), _on(mesh, (B,), I32))
+
+
+def _flash_hybrid(monkeypatch):
+    # the training attention under the Fleet hybrid mesh (mp 2 x sharding
+    # 2): nn/functional/attention.py runs the kernel per shard, because
+    # jax refuses to auto-partition a Mosaic kernel
+    import paddle_tpu.parallel as parallel
+    from paddle_tpu.nn.functional.attention import _per_shard
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention
+    mesh = Mesh(np.array(_TOPO.devices).reshape(1, 1, 2, 1, 2),
+                ("pp", "dp", "sharding", "sep", "mp"))
+    monkeypatch.setattr(parallel, "current_mesh", lambda: mesh)
+    qkv = (8, 1024, 16, 64)
+    wrap = _per_shard(qkv, qkv)
+
+    def kern(q, k, v, seed):
+        return flash_attention(q, k, v, causal=True, dropout_seed=seed)
+    fn = _sum_grad(lambda q, k, v: wrap(kern)(q, k, v, jnp.int32(0)),
+                   (0, 1, 2))
+    return fn, (_on(mesh, qkv, BF, ("dp", "sharding"), None, "mp"),) * 3
+
+
+def _ring_sep(monkeypatch):
+    # the combined ring + kernel path: sequence 4096 over the 4-chip
+    # 'sep' axis; the existing opt-in makes the dispatcher take the
+    # kernel although jax.default_backend() is the CPU here
+    from paddle_tpu.parallel.context_parallel import make_ring_attention_fn
+    monkeypatch.setenv("PADDLE_TPU_RING_KERNEL_CPU", "1")
+    mesh = _mesh4("sep")
+    fn = make_ring_attention_fn(mesh, "sep", causal=True)
+    return fn, (_on(mesh, (1, 4096, 16, 64), BF, None, "sep"),) * 3
+
+
+_CASES = {
+    # the serving step's attention reads (default engine: Bt 64; the
+    # budget core attends a [B, 16] block, its decode tail one token)
+    "paged_sq1": lambda mp: _paged(1, 64),
+    "paged_sq16": lambda mp: _paged(16, 64),
+    "paged_sq64": lambda mp: _paged(64, 64),
+    "paged_i8_sq1": lambda mp: _paged_i8(1, 32),
+    "paged_i8_sq16": lambda mp: _paged_i8(16, 32),
+    "paged_flat_t128": lambda mp: _paged_flat(128, 64),
+    "paged_flat_i8_t128": lambda mp: _paged_flat_i8(128, 32),
+    # the dense-ring flavours (PADDLE_SERVING_PAGED=0 / generate())
+    "stacked_sq1": lambda mp: _stacked(1),
+    "stacked_sq16": lambda mp: _stacked(16),
+    "stacked_i8_sq1": lambda mp: _stacked_i8(1),
+    "stacked_write": lambda mp: _stacked_write(),
+    "stacked_i8_write": lambda mp: _stacked_i8_write(),
+    "decode_attention_dense": lambda mp: _dense_decode(),
+    # the gpt2_124m train step
+    "flash_fwd": lambda mp: _flash(False),
+    "flash_fwd_bwd": lambda mp: _flash(True),
+    "flash_fwd_bwd_dropout": lambda mp: _flash(True, 0.1),
+    "fused_ffn_fwd": lambda mp: _ffn(False, mp),
+    "fused_ffn_bwd": lambda mp: _ffn(True, mp),
+    "layer_norm_fwd": lambda mp: _layer_norm(False),
+    "layer_norm_fwd_bwd": lambda mp: _layer_norm(True),
+    "rms_norm_fwd": lambda mp: _rms_norm(False),
+    "rms_norm_fwd_bwd": lambda mp: _rms_norm(True),
+    "ring_chunk_fwd": lambda mp: _ring_chunk(False),
+    "ring_chunk_fwd_bwd": lambda mp: _ring_chunk(True),
+    # weight_quant="int4": the four matmuls of a layer, decode rows
+    # (M 8) and a budget block (M 8 x 16)
+    "dequant_qkv_m8": lambda mp: _dequant(8, 768, 2304),
+    "dequant_proj_m8": lambda mp: _dequant(8, 768, 768),
+    "dequant_ffn1_m8": lambda mp: _dequant(8, 768, 3072),
+    "dequant_ffn2_m8": lambda mp: _dequant(8, 3072, 768),
+    "dequant_ffn1_m128": lambda mp: _dequant(128, 768, 3072),
+    "dequant_ffn2_m128": lambda mp: _dequant(128, 3072, 768),
+    # shard_map forms over the described 4-chip mesh
+    "mp4_paged_sq1": lambda mp: _paged_mp(1, 64, False),
+    "mp4_paged_sq16": lambda mp: _paged_mp(16, 64, False),
+    "mp4_paged_i8_sq1": lambda mp: _paged_mp(1, 32, True),
+    "mp4_paged_flat_t128": lambda mp: _paged_flat_mp(128, 64, False),
+    "mp4_paged_flat_i8_t128": lambda mp: _paged_flat_mp(128, 32, True),
+    "mp4_stacked_sq1": lambda mp: _stacked_mp(False),
+    "mp4_stacked_i8_sq1": lambda mp: _stacked_mp(True),
+    "hybrid4_flash_fwd_bwd": _flash_hybrid,
+    "sep4_ring_attention": _ring_sep,
+}
+
+
+@pytest.mark.parametrize("name", list(_CASES))
+def test_kernel_compiles_for_v5e(name, monkeypatch):
+    fn, args = _CASES[name](monkeypatch)
+    _compile(fn, *args)
+
+
+@pytest.mark.parametrize("m,k,o,says", [
+    (512, 768, 2304, True), (1024, 3072, 768, True),
+    (1024, 768, 3072, False), (8, 768, 50304, False),
+])
+def test_fused_dequant_gate_tells_the_truth(m, k, o, says):
+    """Off interpret mode a yes from the gate is a promise that Mosaic
+    takes the shape (the largest yes shapes here); the no cases are ones
+    the compiler does refuse — whole-M x whole-O tiles outgrow VMEM."""
+    from paddle_tpu.ops.pallas.fused_dequant_matmul import (
+        fused_dequant_matmul_is_supported)
+    assert fused_dequant_matmul_is_supported(m, k, o) is says
+    fn, args = _dequant(m, k, o)
+    if says:
+        _compile(fn, *args)
+    else:
+        with pytest.raises(Exception, match="vmem"):
+            jax.jit(fn).lower(*args).compile()

@@ -475,135 +475,3 @@ class TestPrefixCacheServing:
             assert after[k] == fresh[k], (
                 f"reset_metrics missed {k}: {after[k]!r} != fresh "
                 f"{fresh[k]!r}")
-
-
-@pytest.mark.slow
-class TestServingBench:
-    def test_bench_serving_poisson_sweep(self, monkeypatch, capsys,
-                                         tmp_path):
-        """The Poisson workload sweep (continuous vs static batching on
-        the same compiled step). Slow-marked: tier-1 covers the engine
-        through the unit tests above; this drives the full bench."""
-        import json
-        import bench_serving
-        # the bench writes BENCH_serving.json next to its own file —
-        # point it at tmp so the committed record isn't clobbered by CI
-        monkeypatch.setattr(bench_serving, "__file__",
-                            str(tmp_path / "bench_serving.py"))
-        monkeypatch.setenv("BENCH_SERVE_REQUESTS", "12")
-        monkeypatch.setenv("BENCH_SERVE_WARMUP", "4")
-        monkeypatch.setenv("BENCH_SLOTS", "4")
-        rc = bench_serving.main()
-        assert rc == 0
-        rec = json.loads(
-            capsys.readouterr().out.strip().splitlines()[-1])
-        assert rec["retraces_after_warmup"] == 0
-        # timing-dependent: assert with margin below the 1.5x the full
-        # fixed-seed bench shows (12 requests here, CI jitter)
-        assert rec["speedup_vs_static"] > 1.1
-
-    def test_bench_shared_prompt_prefix_cache_sweep(self, monkeypatch,
-                                                    capsys, tmp_path):
-        """The Poisson shared-prompt sweep (prefix cache on vs off at
-        equal compiled shape). Slow-marked like the classic sweep: tier-1
-        covers the cache through the unit/parity tests; this drives the
-        full A/B bench and its acceptance gates (hit-rate, no retraces,
-        TTFT not worse)."""
-        import json
-        import bench_serving
-        monkeypatch.setattr(bench_serving, "__file__",
-                            str(tmp_path / "bench_serving.py"))
-        monkeypatch.setenv("BENCH_SERVE_REQUESTS", "12")
-        monkeypatch.setenv("BENCH_PREFIX_TEMPLATES", "3")
-        rc = bench_serving.main(["--shared-prompts"])
-        assert rc == 0
-        rec = json.loads(
-            capsys.readouterr().out.strip().splitlines()[-1])
-        assert rec["retraces_after_warmup"] == 0
-        assert rec["prefix_hit_rate"] > 0.5
-        assert rec["prefill_tokens_saved"] > \
-            rec["prefill_tokens_computed"]
-        # timing-dependent with margin (the full fixed-seed bench shows
-        # ~1.4x tokens/s and ~2x better TTFT p50; 12 requests here)
-        assert rec["value"] > 1.1
-        assert rec["ttft_p50_ms_on"] < rec["ttft_p50_ms_off"]
-
-    def test_bench_paged_kv_sweep(self, monkeypatch, capsys, tmp_path):
-        """The paged-KV capacity A/B (equal KV memory, 4x slots; plus
-        the equal-slot per-step-cost check and the exact token-parity
-        gate). Slow-marked like the other sweeps: tier-1 covers the
-        paged layout through tests/test_paged_kv.py; this drives the
-        full bench. Output redirects to tmp so CI can't clobber the
-        committed record."""
-        import json
-        import bench_serving
-        monkeypatch.setattr(bench_serving, "__file__",
-                            str(tmp_path / "bench_serving.py"))
-        monkeypatch.setenv("BENCH_SERVE_REQUESTS", "12")
-        rc = bench_serving.main(["--paged"])
-        assert rc == 0
-        rec = json.loads(
-            capsys.readouterr().out.strip().splitlines()[-1])
-        assert rec["parity_ok"] is True
-        assert rec["retraces_after_warmup"] == 0
-        assert rec["retraces_after_warmup_dense"] == 0
-        # the capacity win: strictly more concurrent slots than the
-        # dense engine can physically hold at the same KV bytes
-        assert rec["value"] >= 1.5
-        # per-step cost at equal shape: margin below the ~0.97 the
-        # full fixed-seed bench shows (12 requests here, CI jitter)
-        assert rec["tokens_per_sec_ratio_equal_slots"] > 0.8
-
-    def test_bench_chunked_prefill_sweep(self, monkeypatch, capsys,
-                                         tmp_path):
-        """The token-budget overload A/B (chunked vs phase prefill at
-        equal compiled shape, SAME arrivals, engine-owned TTFT
-        percentiles). Slow-marked like the other sweeps: tier-1 covers
-        the scheduler through tests/test_budget_scheduler.py; this
-        drives the full bench and its acceptance gates (TTFT flatness,
-        token parity, no retraces). Output redirects to tmp so CI can't
-        clobber the committed record."""
-        import json
-        import bench_serving
-        monkeypatch.setattr(bench_serving, "__file__",
-                            str(tmp_path / "bench_serving.py"))
-        monkeypatch.setenv("BENCH_SERVE_REQUESTS", "12")
-        rc = bench_serving.main(["--chunked"])
-        assert rc == 0
-        rec = json.loads(
-            capsys.readouterr().out.strip().splitlines()[-1])
-        assert rec["parity_ok"] is True
-        assert rec["retraces_after_warmup"] == 0
-        assert rec["retraces_after_warmup_phase"] == 0
-        assert rec["budget_steps"] > 0
-        # the flatness gate, with margin for 12-request CI jitter (the
-        # full fixed-seed bench pins <= 1.3 in the committed record)
-        assert rec["value"] <= 2.0
-        assert rec["tokens_per_sec_ratio"] > 0.8
-
-    def test_bench_spec_decode_sweep(self, monkeypatch, capsys,
-                                     tmp_path):
-        """The speculative-decoding A/B (n-gram drafter + verify step
-        on vs off at equal compiled shape, SAME arrivals). Slow-marked
-        like the other sweeps: tier-1 covers spec decoding through
-        tests/test_spec_decode.py; this drives the full bench and its
-        acceptance gates (speedup, acceptance rate, no retraces). The
-        output redirects to tmp so CI can't clobber the committed
-        record."""
-        import json
-        import bench_serving
-        monkeypatch.setattr(bench_serving, "__file__",
-                            str(tmp_path / "bench_serving.py"))
-        monkeypatch.setenv("BENCH_SERVE_REQUESTS", "12")
-        rc = bench_serving.main(["--spec"])
-        assert rc == 0
-        rec = json.loads(
-            capsys.readouterr().out.strip().splitlines()[-1])
-        assert rec["retraces_after_warmup"] == 0
-        assert rec["retraces_after_warmup_off"] == 0
-        assert rec["draft_accepted"] > 0
-        assert rec["acceptance_rate"] > 0.5
-        assert rec["tokens_per_step"] > 1.2
-        # timing-dependent with margin below the >= 1.2x the full
-        # fixed-seed bench shows (12 requests here, CI jitter)
-        assert rec["value"] > 1.05

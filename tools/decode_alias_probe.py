@@ -14,7 +14,8 @@ compile time — and counts cache-shaped copies in the optimized HLO:
   dus_kernel1 : carry = DUS(carry); pallas(carry)   (current design)
   dus_kernel2 : carry = DUS(carry); pallas(c, c)    (pre-r5s2 design)
 
-Prints one JSON line. Run:  python tools/decode_alias_probe.py
+Prints one JSON line. Runs on a TPU or not at all (through the chip
+tool):  python tools/decode_alias_probe.py
 """
 from __future__ import annotations
 
@@ -30,8 +31,10 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 def main() -> int:
-    from bench import _init_devices
-    jax, dev, tpu_unavailable = _init_devices()
+    import jax
+
+    from paddle_tpu.device.chip import device_record, require_tpu
+    dev = require_tpu()
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
@@ -121,7 +124,7 @@ def main() -> int:
                                                       lens)
         return (ci, sc), o.sum()
 
-    out = {"device": str(dev), "tpu_unavailable": bool(tpu_unavailable),
+    out = {"device": device_record(dev),
            "cache_bytes": int(np.prod(shape)) * 4}
     for name, body, init in (
             ("dus_only", body_only, None), ("dus_dense", body_dense, None),

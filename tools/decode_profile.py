@@ -59,8 +59,10 @@ def _time_generate(dec, batch=8, tokens=64, prompt_len=16):
 
 
 def main():
-    from bench import _init_devices
-    jax, dev, tpu_unavailable = _init_devices()
+    from paddle_tpu.device.chip import (device_record, require_tpu,
+                                        use_compile_cache)
+    dev = require_tpu()
+    use_compile_cache()
 
     tokens = int(os.environ.get("PROF_TOKENS", "64"))
     results = {}
@@ -101,10 +103,8 @@ def main():
         "two_layer_s": round(results["two_layer"], 4),
         "short8_s": round(results["short"], 4),
         "marginal_ms_per_token": round(per_tok * 1e3, 3),
-        "device": str(dev),
+        "device": device_record(dev),
     }
-    if tpu_unavailable:
-        rec["tpu_unavailable"] = True
     print(json.dumps(rec))
 
 
