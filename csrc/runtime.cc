@@ -1,14 +1,9 @@
-// paddle_tpu native runtime: TCPStore rendezvous, host trace collector,
-// bounded MPMC queue (DataLoader prefetch backbone).
+// paddle_tpu native runtime: TCPStore rendezvous and a bounded MPMC queue (DataLoader prefetch backbone).
 //
 // Capability parity (TPU-native re-implementations, not ports):
 //  - TCPStore / MasterDaemon:  paddle/fluid/distributed/store/tcp_store.cc
 //    (master listens, ranks set/get/add/wait over a tiny length-prefixed
 //    protocol on loopback/DCN; bootstrap KV for multi-host rendezvous).
-//  - Host tracer:              paddle/fluid/platform/profiler/ (RecordEvent
-//    host instrumentation -> chrome trace). Device timing comes from XLA's
-//    own profiler; this collects host-side spans with ns precision and no
-//    Python-object overhead in the hot path.
 //  - Bounded blocking queue:   the native prefetch core of the reference's
 //    DataLoader (paddle/fluid/operators/reader/buffered_reader.cc-class
 //    machinery) — Python workers enqueue opaque handles; consumers block in
@@ -39,12 +34,6 @@
 namespace {
 
 using Clock = std::chrono::steady_clock;
-
-int64_t now_ns() {
-  return std::chrono::duration_cast<std::chrono::nanoseconds>(
-             Clock::now().time_since_epoch())
-      .count();
-}
 
 // ---------------------------------------------------------------------------
 // Length-prefixed framing helpers
@@ -225,31 +214,6 @@ struct Client {
 };
 
 // ---------------------------------------------------------------------------
-// Trace collector
-// ---------------------------------------------------------------------------
-
-struct TraceEvent {
-  std::string name;
-  int64_t begin_ns;
-  int64_t end_ns;
-  uint64_t tid;
-};
-
-struct Tracer {
-  std::mutex mu;
-  std::vector<TraceEvent> events;
-  bool enabled = false;
-};
-
-Tracer g_tracer;
-
-thread_local std::vector<std::pair<std::string, int64_t>> tl_stack;
-
-uint64_t tid_hash() {
-  return std::hash<std::thread::id>{}(std::this_thread::get_id());
-}
-
-// ---------------------------------------------------------------------------
 // Bounded MPMC queue of opaque pointers
 // ---------------------------------------------------------------------------
 
@@ -399,76 +363,6 @@ int pd_store_wait(void* h, const char* key, int timeout_ms) {
   uint8_t op;
   std::string k, v;
   return recv_frame(c->fd, &op, &k, &v) && op == OP_OK ? 0 : -1;
-}
-
-// ------------------------------- tracer ------------------------------------
-
-void pd_trace_enable(int on) {
-  std::lock_guard<std::mutex> l(g_tracer.mu);
-  g_tracer.enabled = on != 0;
-  if (on) g_tracer.events.clear();
-}
-
-void pd_trace_begin(const char* name) {
-  if (!g_tracer.enabled) return;
-  tl_stack.emplace_back(name, now_ns());
-}
-
-void pd_trace_end() {
-  if (!g_tracer.enabled || tl_stack.empty()) return;
-  auto [name, begin] = tl_stack.back();
-  tl_stack.pop_back();
-  std::lock_guard<std::mutex> l(g_tracer.mu);
-  g_tracer.events.push_back({std::move(name), begin, now_ns(), tid_hash()});
-}
-
-int pd_trace_count() {
-  std::lock_guard<std::mutex> l(g_tracer.mu);
-  return static_cast<int>(g_tracer.events.size());
-}
-
-// chrome trace (catapult) JSON
-int pd_trace_dump(const char* path) {
-  std::lock_guard<std::mutex> l(g_tracer.mu);
-  FILE* f = std::fopen(path, "w");
-  if (!f) return -1;
-  auto json_escape = [](const std::string& s) {
-    std::string out;
-    out.reserve(s.size() + 8);
-    for (unsigned char c : s) {
-      switch (c) {
-        case '"': out += "\\\""; break;
-        case '\\': out += "\\\\"; break;
-        case '\n': out += "\\n"; break;
-        case '\t': out += "\\t"; break;
-        case '\r': out += "\\r"; break;
-        default:
-          if (c < 0x20) {
-            char buf[8];
-            std::snprintf(buf, sizeof(buf), "\\u%04x", c);
-            out += buf;
-          } else {
-            out += static_cast<char>(c);
-          }
-      }
-    }
-    return out;
-  };
-  std::fputs("{\"traceEvents\":[", f);
-  bool first = true;
-  for (const auto& e : g_tracer.events) {
-    if (!first) std::fputc(',', f);
-    first = false;
-    std::fprintf(f,
-                 "{\"name\":\"%s\",\"ph\":\"X\",\"ts\":%.3f,\"dur\":%.3f,"
-                 "\"pid\":0,\"tid\":%llu,\"cat\":\"host\"}",
-                 json_escape(e.name).c_str(), e.begin_ns / 1e3,
-                 (e.end_ns - e.begin_ns) / 1e3,
-                 static_cast<unsigned long long>(e.tid % 100000));
-  }
-  std::fputs("]}", f);
-  std::fclose(f);
-  return 0;
 }
 
 // ------------------------------- queue -------------------------------------

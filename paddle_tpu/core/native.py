@@ -10,8 +10,6 @@ Native components exposed here:
   TCPStore / TCPStoreServer  — rendezvous KV
       (parity: paddle/fluid/distributed/store/tcp_store.cc :: TCPStore,
       MasterDaemon)
-  NativeTracer               — host span collector -> chrome trace
-      (parity: paddle/fluid/platform/profiler/ host tracer)
   NativeQueue                — bounded blocking queue; DataLoader prefetch
       (parity: the reference's native buffered-reader machinery)
 """
@@ -107,11 +105,6 @@ def load_native():
         L.pd_store_wait.restype = ctypes.c_int
         L.pd_store_wait.argtypes = [ctypes.c_void_p, ctypes.c_char_p,
                                     ctypes.c_int]
-        L.pd_trace_enable.argtypes = [ctypes.c_int]
-        L.pd_trace_begin.argtypes = [ctypes.c_char_p]
-        L.pd_trace_count.restype = ctypes.c_int
-        L.pd_trace_dump.restype = ctypes.c_int
-        L.pd_trace_dump.argtypes = [ctypes.c_char_p]
         L.pd_queue_new.restype = ctypes.c_void_p
         L.pd_queue_new.argtypes = [ctypes.c_int]
         L.pd_queue_close.argtypes = [ctypes.c_void_p]
@@ -210,48 +203,6 @@ class TCPStore:
             self.close()
         except Exception:
             pass
-
-
-class NativeTracer:
-    """Host span collector; None-safe module-level helpers in profiler.
-
-    Lazy: the (possibly slow, g++-invoking) load_native() runs on first
-    use, never at construction — so importing a module that instantiates a
-    tracer costs nothing."""
-
-    def __init__(self):
-        self._lib_loaded = False
-        self.__lib = None
-
-    @property
-    def _lib(self):
-        if not self._lib_loaded:
-            self.__lib = load_native()
-            self._lib_loaded = True
-        return self.__lib
-
-    @property
-    def available(self) -> bool:
-        return self._lib is not None
-
-    def enable(self, on: bool = True):
-        if self._lib:
-            self._lib.pd_trace_enable(1 if on else 0)
-
-    def begin(self, name: str):
-        if self._lib:
-            self._lib.pd_trace_begin(name.encode())
-
-    def end(self):
-        if self._lib:
-            self._lib.pd_trace_end()
-
-    def count(self) -> int:
-        return self._lib.pd_trace_count() if self._lib else 0
-
-    def dump(self, path: str) -> bool:
-        return bool(self._lib) and \
-            self._lib.pd_trace_dump(path.encode()) == 0
 
 
 class NativeQueue:

@@ -31,14 +31,6 @@ int   pd_store_add(void* client, const char* key, long long delta,
                    long long* out);
 int   pd_store_wait(void* client, const char* key, int timeout_ms);
 
-/* ---- host tracer (reference: paddle/fluid/platform/profiler) ----------- */
-void  pd_trace_enable(int on);
-void  pd_trace_begin(const char* name);
-void  pd_trace_end(void);
-int   pd_trace_count(void);
-/* write events as chrome-trace JSON to path; returns 0 on success */
-int   pd_trace_dump(const char* path);
-
 /* ---- MPMC prefetch queue (reference: paddle/fluid/operators/reader) ---- */
 void* pd_queue_new(int capacity);
 /* item ownership transfers to the queue; 0 on success, -1 on timeout/closed */

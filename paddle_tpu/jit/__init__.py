@@ -14,6 +14,14 @@ the static graph, so ``to_static`` compiles the *same eager code* by tracing:
 This turns a dygraph train step into ONE fused XLA program: the per-op
 dispatch the reference pays per Python call disappears, and AdamW over the
 whole pytree becomes the fused multi-tensor form for free.
+
+The call path measures itself (PERF.md section 3, layer "trainer"): every
+compiled call runs under ``jax.profiler.TraceAnnotation`` spans
+(``to_static.call`` around ``.key``, ``.dispatch`` or ``.trace_compile``,
+and ``.writeback``), which land in the profiler's trace on the device's
+clock, and leaves one record in a ``telemetry.Telemetry`` step ring
+(``call_timeline()``; off with ``PADDLE_TELEMETRY_RING=0``) plus the
+``paddle_to_static_*`` counters of the runtime registry.
 """
 from __future__ import annotations
 
@@ -26,10 +34,31 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..tensor.tensor import (Tensor, persistent_tensors, _tape)
+from jax.profiler import TraceAnnotation
+
+from ..inference import telemetry as _telemetry
+from ..tensor.tensor import (Tensor, persistent_tensors,
+                             unregister_persistent_many, _tape)
 
 __all__ = ["to_static", "not_to_static", "ignore_module", "save", "load",
-           "TranslatedLayer", "enable_to_static"]
+           "TranslatedLayer", "enable_to_static", "call_timeline"]
+
+# The trainer's step ring: one record per compiled call (kind "to_static").
+_timeline = _telemetry.Telemetry()
+
+
+def call_timeline():
+    """One record per compiled ``to_static`` call (``run_steps`` included),
+    oldest first: ``n`` (the call's ordinal in the process, the ``step=`` of
+    its ``to_static.call`` span), ``fn`` (the function's qualified name),
+    ``fresh`` (this call traced and compiled a new entry), and host seconds
+    ``call_s`` (all of the call), ``key_s`` (building the entry key),
+    ``dur_s`` (the jitted call alone) and ``writeback_s`` (everything after
+    it returned). The newest ``PADDLE_TELEMETRY_RING`` calls are kept
+    (default 2048); with the ring at 0 the list is empty and a call reads no
+    clock."""
+    return list(_timeline.steps)
+
 
 _to_static_enabled = [True]
 
@@ -148,6 +177,8 @@ class StaticFunction:
         self._donate_state = donate_state
         self._cache: dict = {}
         self._bound_instance = None
+        # what the spans and the timeline call this function
+        self._qualname = getattr(fn, "__qualname__", type(fn).__name__)
 
     def __get__(self, instance, owner):
         if instance is None:
@@ -165,26 +196,14 @@ class StaticFunction:
     def __call__(self, *args, **kwargs):
         if not _to_static_enabled[0]:
             return self._fn(*args, **kwargs)
+        return self._run(args, kwargs)
 
-        arg_arrays, state, spec, key = self._entry_key(args, kwargs)
-        entry = self._cache.get(key)
-        fresh = entry is None
-        if fresh:
-            entry = self._build(state, spec, key)
-        out_arrays, state_after, new_state = self._execute(
-            entry, state, arg_arrays, scan=False, entry_key=key,
-            fresh_entry=fresh)
-        # state_after may be a superset of state: persistent tensors created
-        # during tracing (e.g. lazily-built optimizer slots) are captured as
-        # extra outputs; the next call's key sees the superset and recompiles
-        # once into the steady signature.
-        for t, arr in zip(state_after, new_state):
-            t._data = arr
-        return _unflatten_out(entry[1][0], out_arrays)
-
-    def _entry_key(self, args, kwargs):
-        """(arg arrays, persistent state, arg spec, compiled-entry key)."""
+    def _entry_key(self, args, kwargs, k=None):
+        """(arg arrays, persistent state, arg spec, compiled-entry key);
+        ``k`` is ``run_steps``' scan length."""
         arg_tensors, spec = _tree_flatten_args(args, kwargs)
+        if k is not None:
+            _check_stacked(arg_tensors, k)
         arg_arrays = [t._data for t in arg_tensors]
         state = persistent_tensors()
         key = (
@@ -192,6 +211,8 @@ class StaticFunction:
             tuple(id(t) for t in state),
             _spec_key(spec),
         )
+        if k is not None:
+            key = ("scan", k) + key
         return arg_arrays, state, spec, key
 
     def lower(self, *args, **kwargs):
@@ -235,80 +256,134 @@ class StaticFunction:
             return out_arrays, new_state
         return pure
 
-    def _execute(self, entry, state, call_arrays, scan, entry_key=None,
-                 fresh_entry=True):
-        """Run a compiled entry with tape/grad save-restore and the
-        donation-aware error contract shared by __call__ and run_steps."""
-        jitted, out_spec_box, state_after_box = entry
-        state_arrays = [t._data for t in state]
-        saved_nodes = _tape.nodes[:]
-        saved_grads = [(t, t.grad) for t in state]
-        pre_existing = {id(t) for t in state}
-        try:
-            out_arrays, new_state = jitted(state_arrays, call_arrays)
-        except Exception as e:
-            _tape.nodes[:] = saved_nodes
-            for t, arr in zip(state, state_arrays):
-                t._data = arr
-            for t, g in saved_grads:
-                t.grad = g
-            # Persistent tensors CREATED during the failed trace/compile
-            # (lazily-built optimizer slots, master weights) hold escaped
-            # tracers; left registered they poison every later to_static
-            # call in the process with UnexpectedTracerError. Their true
-            # values never existed, so roll them back hard: drop from the
-            # registry and mark dead (_data=None) — owners that cache them
-            # (Optimizer._acc/_seed_master) recreate dead slots on reuse.
-            from ..tensor.tensor import (persistent_tensors,
-                                         unregister_persistent_many)
-            killed = [t for t in persistent_tensors()
-                      if id(t) not in pre_existing]
-            unregister_persistent_many(killed)
-            for t in killed:
-                t._data = None
-            if killed or fresh_entry:
-                # only evict when this call's trace may be inconsistent —
-                # a transient EXECUTE failure of a long-good compiled entry
-                # must not force a retrace (compiles cost minutes)
-                state_after_box[0] = None
-                self._cache.pop(entry_key, None)
-            if scan and "carry" in str(e):
-                raise RuntimeError(
-                    "run_steps traced new persistent state (e.g. "
-                    "lazily-built optimizer slots) inside the scan body; "
-                    "call the step function once normally before run_steps "
-                    "so state is steady.") from e
-            if self._donate_state:
-                # execution-time failure after donation: the restored arrays
-                # may already be deleted — say so instead of surfacing a
-                # bare "Array has been deleted" later
-                raise RuntimeError(
-                    "to_static step failed after state buffers were donated; "
-                    "persistent state may be invalid. Re-create the model/"
-                    "optimizer or use to_static(donate_state=False) for "
-                    "rollback-on-error semantics.") from e
-            raise
-        finally:
-            _tape.nodes[:] = saved_nodes
-            for t, arr in zip(state, state_arrays):
-                t._data = arr  # undo any tracer leakage before writeback
-            for t, g in saved_grads:
-                t.grad = g
-        return out_arrays, (state_after_box[0] or state), new_state
+    def _run(self, args, kwargs, k=None):
+        """One compiled call (``k``: ``run_steps``' scan length) with
+        tape/grad save-restore and the donation-aware error contract, under
+        the trainer's spans; leaves one record in ``call_timeline()``."""
+        n = _telemetry.runtime_counter("paddle_to_static_calls_total", 1)
+        timed = _timeline.enabled
+        clock = _timeline.clock if timed else float     # float() is 0.0
+        with TraceAnnotation("to_static.call", step=n, fn=self._qualname):
+            t0 = clock()
+            with TraceAnnotation("to_static.key"):
+                call_arrays, state, spec, key = self._entry_key(
+                    args, kwargs, k)
+            t1 = clock()
+            entry = self._cache.get(key)
+            fresh = entry is None
+            if fresh:
+                entry = self._build(state, spec, key, k)
+            jitted, out_spec_box, state_after_box = entry
+            state_arrays = [t._data for t in state]
+            saved_nodes = _tape.nodes[:]
+            saved_grads = [(t, t.grad) for t in state]
 
-    def _build(self, state, spec, key):
+            def restore():
+                _tape.nodes[:] = saved_nodes
+                for t, arr in zip(state, state_arrays):
+                    t._data = arr
+                for t, g in saved_grads:
+                    t.grad = g
+
+            t2 = clock()
+            try:
+                with TraceAnnotation("to_static.trace_compile" if fresh
+                                     else "to_static.dispatch"):
+                    out_arrays, new_state = jitted(state_arrays, call_arrays)
+            except BaseException as e:
+                restore()
+                if isinstance(e, Exception):
+                    self._failed(e, state, entry, key, fresh, k is not None)
+                raise
+            t3 = clock()
+            with TraceAnnotation("to_static.writeback"):
+                restore()       # undo any tracer leakage before writeback
+                # the state after may be a superset of state: persistent
+                # tensors created during tracing (e.g. lazily-built optimizer
+                # slots) are captured as extra outputs; the next call's key
+                # sees the superset and recompiles once into the steady
+                # signature.
+                for t, arr in zip(state_after_box[0] or state, new_state):
+                    t._data = arr
+                # The pre-step arrays are released HERE, together, and not
+                # one by one inside the loop above (which they would be
+                # without this list). Keep it so: on the v5e releasing them
+                # in the loop costs the next call's result allocation 5-8%
+                # of a gpt2_124m step (PERF.md section 6, PR 25).
+                del state_arrays
+                out = _unflatten_out(out_spec_box[0], out_arrays)
+            if fresh:
+                _telemetry.runtime_counter(
+                    "paddle_to_static_compiles_total", 1)
+            if timed:
+                t4 = clock()
+                _telemetry.runtime_histogram(
+                    "paddle_to_static_call_seconds").observe(t4 - t0)
+                _timeline.step_event(
+                    "to_static", t0, t3 - t2, call_s=t4 - t0, key_s=t1 - t0,
+                    writeback_s=t4 - t3, fresh=fresh, fn=self._qualname, n=n)
+        return out
+
+    def _failed(self, e, state, entry, key, fresh, scan):
+        """The jitted call raised ``e``: roll back what its trace created,
+        and raise the error the caller should see if it is not ``e``."""
+        # Persistent tensors CREATED during the failed trace/compile
+        # (lazily-built optimizer slots, master weights) hold escaped
+        # tracers; left registered they poison every later to_static
+        # call in the process with UnexpectedTracerError. Their true
+        # values never existed, so roll them back hard: drop from the
+        # registry and mark dead (_data=None) — owners that cache them
+        # (Optimizer._acc/_seed_master) recreate dead slots on reuse.
+        pre_existing = {id(t) for t in state}
+        killed = [t for t in persistent_tensors()
+                  if id(t) not in pre_existing]
+        unregister_persistent_many(killed)
+        for t in killed:
+            t._data = None
+        if killed or fresh:
+            # only evict when this call's trace may be inconsistent —
+            # a transient EXECUTE failure of a long-good compiled entry
+            # must not force a retrace (compiles cost minutes)
+            entry[2][0] = None
+            self._cache.pop(key, None)
+        if scan and "carry" in str(e):
+            raise RuntimeError(
+                "run_steps traced new persistent state (e.g. "
+                "lazily-built optimizer slots) inside the scan body; "
+                "call the step function once normally before run_steps "
+                "so state is steady.") from e
+        if self._donate_state:
+            # execution-time failure after donation: the restored arrays
+            # may already be deleted — say so instead of surfacing a
+            # bare "Array has been deleted" later
+            raise RuntimeError(
+                "to_static step failed after state buffers were donated; "
+                "persistent state may be invalid. Re-create the model/"
+                "optimizer or use to_static(donate_state=False) for "
+                "rollback-on-error semantics.") from e
+
+    def _build(self, state, spec, key, k=None):
         out_spec_box = [None]
         state_after_box = [None]
         pure = self._make_pure(state, spec, out_spec_box, state_after_box)
 
+        def scanned(state_arrays, stacked):
+            def body(carry, xs):
+                out_arrays, new_state = pure(carry, list(xs))
+                return new_state, out_arrays
+            final_state, outs = jax.lax.scan(body, state_arrays,
+                                             tuple(stacked), length=k)
+            return outs, final_state
+
         # donate the state buffers: params/optimizer slots update in place
         # (XLA aliases input->output), halving steady-state HBM traffic for
         # the weight update; callers never read the pre-step arrays again
-        # (writeback below replaces every tensor's _data with the outputs).
+        # (writeback replaces every tensor's _data with the outputs).
         # Opt out with to_static(donate_state=False) to keep pre-step arrays
         # valid (e.g. external references, or rollback-on-error semantics).
         donate = (0,) if self._donate_state else ()
-        jitted = jax.jit(pure, donate_argnums=donate)
+        jitted = jax.jit(pure if k is None else scanned,
+                         donate_argnums=donate)
         entry = (jitted, out_spec_box, state_after_box)
         self._cache[key] = entry
         return entry
@@ -346,43 +421,7 @@ class StaticFunction:
                               for j in range(len(flat[0][0]))]
             return _unflatten_out(flat[0][1], stacked_arrays)
 
-        arg_tensors, spec = _tree_flatten_args(args, kwargs)
-        _check_stacked(arg_tensors, k)
-        stacked = [t._data for t in arg_tensors]
-        state = persistent_tensors()
-
-        key = ("scan", k,
-               tuple((tuple(a.shape), str(a.dtype)) for a in stacked),
-               tuple(id(t) for t in state), _spec_key(spec))
-        entry = self._cache.get(key)
-        fresh = entry is None
-        if fresh:
-            entry = self._build_scan(k, state, spec, key)
-        out_arrays, state_after, new_state = self._execute(
-            entry, state, stacked, scan=True, entry_key=key,
-            fresh_entry=fresh)
-        for t, arr in zip(state_after, new_state):
-            t._data = arr
-        return _unflatten_out(entry[1][0], out_arrays)
-
-    def _build_scan(self, k, state, spec, key):
-        out_spec_box = [None]
-        state_after_box = [None]
-        pure = self._make_pure(state, spec, out_spec_box, state_after_box)
-
-        def scanned(state_arrays, stacked):
-            def body(carry, xs):
-                out_arrays, new_state = pure(carry, list(xs))
-                return new_state, out_arrays
-            final_state, outs = jax.lax.scan(body, state_arrays,
-                                             tuple(stacked), length=k)
-            return outs, final_state
-
-        donate = (0,) if self._donate_state else ()
-        jitted = jax.jit(scanned, donate_argnums=donate)
-        entry = (jitted, out_spec_box, state_after_box)
-        self._cache[key] = entry
-        return entry
+        return self._run(args, kwargs, k)
 
 
 def _check_stacked(tensors, k):

@@ -249,6 +249,7 @@ def decode_attention_bhsd(qt, kt, vt, cache_lens, scale=None):
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, h, bq, d), qt.dtype),
+        name="decode_attention_bhsd",
         interpret=_pallas._interpret(),
     )(lens, qt, kt, vt)
     return out[:, :, :sq]
@@ -414,6 +415,7 @@ def decode_attention_stacked(qt, caches, layer, cache_lens, scale=None):
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, h, bq, d), caches.dtype),
+        name="decode_attention_stacked",
         interpret=_pallas._interpret(),
     )(lay, lens, qt, caches)
     return out[:, :, :sq].astype(out_dtype)
@@ -516,6 +518,7 @@ def decode_attention_stacked_i8(qt, caches_i8, cache_scales, layer,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, h, bq, d), out_dtype),
+        name="decode_attention_stacked_i8",
         interpret=_pallas._interpret(),
     )(lay, lens, qt, caches_i8, cache_scales)
     return out[:, :, :sq]
@@ -694,6 +697,7 @@ def decode_attention_stacked_write(qt, kv_new, caches, layer, cache_lens,
             jax.ShapeDtypeStruct((b, h, bq, d), out_dtype),
         ],
         input_output_aliases={4: 0},   # caches operand -> caches output
+        name="decode_attention_stacked_write",
         interpret=_pallas._interpret(),
     )(lay, lens, qt, kv_new.astype(caches.dtype), caches)
     return caches_out, out[:, :, :sq].astype(out_dtype)
@@ -876,6 +880,7 @@ def decode_attention_stacked_i8_write(qt, kv_new, caches_i8, cache_scales,
             jax.ShapeDtypeStruct((b, h, bq, d), out_dtype),
         ],
         input_output_aliases={4: 0, 5: 1},
+        name="decode_attention_stacked_i8_write",
         interpret=_pallas._interpret(),
     )(lay, lens, qt, kv_new.astype(jnp.float32), caches_i8, cache_scales)
     return caches_out, scales_out, out[:, :, :sq].astype(out_dtype)
@@ -1049,6 +1054,7 @@ def decode_attention_paged(qt, pool, tables, layer, cache_lens,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, h, bq, d), pool.dtype),
+        name="decode_attention_paged",
         interpret=_pallas._interpret(),
     )(lay, lens, tbl, qt, pool)
     return out[:, :, :sq].astype(out_dtype)
@@ -1138,6 +1144,7 @@ def decode_attention_paged_i8(qt, pool_i8, pool_scales, tables, layer,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, h, bq, d), out_dtype),
+        name="decode_attention_paged_i8",
         interpret=_pallas._interpret(),
     )(lay, lens, tbl, qt, pool_i8, pool_scales)
     return out[:, :, :sq]
@@ -1298,6 +1305,7 @@ def decode_attention_paged_flat(q, pool, tables, chunk_slot, chunk_base,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((h, t, d), pool.dtype),
+        name="decode_attention_paged_flat",
         interpret=_pallas._interpret(),
     )(lay, chunk_slot.astype(jnp.int32), chunk_base.astype(jnp.int32),
       chunk_n.astype(jnp.int32), tables.astype(jnp.int32), qt, pool)
@@ -1429,6 +1437,7 @@ def decode_attention_paged_flat_i8(q, pool_i8, pool_scales, tables,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((h, t, d), out_dtype),
+        name="decode_attention_paged_flat_i8",
         interpret=_pallas._interpret(),
     )(lay, chunk_slot.astype(jnp.int32), chunk_base.astype(jnp.int32),
       chunk_n.astype(jnp.int32), tables.astype(jnp.int32), qt, pool_i8,

@@ -234,6 +234,7 @@ def _fwd(q, k, v, drop=None, *, causal, scale, bq, bk):
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
+        name="flash_attention_fwd",
         interpret=_pallas._interpret(),
     )(*args)
     return o[:, :, :sq], lse[:, :, :sq]        # lse: [B, H, Sq, 1]
@@ -481,6 +482,7 @@ def _bwd_fused(q_, k_, v_, do_, lse_, delta_, drop, drop_arg, *,
             jax.ShapeDtypeStruct((b, h, sk_p, d), jnp.float32),
             jax.ShapeDtypeStruct((b, h, sk_p, d), jnp.float32),
         ],
+        name="flash_attention_bwd_fused",
         interpret=_pallas._interpret(),
     )(*args)
     return dq, dk, dv
@@ -568,6 +570,7 @@ def _bwd(q, k, v, o, lse, do, drop=None, *, causal, scale, bq, bk):
             pltpu.VMEM((bk, d), jnp.float32),
             pltpu.VMEM((bk, d), jnp.float32),
         ],
+        name="flash_attention_bwd_dkv",
         interpret=_pallas._interpret(),
     )(*dkv_args)
 
@@ -594,6 +597,7 @@ def _bwd(q, k, v, o, lse, do, drop=None, *, causal, scale, bq, bk):
                                lambda b_, h_, i, j: (b_, h_, i, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, sq_p, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+        name="flash_attention_bwd_dq",
         interpret=_pallas._interpret(),
     )(*dq_args)
 

@@ -154,6 +154,7 @@ def fused_dequant_matmul(a, w_packed, scales, *, out_dtype=None):
         out_specs=pl.BlockSpec((mp, o), lambda ki: (0, 0)),
         scratch_shapes=[pltpu.VMEM((mp, o), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct((mp, o), out_dtype),
+        name="fused_dequant_matmul_fwd",
         interpret=_pallas._interpret(),
     )(a_even, a_odd, w_packed, s2)
     if mp != m:

@@ -98,6 +98,7 @@ def _fwd_kernel_call(x, w1, b1, w2, b2, bm, bf, act):
         out_specs=pl.BlockSpec((bm, k), lambda mi, fi: (mi, 0)),
         scratch_shapes=[pltpu.VMEM((bm, k), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct((m, k), x.dtype),
+        name="fused_ffn_fwd",
         interpret=_pallas._interpret(),
     )(x, w1, b1.reshape(1, f), w2, b2.reshape(1, k))
 
@@ -281,6 +282,7 @@ def _bwd_kernel_calls(x2, g2, w1, b1, w2, bm_dx, bm_dw, bf, act):
         out_specs=pl.BlockSpec((bm, k), lambda mi, fi: (mi, 0)),
         scratch_shapes=[pltpu.VMEM((bm, k), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct((m, k), x2.dtype),
+        name="fused_ffn_bwd_dx",
         interpret=_pallas._interpret(),
     )(x2, g2, w1, b1r, w2)
     bm, nm = bm_dw, m // bm_dw
@@ -305,6 +307,7 @@ def _bwd_kernel_calls(x2, g2, w1, b1, w2, bm_dx, bm_dw, bf, act):
         out_shape=[jax.ShapeDtypeStruct((k, f), w1.dtype),
                    jax.ShapeDtypeStruct((f, k), w2.dtype),
                    jax.ShapeDtypeStruct((1, f), jnp.float32)],
+        name="fused_ffn_bwd_dw",
         interpret=_pallas._interpret(),
     )(x2, g2, w1, b1r, w2)
     return dx, dw1, dw2, db1.reshape(f)

@@ -104,6 +104,7 @@ def _ln_fwd(x2, gamma, beta, eps):
             jax.ShapeDtypeStruct((n, 1), jnp.float32),
             jax.ShapeDtypeStruct((n, 1), jnp.float32),
         ],
+        name="layer_norm_fwd",
         interpret=_pallas._interpret(),
     )(x2, gamma[None, :], beta[None, :])
     return y, mean, rstd
@@ -144,6 +145,7 @@ def _ln_vjp_bwd(eps, res, dy):
             jax.ShapeDtypeStruct((nb, 1, d), jnp.float32),
             jax.ShapeDtypeStruct((nb, 1, d), jnp.float32),
         ],
+        name="layer_norm_bwd",
         interpret=_pallas._interpret(),
     )(x2, gamma[None, :], mean, rstd, dy)
     dgamma = jnp.sum(dg_part, axis=(0, 1)).astype(gamma.dtype)
@@ -210,6 +212,7 @@ def _rms_fwd(x2, gamma, eps):
             jax.ShapeDtypeStruct((n, d), x2.dtype),
             jax.ShapeDtypeStruct((n, 1), jnp.float32),
         ],
+        name="rms_norm_fwd",
         interpret=_pallas._interpret(),
     )(x2, gamma[None, :])
     return y, rstd
@@ -242,6 +245,7 @@ def _rms_vjp_bwd(eps, res, dy):
             jax.ShapeDtypeStruct((n, d), x2.dtype),
             jax.ShapeDtypeStruct((nb, 1, d), jnp.float32),
         ],
+        name="rms_norm_bwd",
         interpret=_pallas._interpret(),
     )(x2, gamma[None, :], rstd, dy)
     return dx, jnp.sum(dg_part, axis=(0, 1)).astype(gamma.dtype)

@@ -256,6 +256,7 @@ def _fwd(q, k, v, offset, scale):
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
+        name="ring_chunk_attention_fwd",
         interpret=_pallas._interpret(),
     )(jnp.reshape(offset.astype(jnp.int32), (1,)), q_, k_, v_)
     return o[:, :, :sq], lse[:, :, :sq, 0]        # lse: [B, H, Sq]
@@ -315,6 +316,7 @@ def _vjp_bwd(scale, res, cts):
             pltpu.VMEM((bk, d), jnp.float32),
             pltpu.VMEM((bk, d), jnp.float32),
         ],
+        name="ring_chunk_attention_bwd_dkv",
         interpret=_pallas._interpret(),
     )(off, q_, k_, v_, do_, lse_, delta_)
 
@@ -328,6 +330,7 @@ def _vjp_bwd(scale, res, cts):
         out_specs=qspec,
         out_shape=_sds((b, h, sq_p, d), q.dtype, q_, k_, v_, do_),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+        name="ring_chunk_attention_bwd_dq",
         interpret=_pallas._interpret(),
     )(off, q_, k_, v_, do_, lse_, delta_)
 

@@ -4,8 +4,8 @@ Parity: python/paddle/profiler/profiler.py (Profiler, RecordEvent, scheduler
 cycles, export_chrome_tracing) backed by paddle/fluid/platform/profiler/ host
 + CUPTI tracers. TPU-native: jax.profiler writes XPlane/Perfetto traces that
 TensorBoard renders (the TPU-side analog of the Chrome trace), and
-RecordEvent maps to jax.profiler.TraceAnnotation scopes compiled into the
-XLA timeline.
+RecordEvent maps to jax.profiler.TraceAnnotation: a host span in that same
+trace, on the device operations' clock, and the one place spans are kept.
 """
 from __future__ import annotations
 
@@ -16,8 +16,6 @@ from enum import Enum
 from typing import Callable, Iterable, Optional
 
 import jax
-
-from ..core.native import NativeTracer
 
 __all__ = ["Profiler", "RecordEvent", "ProfilerTarget", "ProfilerState",
            "make_scheduler", "export_chrome_tracing", "load_profiler_result",
@@ -73,10 +71,6 @@ class ChromeTrace:
         with open(path, "w") as f:
             json.dump(self.to_dict(), f)
         return path
-
-# Host span collector (C++, csrc/runtime.cc — parity with the reference's
-# native host tracer); None-safe when the toolchain is absent.
-_host_tracer = NativeTracer()
 
 
 class ProfilerTarget(Enum):
@@ -142,14 +136,12 @@ class RecordEvent:
     def begin(self):
         self._ctx = jax.profiler.TraceAnnotation(self.name)
         self._ctx.__enter__()
-        _host_tracer.begin(self.name)
         self.begin_ns = time.perf_counter_ns()
 
     def end(self):
         if self._ctx is not None:
             self._ctx.__exit__(None, None, None)
             self._ctx = None
-        _host_tracer.end()
         self.end_ns = time.perf_counter_ns()
 
     def __enter__(self):
@@ -193,7 +185,6 @@ class Profiler:
                 self._active = True
             except Exception:
                 self._active = False
-            _host_tracer.enable(True)
         self._t0 = time.perf_counter()
 
     def stop(self):
@@ -203,12 +194,6 @@ class Profiler:
             except Exception:
                 pass
             self._active = False
-        if _host_tracer.available and not self.timer_only:
-            # chrome trace of host spans alongside the XPlane dump
-            os.makedirs(self._log_dir(), exist_ok=True)
-            _host_tracer.dump(os.path.join(self._log_dir(),
-                                           "host_trace.json"))
-            _host_tracer.enable(False)
         if self.on_trace_ready:
             self.on_trace_ready(self)
 
@@ -233,7 +218,7 @@ class Profiler:
 
     def export(self, path, format="json"):
         """Chrome-trace export of the timer-level step timeline (the
-        XPlane/host dumps land in the log dir at stop(); this is the
+        XPlane dump lands in the log dir at stop(); this is the
         lightweight per-step view, same event model as the serving
         telemetry export)."""
         tr = ChromeTrace()

@@ -159,8 +159,32 @@ def quant_env_active() -> list:
             if os.environ.get(v) not in (None, "")]
 
 
+def pallas_call_sites() -> list:
+    """``(file, line, name)`` of every ``pallas_call`` under
+    ``ops/pallas/``, read from the source: ``name`` is the call's ``name=``
+    string (the compiled instruction's and so the device trace's event
+    name), or None where the call has none."""
+    import ast
+    import glob
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sites = []
+    for path in sorted(glob.glob(os.path.join(here, "ops", "pallas",
+                                              "*.py"))):
+        with open(path) as f:
+            tree = ast.parse(f.read())
+        for node in ast.walk(tree):
+            if (isinstance(node, ast.Call)
+                    and isinstance(node.func, ast.Attribute)
+                    and node.func.attr == "pallas_call"):
+                name = next((kw.value.value for kw in node.keywords
+                             if kw.arg == "name"
+                             and isinstance(kw.value, ast.Constant)), None)
+                sites.append((os.path.basename(path), node.lineno, name))
+    return sites
+
+
 from . import fault  # noqa: E402  (re-export the harness)
 
 __all__ = ["FI_ENV_VARS", "FR_ENV_VARS", "GW_ENV_VARS", "QUANT_ENV_VARS",
            "fi_env_active", "fr_env_active", "gw_env_active",
-           "quant_env_active", "fault"]
+           "quant_env_active", "pallas_call_sites", "fault"]

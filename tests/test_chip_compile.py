@@ -16,6 +16,7 @@ import os
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
 
 import functools
+import re
 
 import numpy as np
 import pytest
@@ -26,19 +27,22 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
 
-def _describe_topology():
+_TOPO = None    # the described v5e:2x2, once a test of this file has started
+
+
+@pytest.fixture(scope="module")
+def topo():
+    """Describes the topology, which loads the TPU's library: inside a
+    fixture, so that importing this file (every xdist worker does) never
+    does (on-chip-measurement guide, section 2)."""
+    global _TOPO
     try:
         from jax.experimental import topologies
-        return topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
+        _TOPO = topologies.get_topology_desc(platform="tpu",
+                                             topology_name="v5e:2x2")
     except Exception as e:  # no libtpu / unknown topology: skip, say why
-        return e
-
-
-_TOPO = _describe_topology()
-pytestmark = pytest.mark.skipif(
-    isinstance(_TOPO, Exception),
-    reason=f"cannot describe a v5e:2x2 topology here: {_TOPO!r}")
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e!r}")
+    return _TOPO
 
 # chip_smoke.py's serving widths
 B, H, D, L, SMAX = 8, 12, 64, 12, 1024
@@ -49,7 +53,7 @@ I32 = jnp.int32
 
 
 @pytest.fixture(autouse=True)
-def _compile_for_the_chip(monkeypatch):
+def _compile_for_the_chip(monkeypatch, topo):
     """Kernels lower through Mosaic (not the interpreter), and the
     persistent compile cache stays off: an AOT TPU executable written
     from the CPU cannot be read back and would warn on the next run."""
@@ -78,11 +82,27 @@ def _on(mesh, shape, dtype, *spec):
                                 sharding=NamedSharding(mesh, P(*spec)))
 
 
+# "%flash_attention_fwd.1 = ... custom-call(...), custom_call_target=
+# "tpu_custom_call"": the instruction's name is the device trace's event name
+_KERNEL_INSTR = re.compile(
+    r"^\s*(?:ROOT )?%([\w.\-]+) = .*custom_call_target=\"tpu_custom_call\"",
+    re.M)
+
+
 def _compile(fn, *args):
     """Lower + compile for the described chip; the kernel must be IN the
-    program (a stale interpret-mode trace would compile trivially)."""
+    program (a stale interpret-mode trace would compile trivially), named
+    after the ``name=`` of its ``pallas_call``, which is what a device trace,
+    and ``breakdown.device_ops``, then calls the kernel. JAX wraps the name
+    in the transforms it went through: the backward of ``flash_attention``
+    compiles to ``%transpose_jvp_flash_attention_bwd_fused__.1``."""
+    from paddle_tpu.testing import pallas_call_sites
     compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    kernels = _KERNEL_INSTR.findall(compiled.as_text())
+    assert kernels, "no tpu_custom_call in the compiled program"
+    known = {name for _, _, name in pallas_call_sites() if name}
+    for k in kernels:
+        assert any(name in k for name in known), (k, sorted(known))
     return compiled
 
 
@@ -183,13 +203,13 @@ def _dense_decode():
 _QKV = (B, 1024, H, D)     # the gpt2_124m train step: 8 x 1024 x 12 x 64
 
 
-def _flash(grad, dropout_p=0.0):
+def _flash(grad, dropout_p=0.0, qkv=_QKV):
     from paddle_tpu.ops.pallas.flash_attention import flash_attention
     fn = functools.partial(flash_attention, causal=True,
                            dropout_p=dropout_p)
     if grad:
         fn = _sum_grad(fn, (0, 1, 2))
-    return fn, (_one(_QKV, BF),) * 3
+    return fn, (_one(qkv, BF),) * 3
 
 
 def _ffn(grad, monkeypatch):
@@ -347,6 +367,8 @@ _CASES = {
     "flash_fwd": lambda mp: _flash(False),
     "flash_fwd_bwd": lambda mp: _flash(True),
     "flash_fwd_bwd_dropout": lambda mp: _flash(True, 0.1),
+    # past one block a side the backward is two kernels, dk/dv then dq
+    "flash_fwd_bwd_seq4096": lambda mp: _flash(True, qkv=(2, 4096, H, D)),
     "fused_ffn_fwd": lambda mp: _ffn(False, mp),
     "fused_ffn_bwd": lambda mp: _ffn(True, mp),
     "layer_norm_fwd": lambda mp: _layer_norm(False),

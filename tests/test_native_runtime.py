@@ -1,16 +1,15 @@
-"""Native C++ runtime: TCPStore rendezvous, tracer, bounded queue.
+"""Native C++ runtime: TCPStore rendezvous, bounded queue.
 
 Parity targets: paddle/fluid/distributed/store/tcp_store.cc (store ops
 exercised client/server over loopback, like test/collective's store tests),
-host tracer -> chrome trace, buffered-reader-style queue.
+buffered-reader-style queue.
 """
-import json
 import os
 import threading
 
 import pytest
 
-from paddle_tpu.core.native import (NativeQueue, NativeTracer, TCPStore,
+from paddle_tpu.core.native import (NativeQueue, TCPStore,
                                     TCPStoreServer, load_native)
 
 pytestmark = pytest.mark.skipif(load_native() is None,
@@ -74,25 +73,6 @@ class TestTCPStore:
         for _, peers in results:
             assert peers == [f"addr-{j}" for j in range(world)]
         srv.stop()
-
-
-class TestNativeTracer:
-    def test_spans_to_chrome_trace(self, tmp_path):
-        tr = NativeTracer()
-        assert tr.available
-        tr.enable(True)
-        tr.begin("outer")
-        tr.begin("inner")
-        tr.end()
-        tr.end()
-        assert tr.count() == 2
-        p = str(tmp_path / "trace.json")
-        assert tr.dump(p)
-        data = json.load(open(p))
-        names = {e["name"] for e in data["traceEvents"]}
-        assert names == {"outer", "inner"}
-        assert all(e["dur"] >= 0 for e in data["traceEvents"])
-        tr.enable(False)
 
 
 class TestNativeQueue:
