@@ -126,7 +126,7 @@ def bench_gpt2(peak_tflops):
         opt.clear_grad()
         return loss
 
-    # donation follows to_static's default (PADDLE_TPU_DONATE=1 opts in)
+    # to_static at its default: the state is donated
     train_step = paddle.jit.to_static(_step)
 
     # First call traces with slot creation (state superset), second call

@@ -9,7 +9,8 @@ comes out by the repo's own means.
     8 x 1024 on one fixed batch from a seed. Losses finite, the first within
     5% of ln(vocab), the last below the first; the flash-attention Pallas
     kernel compiled into the step (``tpu_custom_call`` in the lowered text).
-    Then one more step built with ``donate_state=True``.
+    The state is donated (``to_static``'s default); then one more step built
+    with ``donate_state=False``, the opt-out.
   * Server — ``FusedMultiTransformer(768, 12, 3072, 12 layers)`` + embedding
     + head in bf16 behind ``ServingEngine`` (8 slots, 1024 positions, paged
     KV) behind ``Gateway(Router([LocalReplica]))``: completions POSTed over
@@ -212,21 +213,27 @@ def phase_trainer():
     check(spy.call_count > 0, "flash_attention was never dispatched (the XLA "
           "composite in nn/functional/attention.py ran instead)")
     _check_mosaic("trainer", step.lower(x, y).as_text())
+    last = paddle.jit.call_timeline()[-1]
+    check(last["donated"] > 0, "the steady step donated no state leaf: "
+          "to_static's default donates them")
     say(f"[trainer] flash_attention traced {spy.call_count}x; compile+first "
         f"run {secs[0]:.1f} s (slot-creation trace) + {secs[1]:.1f} s "
         f"(steady signature); steady step "
         f"{1e3 * float(np.median(secs[2:])):.1f} ms median of "
-        f"{len(secs) - 2} (smoke timing, not a benchmark)")
+        f"{len(secs) - 2} (smoke timing, not a benchmark); state leaves "
+        f"donated {last['donated']}, kept {last['kept']}")
 
-    # to_static keeps donation off by default; does a donated step start?
-    donated = paddle.jit.to_static(eager, donate_state=True)
+    # to_static donates by default; does the opt-out still start?
+    undonated = paddle.jit.to_static(eager, donate_state=False)
     t0 = time.perf_counter()
-    loss = donated(x, y)
+    loss = undonated(x, y)
     jax.block_until_ready(loss._data)
     val = float(np.asarray(loss._data, np.float32))
-    check(math.isfinite(val), "donated step: loss not finite")
-    say(f"[trainer] donated step (donate_state=True) ran: yes, loss "
-        f"{val:.4f} ({time.perf_counter() - t0:.1f} s with its compile)")
+    check(math.isfinite(val), "undonated step: loss not finite")
+    last = paddle.jit.call_timeline()[-1]
+    say(f"[trainer] undonated step (donate_state=False) ran: yes, loss "
+        f"{val:.4f}, donated {last['donated']}, kept {last['kept']} "
+        f"({time.perf_counter() - t0:.1f} s with its compile)")
 
 
 # ------------------------------------------------------------------ server

@@ -8,7 +8,9 @@ the static graph, so ``to_static`` compiles the *same eager code* by tracing:
   2. build a pure function (state_in, args) -> (out, state_out) that binds
      tracers into those tensors and runs the user fn — the eager tape,
      ``backward()`` and ``optimizer.step()`` all work under tracing,
-  3. jax.jit it with donated state (in-place buffer reuse on TPU),
+  3. jax.jit it with the state DONATED: each state result takes its input's
+     buffer, so a call allocates nothing for them and the pre-step arrays
+     are consumed (``to_static(fn, donate_state=False)`` keeps them valid),
   4. write the updated state back after each call.
 
 This turns a dygraph train step into ONE fused XLA program: the per-op
@@ -25,6 +27,7 @@ clock, and leaves one record in a ``telemetry.Telemetry`` step ring
 """
 from __future__ import annotations
 
+import collections
 import functools
 import os
 import pickle
@@ -54,9 +57,11 @@ def call_timeline():
     ``fresh`` (this call traced and compiled a new entry), and host seconds
     ``call_s`` (all of the call), ``key_s`` (building the entry key),
     ``dur_s`` (the jitted call alone) and ``writeback_s`` (everything after
-    it returned). The newest ``PADDLE_TELEMETRY_RING`` calls are kept
-    (default 2048); with the ring at 0 the list is empty and a call reads no
-    clock."""
+    it returned), and ``donated`` / ``kept``: how many persistent-state
+    leaves this call handed to the program to consume and how many it did not
+    (``StaticFunction._entry_key`` says which). The newest
+    ``PADDLE_TELEMETRY_RING`` calls are kept (default 2048); with the ring at
+    0 the list is empty and a call reads no clock."""
     return list(_timeline.steps)
 
 
@@ -161,20 +166,50 @@ def _constrain_to_spec(t, arr):
         return arr
 
 
+def _undonatable(state, arg_arrays):
+    """Indices of the state leaves whose arrays a call must not donate
+    (``StaticFunction._entry_key`` says why), ascending."""
+    arrays = [t._data for t in state]
+    uses = collections.Counter(map(id, arrays))
+    uses.update(map(id, arg_arrays))    # an argument's array: a second use
+    dead = {tp for tp in set(map(type, arrays))
+            if not issubclass(tp, jax.Array) or issubclass(tp, jax.core.Tracer)}
+    return tuple(i for i, a in enumerate(arrays)
+                 if uses[id(a)] > 1 or type(a) in dead)
+
+
+def _split(state_arrays, kept):
+    """(donated, kept): the state's arrays as the two list arguments of a
+    compiled entry, ``kept`` the ascending indices of the second."""
+    held = set(kept)
+    return ([a for i, a in enumerate(state_arrays) if i not in held],
+            [state_arrays[i] for i in kept])
+
+
+def _merge(donated, kept_arrays, kept):
+    """``_split``'s two lists back in the state's order."""
+    held, rest = dict(zip(kept, kept_arrays)), iter(donated)
+    return [held[i] if i in held else next(rest)
+            for i in range(len(donated) + len(kept))]
+
+
 class StaticFunction:
-    """Compiled wrapper around an eager function (dygraph → XLA program)."""
+    """Compiled wrapper around an eager function (dygraph → XLA program).
+
+    A compiled call OWNS the persistent state for its duration: it donates
+    the state's buffers to the program, which updates them in place, so an
+    array taken from a parameter before the call (``p.detach()``,
+    ``p._data``) is deleted by it; the tensors themselves, ``numpy()``,
+    ``clone()`` and ``state_dict()`` are not affected.
+    ``donate_state=False`` keeps the pre-step arrays valid, at the cost of
+    a second copy of the state and one allocation per leaf and call."""
 
     def __init__(self, fn: Callable, input_spec=None, build_strategy=None,
                  backend=None, donate_state: bool = None, static_argnames=None):
-        if donate_state is None:
-            # default off; opt in per-function or via env (chip_smoke.py
-            # runs one donated step on the chip — see CHANGES.md PR 21)
-            import os
-            donate_state = os.environ.get("PADDLE_TPU_DONATE") == "1"
         functools.update_wrapper(self, fn)
         self._fn = fn
         self._input_spec = input_spec
-        self._donate_state = donate_state
+        self._donate_state = donate_state is None or bool(donate_state)
         self._cache: dict = {}
         self._bound_instance = None
         # what the spans and the timeline call this function
@@ -199,21 +234,32 @@ class StaticFunction:
         return self._run(args, kwargs)
 
     def _entry_key(self, args, kwargs, k=None):
-        """(arg arrays, persistent state, arg spec, compiled-entry key);
-        ``k`` is ``run_steps``' scan length."""
+        """(arg arrays, persistent state, indices of the state leaves NOT to
+        donate, arg spec, compiled-entry key); ``k`` is ``run_steps``' scan
+        length.
+
+        A leaf is kept when donating it would be an error or a lie: its
+        array is also a call argument (``step(model.weight)``), is shared
+        with another state leaf (a registered ``detach()``), or is not a
+        live device array (``None``, a NumPy value, a tracer of an outer
+        trace); with ``donate_state=False`` every leaf is. The split is
+        part of the key: when it changes, the call retraces once."""
         arg_tensors, spec = _tree_flatten_args(args, kwargs)
         if k is not None:
             _check_stacked(arg_tensors, k)
         arg_arrays = [t._data for t in arg_tensors]
         state = persistent_tensors()
+        kept = (_undonatable(state, arg_arrays) if self._donate_state
+                else tuple(range(len(state))))
         key = (
             tuple((tuple(a.shape), str(a.dtype)) for a in arg_arrays),
             tuple(id(t) for t in state),
+            kept,
             _spec_key(spec),
         )
         if k is not None:
             key = ("scan", k) + key
-        return arg_arrays, state, spec, key
+        return arg_arrays, state, kept, spec, key
 
     def lower(self, *args, **kwargs):
         """The jax ``Lowered`` of the compiled entry these arguments
@@ -221,13 +267,14 @@ class StaticFunction:
         lowers to (is a Pallas kernel, ``tpu_custom_call``, in it?),
         ``.compile().as_text()`` the collectives the compiler put in.
         The entry must exist: call the step with these arguments first."""
-        arg_arrays, state, _, key = self._entry_key(args, kwargs)
+        arg_arrays, state, kept, _, key = self._entry_key(args, kwargs)
         entry = self._cache.get(key)
         if entry is None:
             raise RuntimeError(
                 "to_static.lower: no compiled entry for these arguments "
                 "and the current persistent state; call the step first")
-        return entry[0].lower([t._data for t in state], arg_arrays)
+        return entry[0].lower(
+            *_split([t._data for t in state], kept), arg_arrays)
 
     def _make_pure(self, state, spec, out_spec_box, state_after_box):
         """(state_arrays, arg_arrays) -> (out_arrays, new_state): bind the
@@ -266,15 +313,16 @@ class StaticFunction:
         with TraceAnnotation("to_static.call", step=n, fn=self._qualname):
             t0 = clock()
             with TraceAnnotation("to_static.key"):
-                call_arrays, state, spec, key = self._entry_key(
+                call_arrays, state, kept, spec, key = self._entry_key(
                     args, kwargs, k)
             t1 = clock()
             entry = self._cache.get(key)
             fresh = entry is None
             if fresh:
-                entry = self._build(state, spec, key, k)
+                entry = self._build(state, kept, spec, key, k)
             jitted, out_spec_box, state_after_box = entry
             state_arrays = [t._data for t in state]
+            donated_arrays, kept_arrays = _split(state_arrays, kept)
             saved_nodes = _tape.nodes[:]
             saved_grads = [(t, t.grad) for t in state]
 
@@ -289,11 +337,13 @@ class StaticFunction:
             try:
                 with TraceAnnotation("to_static.trace_compile" if fresh
                                      else "to_static.dispatch"):
-                    out_arrays, new_state = jitted(state_arrays, call_arrays)
+                    out_arrays, new_state = jitted(
+                        donated_arrays, kept_arrays, call_arrays)
             except BaseException as e:
                 restore()
                 if isinstance(e, Exception):
-                    self._failed(e, state, entry, key, fresh, k is not None)
+                    self._failed(e, state, donated_arrays, entry, key, fresh,
+                                 k is not None)
                 raise
             t3 = clock()
             with TraceAnnotation("to_static.writeback"):
@@ -305,26 +355,32 @@ class StaticFunction:
                 # signature.
                 for t, arr in zip(state_after_box[0] or state, new_state):
                     t._data = arr
-                # The pre-step arrays are released HERE, together, and not
-                # one by one inside the loop above (which they would be
-                # without this list). Keep it so: on the v5e releasing them
-                # in the loop costs the next call's result allocation 5-8%
-                # of a gpt2_124m step (PERF.md section 6, PR 25).
-                del state_arrays
+                # The donated pre-step arrays were consumed by the call and
+                # own no buffer now. The others (kept leaves, all of them
+                # under donate_state=False) are released HERE, together, and
+                # not one by one inside the loop above: on the v5e that order
+                # cost the next call's result allocation 5-8% of an undonated
+                # gpt2_124m step (PERF.md section 6, PR 25).
+                del state_arrays, donated_arrays, kept_arrays
                 out = _unflatten_out(out_spec_box[0], out_arrays)
             if fresh:
                 _telemetry.runtime_counter(
                     "paddle_to_static_compiles_total", 1)
+            n_kept = len(kept)
+            n_donated = len(state) - n_kept
+            _telemetry.runtime_counter(
+                "paddle_to_static_donated_leaves_total", n_donated)
             if timed:
                 t4 = clock()
                 _telemetry.runtime_histogram(
                     "paddle_to_static_call_seconds").observe(t4 - t0)
                 _timeline.step_event(
                     "to_static", t0, t3 - t2, call_s=t4 - t0, key_s=t1 - t0,
-                    writeback_s=t4 - t3, fresh=fresh, fn=self._qualname, n=n)
+                    writeback_s=t4 - t3, fresh=fresh, fn=self._qualname, n=n,
+                    donated=n_donated, kept=n_kept)
         return out
 
-    def _failed(self, e, state, entry, key, fresh, scan):
+    def _failed(self, e, state, donated_arrays, entry, key, fresh, scan):
         """The jitted call raised ``e``: roll back what its trace created,
         and raise the error the caller should see if it is not ``e``."""
         # Persistent tensors CREATED during the failed trace/compile
@@ -352,38 +408,43 @@ class StaticFunction:
                 "lazily-built optimizer slots) inside the scan body; "
                 "call the step function once normally before run_steps "
                 "so state is steady.") from e
-        if self._donate_state:
-            # execution-time failure after donation: the restored arrays
-            # may already be deleted — say so instead of surfacing a
-            # bare "Array has been deleted" later
+        if any(a.is_deleted() for a in donated_arrays):
+            # the program started and consumed its donated state (a failure
+            # in tracing or compiling consumes nothing, and the rollback
+            # above is complete): the restored arrays are deleted — say so
+            # instead of surfacing a bare "Array has been deleted" later
             raise RuntimeError(
                 "to_static step failed after state buffers were donated; "
                 "persistent state may be invalid. Re-create the model/"
                 "optimizer or use to_static(donate_state=False) for "
                 "rollback-on-error semantics.") from e
 
-    def _build(self, state, spec, key, k=None):
+    def _build(self, state, kept, spec, key, k=None):
         out_spec_box = [None]
         state_after_box = [None]
-        pure = self._make_pure(state, spec, out_spec_box, state_after_box)
+        step = self._make_pure(state, spec, out_spec_box, state_after_box)
 
-        def scanned(state_arrays, stacked):
+        # the compiled module keeps its name in traces: jit_pure
+        def pure(donated_arrays, kept_arrays, arg_arrays):
+            return step(_merge(donated_arrays, kept_arrays, kept), arg_arrays)
+
+        def scanned(donated_arrays, kept_arrays, stacked):
             def body(carry, xs):
-                out_arrays, new_state = pure(carry, list(xs))
+                out_arrays, new_state = step(carry, list(xs))
                 return new_state, out_arrays
-            final_state, outs = jax.lax.scan(body, state_arrays,
-                                             tuple(stacked), length=k)
+            final_state, outs = jax.lax.scan(
+                body, _merge(donated_arrays, kept_arrays, kept),
+                tuple(stacked), length=k)
             return outs, final_state
 
-        # donate the state buffers: params/optimizer slots update in place
-        # (XLA aliases input->output), halving steady-state HBM traffic for
-        # the weight update; callers never read the pre-step arrays again
-        # (writeback replaces every tensor's _data with the outputs).
-        # Opt out with to_static(donate_state=False) to keep pre-step arrays
-        # valid (e.g. external references, or rollback-on-error semantics).
-        donate = (0,) if self._donate_state else ()
+        # The first argument is donated: params/optimizer slots update in
+        # place (XLA aliases each state result to its input's buffer), so
+        # PjRt allocates nothing for them and the next call can be enqueued
+        # while this one runs. Which leaves are in it is _entry_key's
+        # decision (none under donate_state=False); writeback replaces
+        # every tensor's _data with the outputs.
         jitted = jax.jit(pure if k is None else scanned,
-                         donate_argnums=donate)
+                         donate_argnums=(0,))
         entry = (jitted, out_spec_box, state_after_box)
         self._cache[key] = entry
         return entry

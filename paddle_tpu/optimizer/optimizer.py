@@ -229,7 +229,10 @@ class Optimizer:
             def run(grads, lr_t):
                 self._step_core(list(zip(params, grads)), lr_t._data)
                 return Tensor(jnp.zeros((), jnp.float32))
-            fn = cache[key] = to_static(run)
+            # not donated: an eager opt.step() does not own the state as a
+            # compiled step does; the tape and user code may still hold the
+            # parameter arrays of the forward pass behind these gradients
+            fn = cache[key] = to_static(run, donate_state=False)
             self._fused_fn = fn          # introspection/debug handle
         fn([g for _, g in params_grads],
            Tensor(jnp.asarray(lr, jnp.float32)))

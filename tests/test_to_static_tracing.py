@@ -158,6 +158,190 @@ def test_a_failed_call_leaves_no_record_and_no_compile(timeline):
     assert not broken._cache            # the fresh entry was evicted
 
 
+# ------------------------------------------------------------------ donation
+def _lin_step(donate_state=None, seed=0, forward_only=False):
+    """(layer, compiled step): a training step past its two compiles, or a
+    forward pass past its one."""
+    paddle.seed(seed)
+    lin = paddle.nn.Linear(4, 4)
+    opt = paddle.optimizer.AdamW(learning_rate=1e-2,
+                                 parameters=lin.parameters())
+
+    def train_step(x):
+        loss = (lin(x) ** 2).mean()
+        loss.backward()
+        opt.step()
+        opt.clear_grad()
+        return loss
+
+    step = paddle.jit.to_static(lin.forward if forward_only else train_step,
+                                donate_state=donate_state)
+    for _ in range(1 if forward_only else 2):
+        step(_x())
+    return lin, step
+
+
+def _leaves():
+    """How many persistent tensors the next compiled call threads (the
+    registry is the process's: other tests' survivors count)."""
+    import gc
+    from paddle_tpu.tensor.tensor import persistent_tensors
+    gc.collect()
+    return len(persistent_tensors())
+
+
+def _default():
+    lin, step = _lin_step()
+    return lin, step, (_x(),), 0, True
+
+
+def _opted_out():
+    lin, step = _lin_step(donate_state=False)
+    return lin, step, (_x(),), _leaves(), False
+
+
+def _state_as_argument():
+    """``step(lin.bias)``: the bias array is a state leaf and an argument;
+    donating it would hand the program a buffer it was also told to read."""
+    paddle.seed(0)
+    lin = paddle.nn.Linear(4, 4)
+
+    @paddle.jit.to_static
+    def step(b):
+        lin.weight.set_value(lin.weight + b.reshape([1, 4]))
+        return b * 2.0
+    return lin, step, (lin.bias,), 1, True
+
+
+def _two_leaves_one_array():
+    """A registered ``detach()`` shares its source's array: neither may be
+    donated, each comes back with its own result."""
+    from paddle_tpu.tensor.tensor import register_persistent
+    lin, step = _lin_step()
+    twin = lin.weight.detach()
+    register_persistent(twin)
+    lin.twin = twin                     # lives as long as the layer
+    return lin, step, (_x(),), 2, False
+
+
+@pytest.mark.parametrize("case", [_default, _opted_out, _state_as_argument,
+                                  _two_leaves_one_array])
+def test_a_call_donates_the_state_it_may_and_keeps_the_rest(timeline, case):
+    lin, step, args, kept, weight_consumed = case()
+    n = _leaves()
+    donated0 = _counter("paddle_to_static_donated_leaves_total")
+    held = lin.weight._data             # the pre-step array
+    before = np.asarray(held).copy()
+    bias = np.asarray(lin.bias._data).copy()
+    out = step(*args)
+    rec = jit.call_timeline()[-1]
+    assert (rec["donated"], rec["kept"]) == (n - kept, kept)
+    assert (_counter("paddle_to_static_donated_leaves_total") - donated0
+            == n - kept)
+    assert held.is_deleted() == weight_consumed
+    if not weight_consumed:
+        np.testing.assert_array_equal(np.asarray(held), before)
+    if case is _state_as_argument:
+        np.testing.assert_array_equal(np.asarray(out._data), 2.0 * bias)
+        np.testing.assert_allclose(np.asarray(lin.weight._data),
+                                   before + bias.reshape(1, 4))
+        np.testing.assert_array_equal(np.asarray(lin.bias._data), bias)
+    else:
+        assert np.isfinite(float(out))
+        assert not np.array_equal(np.asarray(lin.weight._data), before)
+    if case is _two_leaves_one_array:
+        # the twin passed through unchanged, and is its own array now:
+        # the next call donates both (one retrace, by the key)
+        np.testing.assert_array_equal(np.asarray(lin.twin._data), before)
+        step(*args)
+        rec = jit.call_timeline()[-1]
+        assert rec["fresh"] and (rec["donated"], rec["kept"]) == (n, 0)
+
+
+def test_a_forward_only_call_hands_every_parameter_back(timeline):
+    lin, forward = _lin_step(forward_only=True)
+    want = [np.asarray(p._data).copy() for p in lin.parameters()]
+    first = np.asarray(forward(_x())._data)
+    for _ in range(3):
+        held = lin.weight._data
+        np.testing.assert_array_equal(np.asarray(forward(_x())._data), first)
+        assert held.is_deleted()
+        for p, w in zip(lin.parameters(), want):
+            np.testing.assert_array_equal(np.asarray(p._data), w)
+    assert jit.call_timeline()[-1]["kept"] == 0
+
+
+def test_run_steps_donates_and_matches_single_steps(timeline):
+    lin1, step1 = _lin_step(seed=5)
+    lin2, step2 = _lin_step(seed=5)
+    xs = np.random.RandomState(0).randn(3, 2, 4).astype(np.float32)
+    single = [float(step1(paddle.to_tensor(xs[i]))) for i in range(3)]
+    held = lin2.weight._data
+    scanned = step2.run_steps(3, paddle.to_tensor(xs))
+    rec = jit.call_timeline()[-1]
+    assert held.is_deleted() and rec["kept"] == 0
+    assert rec["donated"] == _leaves()
+    np.testing.assert_allclose(np.asarray(scanned._data), single,
+                               rtol=1e-6, atol=1e-7)
+    for p, q in zip(lin1.parameters(), lin2.parameters()):
+        np.testing.assert_allclose(np.asarray(p._data), np.asarray(q._data),
+                                   rtol=1e-6, atol=1e-7)
+
+
+def test_donated_steps_match_undonated_ones_bit_for_bit(timeline):
+    def losses(donate_state):
+        _, step = _lin_step(donate_state=donate_state, seed=9)
+        return [np.asarray(step(_x())._data).tobytes() for _ in range(3)]
+    assert losses(None) == losses(False)
+
+
+def test_the_error_after_a_failure_says_what_was_consumed(timeline):
+    """A failure while tracing consumed nothing: the state is as it was and
+    the error is the function's own. A failure of the running program, after
+    the buffers were handed over, says that the state is gone."""
+    lin, step = _lin_step()
+    held = lin.weight._data
+    before = np.asarray(held).copy()
+
+    @paddle.jit.to_static
+    def broken(x):
+        lin.weight.set_value(lin.weight * 0.0)
+        raise ValueError("inside the trace")
+    with pytest.raises(ValueError, match="inside the trace"):
+        broken(_x())
+    assert lin.weight._data is held and not held.is_deleted()
+    np.testing.assert_array_equal(np.asarray(held), before)
+    step(_x())                          # and the good step still runs
+
+    key, (jitted, *boxes) = list(step._cache.items())[-1]    # the steady one
+
+    def fails_on_the_device(*a):
+        jitted(*a)
+        raise RuntimeError("the program failed while running")
+    step._cache[key] = (fails_on_the_device, *boxes)
+    with pytest.raises(RuntimeError, match="state buffers were donated"):
+        step(_x())
+
+
+def test_the_eager_optimizer_step_consumes_nothing():
+    """``opt.step()`` in eager mode compiles its update through
+    ``to_static`` but does not own the state: an array held from before
+    it stays readable."""
+    paddle.seed(0)
+    lin = paddle.nn.Linear(4, 4)
+    opt = paddle.optimizer.AdamW(learning_rate=1e-2,
+                                 parameters=lin.parameters())
+    for _ in range(3):
+        held = lin.weight.detach()
+        before = held.numpy().copy()
+        (lin(_x()) ** 2).mean().backward()
+        opt.step()
+        opt.clear_grad()
+        np.testing.assert_array_equal(held.numpy(), before)
+        assert not np.array_equal(lin.weight.numpy(), before)
+    assert opt._fused_fn is not None    # the compiled path is what ran
+
+
 # ---------------------------------------------------------- the shared clock
 def _host_events(trace_dir):
     """``{(plane, line): [(name, start_ns, end_ns, stats)]}`` of the newest

@@ -148,7 +148,7 @@ def _build_parts():
     return model, opt, args, loss_call, body_call, batch * seq
 
 
-def _build_step(donate):
+def _build_step():
     """Bench-identical train step; returns (step, args, tokens/step)."""
     import paddle_tpu as paddle
     model, opt, args, loss_call, _body, tokens = _build_parts()
@@ -160,7 +160,7 @@ def _build_step(donate):
         opt.clear_grad()
         return loss
 
-    step = paddle.jit.to_static(_step, donate_state=donate)
+    step = paddle.jit.to_static(_step)
     return step, args, tokens
 
 
@@ -170,8 +170,7 @@ def _drain(loss):
 
 def profile_trace(outdir, steps):
     import jax
-    step, args, _ = _build_step(donate=os.environ.get(
-        "PADDLE_TPU_DONATE", "1") == "1")
+    step, args, _ = _build_step()
     for _ in range(3):
         loss = step(*args)
     _drain(loss)
