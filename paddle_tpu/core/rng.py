@@ -97,6 +97,11 @@ def get_rng_state():
     return _rng.ensure()._data
 
 
+def get_rng_tensor():
+    """The persistent tensor that holds the global key."""
+    return _rng.ensure()
+
+
 def set_rng_state(state):
     t = _rng.ensure()
     if isinstance(state, int):

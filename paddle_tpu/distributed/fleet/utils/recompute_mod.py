@@ -8,15 +8,19 @@ enabled and backprops through the sub-tape — parameter gradients accumulate
 into .grad exactly as in the reference's RecomputeFunction.backward. RNG
 replay is exact because the global PRNG key is snapshotted and restored
 (explicit keys — stronger than the reference's CUDA RNG state juggling).
-Under paddle.jit.to_static the same code traces into XLA remat regions.
+Under paddle.jit.to_static the replay sits behind an optimization barrier,
+so that XLA recomputes and does not merge it with the first forward.
 """
 from __future__ import annotations
 
 from typing import Callable
 
-from ....core.rng import get_rng_state, set_rng_state
-from ....tensor.tensor import (Tensor, _TapeNode, _tape, enable_grad,
-                               is_grad_enabled, no_grad)
+import jax
+
+from ....core.rng import get_rng_state, get_rng_tensor, set_rng_state
+from ....tensor.tensor import (Parameter, Tensor, _TapeNode, _tape,
+                               enable_grad, is_grad_enabled, no_grad,
+                               persistent_tensors)
 from ....autograd.backward_engine import run_backward
 
 __all__ = ["recompute", "recompute_sequential", "RecomputeFunction"]
@@ -47,14 +51,29 @@ def recompute(function: Callable, *args, **kwargs):
             set_rng_state(rng_snapshot)
         detached = []
         rebuilt = list(args)
-        for i, t in zip(tensor_positions, tensor_inputs):
-            d = Tensor(t._data, stop_gradient=t.stop_gradient)
+        # Under jit the replayed forward is, to XLA, the first forward over
+        # again, and common-subexpression elimination would merge the two
+        # and keep every activation alive after all. Behind the barrier the
+        # replay's inputs are new values that exist only once the cotangents
+        # do (jax.checkpoint's own device).
+        held, cots = jax.lax.optimization_barrier(
+            ([t._data for t in tensor_inputs], list(cots)))
+        for i, t, arr in zip(tensor_positions, tensor_inputs, held):
+            d = Tensor(arr, stop_gradient=t.stop_gradient)
             d._is_leaf = True
             detached.append(d)
             rebuilt[i] = d
         mark = len(_tape.nodes)
+        # The replay is the first forward over again: state that a layer
+        # moves in its forward (counters, running statistics) keeps what the
+        # first forward left there. The RNG key has its own rule above.
+        key = get_rng_tensor()
+        moved = [(t, t._data) for t in persistent_tensors()
+                 if t is not key and not isinstance(t, Parameter)]
         with enable_grad():
             out2 = function(*rebuilt, **kwargs)
+        for t, data in moved:
+            t._data = data
         outs2 = tuple(out2) if isinstance(out2, (tuple, list)) else (out2,)
         seeds = [Tensor(c) for c in cots]
         run_backward(list(outs2), seeds, retain_graph=True)

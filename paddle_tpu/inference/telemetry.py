@@ -521,6 +521,7 @@ class Telemetry:
 # into runtime_prometheus() for engine-less processes.
 _runtime_hists: dict = {}
 _runtime_counters: dict = {}
+_runtime_collectors: list = []
 
 
 def runtime_histogram(name, lo=1e-6, hi=1e3):
@@ -533,6 +534,16 @@ def runtime_histogram(name, lo=1e-6, hi=1e3):
 def runtime_counter(name, inc=0):
     _runtime_counters[name] = _runtime_counters.get(name, 0) + inc
     return _runtime_counters[name]
+
+
+def runtime_collector(fn):
+    """Register ``fn() -> {counter name: value}``, read at every
+    exposition: for counts that live somewhere a scrape has to fetch them
+    from (the expert layers' routing counts live on the device). Returns
+    ``fn``; registering it again changes nothing."""
+    if fn not in _runtime_collectors:
+        _runtime_collectors.append(fn)
+    return fn
 
 
 def runtime_registry_snapshot():
@@ -581,10 +592,13 @@ def runtime_prometheus():
                      "counter")
         lines.append("paddle_runtime_watchdog_peer_failures_total "
                      f"{g['peer_failures_total']}")
-    for name in sorted(_runtime_counters):
+    counters = dict(_runtime_counters)
+    for collect in _runtime_collectors:
+        counters.update(collect())
+    for name in sorted(counters):
         lines.append(f"# HELP {name} {name}")
         lines.append(f"# TYPE {name} counter")
-        lines.append(f"{name} {_runtime_counters[name]}")
+        lines.append(f"{name} {counters[name]}")
     for name in sorted(_runtime_hists):
         lines.extend(_runtime_hists[name].prometheus_lines(name))
     return lines

@@ -6,3 +6,4 @@ from .norm import *  # noqa: F401,F403
 from .loss import *  # noqa: F401,F403
 from .pooling import *  # noqa: F401,F403
 from .attention import *  # noqa: F401,F403
+from .linear_attention import *  # noqa: F401,F403

@@ -26,6 +26,9 @@ def _sdpa_ref(q, k, v, mask, dropout_p, causal, scale, dropout_key=None):
     qt = jnp.swapaxes(q, 1, 2)  # [B,H,S,D]
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)
+    if kt.shape[1] != qt.shape[1]:  # GQA: each KV head serves a group
+        kt = jnp.repeat(kt, qt.shape[1] // kt.shape[1], axis=1)
+        vt = jnp.repeat(vt, qt.shape[1] // vt.shape[1], axis=1)
     s = scale if scale is not None else (q.shape[-1] ** -0.5)
     logits = jnp.einsum("bhqd,bhkd->bhqk", qt, kt) * s
     logits = logits.astype(jnp.float32)

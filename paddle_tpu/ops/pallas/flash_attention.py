@@ -47,13 +47,15 @@ def is_supported(q_shape, dtype) -> bool:
     return jnp.dtype(dtype) in (jnp.float32, jnp.bfloat16, jnp.float16)
 
 
-def _block_sizes(sq: int, sk: int):
+def _block_sizes(sq: int, sk: int, d: int = 64):
     """1024-wide tiles (default cap): the [bq,d]x[d,bk] and [bq,bk]x[bk,d]
     dots must be large enough to fill the MXU pipeline — 128x128 tiles
     measure ~5-9 TFLOP/s on v5e, 512x512 ~12, 1024x1024 ~16 (r3 s4 sweep:
     fwd+bwd 4.76 -> 3.56 ms/layer at the GPT-2 headline shape; headline
     step 91.7 -> 86.6 ms). VMEM per program at 1024 tiles is ~6 MB
-    (s/p [1024,1024] f32 + q/k/v/acc tiles), still < the ~16 MB budget."""
+    (s/p [1024,1024] f32 + q/k/v/acc tiles), still < the ~16 MB budget.
+    Past head_dim 128 the default cap is 512: at 256 the dk/dv kernel's
+    1024 tiles ask for 17.05 MB of the 16 MB the v5e's compiler grants."""
     def pick(n, cap):
         if n < cap:
             return max(8, 1 << (n - 1).bit_length())
@@ -76,8 +78,9 @@ def _block_sizes(sq: int, sk: int):
         v = min(max(v, 8), 4096)
         return 1 << (v.bit_length() - 1)
 
-    return (pick(sq, cap_from_env("PADDLE_TPU_FLASH_BQ", 1024)),
-            pick(sk, cap_from_env("PADDLE_TPU_FLASH_BK", 1024)))
+    cap = 1024 if d <= 128 else 512
+    return (pick(sq, cap_from_env("PADDLE_TPU_FLASH_BQ", cap)),
+            pick(sk, cap_from_env("PADDLE_TPU_FLASH_BK", cap)))
 
 
 # ---------------------------------------------------------------------------
@@ -624,8 +627,8 @@ def _dropout_mask(seed, shape, dropout_p):
     return keep.astype(jnp.float32) / (1.0 - dropout_p)
 
 
-def _padded_sizes(sq, sk):
-    bq, bk = _block_sizes(sq, sk)
+def _padded_sizes(sq, sk, d=64):
+    bq, bk = _block_sizes(sq, sk, d)
     return bq, bk, math.ceil(sq / bq) * bq, math.ceil(sk / bk) * bk
 
 
@@ -642,14 +645,14 @@ def _make_drop(q, k, seed, dropout_p):
         return None
     if not _pallas._interpret():
         return ("prng", seed, dropout_p)
-    bq, bk, sq_p, sk_p = _padded_sizes(q.shape[2], k.shape[2])
+    bq, bk, sq_p, sk_p = _padded_sizes(q.shape[2], k.shape[2], q.shape[3])
     return ("mask",
             _dropout_mask(seed, (q.shape[0], q.shape[1], sq_p, sk_p),
                           dropout_p))
 
 
 def _core_fwd(q, k, v, seed, causal, scale, dropout_p):
-    bq, bk, _, _ = _padded_sizes(q.shape[2], k.shape[2])
+    bq, bk, _, _ = _padded_sizes(q.shape[2], k.shape[2], q.shape[3])
     drop = _make_drop(q, k, seed, dropout_p)
     return _fwd(q, k, v, drop, causal=causal, scale=scale, bq=bq, bk=bk)
 
@@ -661,7 +664,7 @@ def _flash_fwd(q, k, v, seed, causal, scale, dropout_p):
 
 def _flash_bwd(causal, scale, dropout_p, res, g):
     q, k, v, o, lse, seed = res
-    bq, bk, _, _ = _padded_sizes(q.shape[2], k.shape[2])
+    bq, bk, _, _ = _padded_sizes(q.shape[2], k.shape[2], q.shape[3])
     drop = _make_drop(q, k, seed, dropout_p)
     dq, dk, dv = _bwd(q, k, v, o, lse, g, drop, causal=causal, scale=scale,
                       bq=bq, bk=bk)

@@ -203,13 +203,14 @@ def _dense_decode():
 _QKV = (B, 1024, H, D)     # the gpt2_124m train step: 8 x 1024 x 12 x 64
 
 
-def _flash(grad, dropout_p=0.0, qkv=_QKV):
+def _flash(grad, dropout_p=0.0, qkv=_QKV, kv_heads=None):
     from paddle_tpu.ops.pallas.flash_attention import flash_attention
     fn = functools.partial(flash_attention, causal=True,
                            dropout_p=dropout_p)
     if grad:
         fn = _sum_grad(fn, (0, 1, 2))
-    return fn, (_one(qkv, BF),) * 3
+    kv = qkv if kv_heads is None else qkv[:2] + (kv_heads,) + qkv[3:]
+    return fn, (_one(qkv, BF), _one(kv, BF), _one(kv, BF))
 
 
 def _ffn(grad, monkeypatch):
@@ -369,6 +370,13 @@ _CASES = {
     "flash_fwd_bwd_dropout": lambda mp: _flash(True, 0.1),
     # past one block a side the backward is two kernels, dk/dv then dq
     "flash_fwd_bwd_seq4096": lambda mp: _flash(True, qkv=(2, 4096, H, D)),
+    # Qwen3-Next's gated attention at the benchmark's 2 x 8192: head 256
+    # (512-wide tiles: 1024 outgrow VMEM in the dk/dv kernel), 16 query
+    # heads on 2 KV heads, per-query-head dK / dV summed over 8
+    "flash_fwd_gqa_d256_seq8192": lambda mp: _flash(
+        False, qkv=(2, 8192, 16, 256), kv_heads=2),
+    "flash_fwd_bwd_gqa_d256_seq8192": lambda mp: _flash(
+        True, qkv=(2, 8192, 16, 256), kv_heads=2),
     "fused_ffn_fwd": lambda mp: _ffn(False, mp),
     "fused_ffn_bwd": lambda mp: _ffn(True, mp),
     "layer_norm_fwd": lambda mp: _layer_norm(False),
