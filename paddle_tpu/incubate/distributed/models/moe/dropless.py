@@ -171,10 +171,17 @@ class DroplessMoELayer(Layer):
             a = a.reshape(b * s, d)
             total = jnp.take_along_axis(p, chosen, axis=1).sum(-1)
             w = p[token, expert] / total[token]
-            y = _swiglu(a[token], w_gate_up, w_down,        # [rows, d]
+            # The buffer's rows past the held pairs are pairs of real tokens
+            # with experts not held here, and the grouped product leaves
+            # those rows unwritten, of its result and of its input's
+            # gradient alike (zeros on the CPU, whatever the memory held on
+            # the TPU). A select on each side keeps them out of the sum and
+            # out of the tokens' gradients, whatever they hold.
+            y = _swiglu(jnp.where(valid[:, None], a[token], 0),  # [rows, d]
+                        w_gate_up, w_down,
                         lambda u, v: jax.lax.ragged_dot(
                             u, v, group, preferred_element_type=_F32))
-            y = jnp.where(valid[:, None], y * w[:, None], 0.0)
+            y = jnp.where(valid[:, None], y, 0.0) * w[:, None]
             out = jnp.zeros((b * s, d), _F32).at[token].add(y)
             return out.astype(a.dtype).reshape(b, s, d)
 

@@ -33,6 +33,7 @@ import jax.numpy as jnp
 from ..core.rng import next_key
 from ..incubate.distributed.models.moe.dropless import DroplessMoELayer
 from ..nn import functional as F
+from ..nn.functional.linear_attention import TILE_ROWS, causal_conv
 from ..nn.initializer import Normal
 from ..nn.layer.common import Embedding, Linear
 from ..nn.layer.layers import Layer, LayerList
@@ -143,20 +144,39 @@ class Qwen3NextGatedDeltaNet(Layer):
         projections' results, the convolution, the rule's result): each is
         read once by a float32 computation (a norm, a sigmoid, a running
         sum), and a bf16 round trip there costs a third of the mixer's
-        distance from the float32 reference (PERF.md section 6, PR 28)."""
+        distance from the float32 reference (PERF.md section 6, PR 28).
+
+        One layout from the projections to ``out_proj``: every large array
+        is ``[B, T, heads * 128]``, the sequence in the sublanes and a
+        head's features in the lanes (``linear_attention``'s docstring).
+        So each consumer's projection is taken from its own columns of the
+        ONE fused weight (a slice of the 48-MiB weight, not of the 768-MiB
+        product, whose backward would pad and add it back), the convolution
+        runs on ``q``, ``k`` and ``v`` apart, and the ``[B, T, h, d]``
+        operands the rule asks for are reshapes that nothing reads with
+        the heads in the sublanes: the rule moves whole tiles, and the
+        gated norm reduces each head's features through the same view."""
         b, s = x.shape[0], x.shape[1]
         key, value = self.hk * self.dk, self.hv * self.dv
         hv, dv, eps, mm = self.hv, self.dv, self.eps, x.dtype
 
+        hk, dk = self.hk, self.dk
+
         def project(a, w):
             return jnp.matmul(a, w, preferred_element_type=_F32)
-        qkvz = apply_op(project, x, self.in_proj_qkvz.weight)
-        with jax.named_scope("gdn.conv"):
-            qkv = F.causal_conv1d(qkvz[:, :, :2 * key + value],
-                                  self.conv_weight, activation="silu")
-        q = reshape(qkv[:, :, :key], [b, s, self.hk, self.dk])
-        k = reshape(qkv[:, :, key:2 * key], [b, s, self.hk, self.dk])
-        v = reshape(qkv[:, :, 2 * key:], [b, s, hv, dv])
+
+        def front(a, w, cw):
+            """q, k, v after their convolution, and z: ONE op, so that the
+            step's trace holds one node for them and not fourteen."""
+            def conv(lo, hi, heads, d):
+                with jax.named_scope("gdn.conv"):
+                    y = causal_conv(project(a, w[:, lo:hi]), cw[lo:hi], True)
+                return y.reshape(b, s, heads, d)
+            return (conv(0, key, hk, dk), conv(key, 2 * key, hk, dk),
+                    conv(2 * key, 2 * key + value, hv, dv),
+                    project(a, w[:, 2 * key + value:]))
+        q, k, v, z = apply_op(front, x, self.in_proj_qkvz.weight,
+                              self.conv_weight, n_outputs=4)
 
         def gates(a, w, a_log, dt_bias):
             ba = project(a, w)
@@ -170,11 +190,16 @@ class Qwen3NextGatedDeltaNet(Layer):
                                      matmul_dtype=mm)
 
         def gated_norm(o_, z, w):
+            rows = TILE_ROWS if s % TILE_ROWS == 0 else 1
+
+            def tiles(a):       # [B, T, hv (x) dv] -> [B, T/8, hv, 8, dv]
+                return a.reshape(b, s // rows, rows, hv, dv).transpose(
+                    0, 1, 3, 2, 4)
+            o_, z = tiles(o_), tiles(z)
             h = o_ * jax.lax.rsqrt(jnp.mean(o_ * o_, -1, keepdims=True) + eps)
-            y = h * w.astype(_F32) * jax.nn.silu(z.reshape(b, s, hv, dv))
-            return y.astype(mm).reshape(b, s, hv * dv)
-        y = apply_op(gated_norm, o, qkvz[:, :, 2 * key + value:],
-                     self.norm_weight)
+            y = (h * w.astype(_F32) * jax.nn.silu(z)).astype(mm)
+            return y.transpose(0, 1, 3, 2, 4).reshape(b, s, hv * dv)
+        y = apply_op(gated_norm, o, z, self.norm_weight)
         return self.out_proj(y)
 
 

@@ -23,9 +23,29 @@ strong the decay.
 Decays and the solve run in float32; the matrix products take their
 operands in ``matmul_dtype`` (the dtype of ``v`` unless stated; bf16 in a
 bf16 model) and accumulate in float32. The backward pass is JAX's own
-through this code.
+through the rule; the convolution's is written out (``_causal_conv_bwd``).
+
+Layout. The TPU keeps the last two axes of an array in (8, 128) tiles: 8
+rows in the sublanes, 128 columns in the lanes. A ``[B, T, H * 128]``
+array therefore already lies as the rule wants it: the sequence in the
+sublanes, one head's 128 features in the lanes, a head a range of lane
+tiles, a chunk of 64 tokens a range of 8 row tiles. Reshaping it to ``[B,
+T, H, 128]`` is free only as long as nothing asks for ``H`` in the
+sublanes: XLA has no name for "rows of 8, then heads, then the row in the
+tile" on a 4-axis shape, so a reduction over the head's features, a
+transposition that ends ``[..., T', H, 128]`` or a size-2 axis next to
+the features each make it copy the array into another tiling (at 2 x 8192
+tokens, 128 to 256 MiB a copy; PERF.md section 6, PR 29). What is free is
+to split the sequence into ``(T / 8, 8)`` FIRST and move the heads in
+front of the 8: then every step is a move of whole tiles, or no move at
+all. ``_chunk_rule`` forms its blocks that way and returns its result by
+the mirrored path; a caller that works on heads (the layer's gated norm)
+does the same with ``TILE_ROWS``. A kernel for the rule would read the
+same layout through a ``BlockSpec`` of ``(1, chunk, 128)``.
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -39,6 +59,54 @@ _EXACT = jax.lax.Precision.HIGHEST    # float32 products in float32
 # Chunks prepared together and replayed together in the backward pass: the
 # rule's working set is one block's (1024 tokens at chunk 64) whatever T.
 _BLOCK_CHUNKS = 16
+# Rows of one (8, 128) tile (the module docstring's "Layout").
+TILE_ROWS = 8
+
+
+def _shifted(a, k, shift):
+    """The ``k`` views ``a[:, t + j - shift]``, ``j < k``, of ``a`` [B, T, C]
+    in float32, zeros outside the sequence."""
+    t = a.shape[1]
+    a = jnp.pad(a.astype(_F32), ((0, 0), (shift, k - 1 - shift), (0, 0)))
+    return [a[:, j:j + t] for j in range(k)]
+
+
+def _taps(views, w):
+    return sum(x * w[:, j] for j, x in enumerate(views))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def causal_conv(a, w, silu):
+    """``causal_conv1d`` on arrays, for a caller that is inside an op of its
+    own (the Gated DeltaNet layer)."""
+    k = w.shape[1]
+    y = _taps(_shifted(a, k, k - 1), w.astype(_F32))
+    return (jax.nn.silu(y) if silu else y).astype(a.dtype)
+
+
+def _causal_conv_fwd(a, w, silu):
+    return causal_conv(a, w, silu), (a, w)
+
+
+def _causal_conv_bwd(silu, res, dy):
+    """Keeps the input and recomputes the sum (four multiply-adds an
+    element; behind a barrier, or XLA keeps the first sum instead); each
+    tap of the weight's gradient is REDUCED as it is multiplied: JAX's own
+    transpose writes the ``K`` products ``[B, T, C]`` out before it sums
+    them to ``[C, K]``. The input's gradient is the same sum run the other
+    way: taps reversed, zeros after the sequence's end."""
+    a, w = res
+    a, dy = jax.lax.optimization_barrier((a, dy))
+    k, w32, dy = w.shape[1], w.astype(_F32), dy.astype(_F32)
+    views = _shifted(a, k, k - 1)
+    if silu:
+        dy, = jax.vjp(jax.nn.silu, _taps(views, w32))[1](dy)
+    dw = jnp.stack([jnp.sum(x * dy, axis=(0, 1)) for x in views], axis=1)
+    da = _taps(_shifted(dy, k, 0), w32[:, ::-1])
+    return da.astype(a.dtype), dw.astype(w.dtype)
+
+
+causal_conv.defvjp(_causal_conv_fwd, _causal_conv_bwd)
 
 
 def causal_conv1d(x, weight, activation=None, name=None):
@@ -49,16 +117,8 @@ def causal_conv1d(x, weight, activation=None, name=None):
     dtype."""
     if activation not in (None, "silu"):
         raise ValueError(f"causal_conv1d: activation {activation!r}")
-
-    def f(a, w):
-        k, t = w.shape[1], a.shape[1]
-        pad = jnp.pad(a, ((0, 0), (k - 1, 0), (0, 0))).astype(_F32)
-        w = w.astype(_F32)
-        y = sum(pad[:, j:j + t] * w[:, j] for j in range(k))
-        if activation == "silu":
-            y = jax.nn.silu(y)
-        return y.astype(a.dtype)
-    return apply_op(f, x, weight)
+    return apply_op(lambda a, w: causal_conv(a, w, activation == "silu"),
+                    x, weight)
 
 
 def _l2norm(x):
@@ -104,23 +164,29 @@ def _chunk_rule(q, k, v, g, beta, *, chunk, mm):
     b, t, hk, dk = q.shape
     hv, dv = v.shape[2], v.shape[3]
     r, out = hv // hk, v.dtype      # each key head serves r value heads
-    q = _l2norm(q.astype(_F32)) * dk ** -0.5
-    k = _l2norm(k.astype(_F32))
     nb = min(_BLOCK_CHUNKS, -(-t // chunk))     # chunks a block
     pad = -t % (chunk * nb)         # a padded token decays nothing (g 0)
     n_blocks = (t + pad) // (chunk * nb)    # and writes nothing (beta, k 0)
 
-    def blocks(x, heads, to):
-        """[B, T, *heads-flat, ...] -> one leading entry a block."""
-        x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
-        x = x.reshape((b, n_blocks, nb, chunk) + heads + x.shape[3:])
-        return x.transpose(to)
+    def blocks(x, heads):
+        """[B, T, H, d] -> [G, B, *heads, nb, C, d]: the sequence splits
+        into (G, nb, C/8, 8) FIRST, so that what then moves past the heads
+        is whole (8, d) tiles."""
+        x = jnp.pad(x.astype(_F32), ((0, 0), (0, pad), (0, 0), (0, 0)))
+        d = x.shape[-1]
+        x = x.reshape(b, n_blocks, nb, chunk // TILE_ROWS, TILE_ROWS, -1, d)
+        x = x.transpose(1, 0, 5, 2, 3, 4, 6)
+        return x.reshape((n_blocks, b) + heads + (nb, chunk, d))
 
-    q = blocks(q, (hk,), (1, 0, 4, 2, 3, 5))            # [G,B,hk,nb,C,dk]
-    k = blocks(k, (hk,), (1, 0, 4, 2, 3, 5))
-    v = blocks(v.astype(_F32), (hk, r), (1, 0, 4, 5, 2, 3, 6))
-    g = blocks(g.astype(_F32), (hk, r), (1, 0, 4, 5, 2, 3))  # [G,B,hk,r,nb,C]
-    beta = blocks(beta.astype(_F32), (hk, r), (1, 0, 4, 5, 2, 3))
+    def gate_blocks(x):
+        """[B, T, hv] -> [G, B, hk, r, nb, C]: a few MiB, any way will do."""
+        x = jnp.pad(x.astype(_F32), ((0, 0), (0, pad), (0, 0)))
+        x = x.reshape(b, n_blocks, nb, chunk, hk, r)
+        return x.transpose(1, 0, 4, 5, 2, 3)
+
+    q, k = blocks(q, (hk,)), blocks(k, (hk,))           # [G,B,hk,nb,C,dk]
+    v = blocks(v, (hk, r))                              # [G,B,hk,r,nb,C,dv]
+    g, beta = gate_blocks(g), gate_blocks(beta)
 
     def dot(spec, x, y):
         return jnp.einsum(spec, x.astype(mm), y.astype(mm),
@@ -145,6 +211,7 @@ def _chunk_rule(q, k, v, g, beta, *, chunk, mm):
         block's start and recomputes the block, so that the rule's working
         set is one block's and not the sequence's."""
         q_, k_, v_, g_, beta_ = xs
+        q_, k_ = _l2norm(q_) * dk ** -0.5, _l2norm(k_)
         gamma = jnp.cumsum(g_, axis=-1)                 # [B,hk,r,nb,C]
         # exp(gamma_i - gamma_j) where j <= i, 0 above the diagonal; masked
         # BEFORE the exponential, where the difference would be positive
@@ -168,8 +235,9 @@ def _chunk_rule(q, k, v, g, beta, *, chunk, mm):
     _, o = jax.lax.scan(block_of_chunks,
                         jnp.zeros((b, hk, r, dk, dv), _F32),
                         (q, k, v, g, beta))
-    # [G,nb,B,hk,r,C,dv] -> [B,(G,nb,C),(hk,r),dv]
-    o = o.transpose(2, 0, 1, 5, 3, 4, 6).reshape(b, t + pad, hv, dv)
+    # [G,nb,B,hk,r,C,dv] -> [B,T,hv,dv] by the mirrored path
+    o = o.reshape(n_blocks, nb, b, hv, chunk // TILE_ROWS, TILE_ROWS, dv)
+    o = o.transpose(2, 0, 1, 4, 5, 3, 6).reshape(b, t + pad, hv, dv)
     return o[:, :t].astype(out)
 
 

@@ -412,6 +412,74 @@ def test_kernel_compiles_for_v5e(name, monkeypatch):
     _compile(fn, *args)
 
 
+# "%copy.12 = f32[2,8192,4096]{2,1,0:T(8,128)} copy(...)": name, shape with
+# its layout, opcode
+_INSTR = re.compile(r"^\s*(?:ROOT )?%[\w.\-]+ = (\S+) ([\w\-]+)\(", re.M)
+_ARRAY = re.compile(r"(\w+)\[([\d,]*)\]")
+_MOVES = {"reshape", "copy", "slice", "broadcast", "pad", "transpose",
+          "concatenate"}
+
+
+def test_gated_deltanet_mixer_keeps_one_layout(topo):
+    """One ``Qwen3NextGatedDeltaNet`` at the published widths on the
+    benchmark's 2 x 8192 tokens, bf16, in train mode: forward, the replay
+    under ``recompute`` and the backward pass in one program. From its
+    projections to its rule the mixer's arrays keep the sequence in the
+    sublanes and a head's 128 features in the lanes, so XLA has nothing to
+    copy into another tiling: held here by what the ENTRY computation's
+    plain data-movement instructions (fusions not counted) write, by the
+    absence of a (2, 128) tile among them (a size-2 axis in the sublanes),
+    and by the program's temporaries. These are BYTES of a compile, not
+    times: PERF.md section 6 (PR 29) says which of them turned into time on
+    the chip. At the parent commit: 8.19 GiB, nine such tiles, 5.02 GiB."""
+    import paddle_tpu as paddle
+    from paddle_tpu.distributed.fleet.utils.recompute_mod import recompute
+    from paddle_tpu.models.qwen3_next import (Qwen3NextConfig,
+                                              Qwen3NextGatedDeltaNet)
+    from paddle_tpu.tensor.tensor import Tensor
+    paddle.seed(0)
+    mixer = Qwen3NextGatedDeltaNet(Qwen3NextConfig())
+    mixer.bfloat16()
+    mixer.train()
+    params = list(mixer.parameters())
+    held = [p._data for p in params]
+
+    def step(arrays, x):
+        for p, a in zip(params, arrays):
+            p._data, p.grad = a, None
+        x = Tensor(x, stop_gradient=False)
+        y = recompute(mixer, x)
+        (y.astype("float32") ** 2).sum().backward()
+        return [p.grad._data for p in params], x.grad._data
+
+    try:
+        compiled = jax.jit(step).lower(
+            [_one(a.shape, a.dtype) for a in held],
+            _one((2, 8192, 2048), BF)).compile()
+    finally:
+        for p, a in zip(params, held):
+            p._data, p.grad = a, None
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    itemsize = {"f32": 4, "bf16": 2, "s32": 4, "u32": 4, "pred": 1}
+    moved, small_tiles = 0, []
+    for shape, opcode in _INSTR.findall(entry):
+        if opcode in _MOVES:
+            kind, dims = _ARRAY.match(shape).groups()
+            size = itemsize[kind] * int(np.prod(
+                [int(d) for d in dims.split(",") if d]))
+            moved += size
+            if "T(2,128)" in shape and size >= 1 << 20:  # not a weight's row
+                small_tiles.append(shape)
+    gib = float(1 << 30)
+    assert not small_tiles, small_tiles
+    # the finished change writes 2.66 GiB: four tile-for-tile copies a pass
+    # (v in, o out and their cotangents) and the scans' zero-filled results
+    assert moved / gib <= 2.95, moved / gib
+    assert compiled.memory_analysis().temp_size_in_bytes / gib <= 3.5
+
+
 @pytest.mark.parametrize("m,k,o,says", [
     (512, 768, 2304, True), (1024, 3072, 768, True),
     (1024, 768, 3072, False), (8, 768, 50304, False),

@@ -191,6 +191,147 @@ def test_causal_conv_matches_the_reference():
                                   np.asarray(got._data)[:, :5])
 
 
+def _plain_conv(a, w, silu):
+    """``causal_conv1d`` as it was before its backward pass was written
+    out: JAX's own transpose is the oracle."""
+    k, t = w.shape[1], a.shape[1]
+    pad = jnp.pad(a, ((0, 0), (k - 1, 0), (0, 0))).astype(jnp.float32)
+    y = sum(pad[:, j:j + t] * w[:, j] for j in range(k))
+    return (jax.nn.silu(y) if silu else y).astype(a.dtype)
+
+
+@pytest.mark.parametrize("seq", [9, 130])
+@pytest.mark.parametrize("activation", [None, "silu"])
+def test_causal_conv_gradients_match_jax_own(seq, activation):
+    rng = np.random.default_rng(seq)
+    x, cot = (rng.standard_normal((2, seq, 6)).astype(np.float32)
+              for _ in range(2))
+    w = rng.standard_normal((6, 4)).astype(np.float32)
+    want = jax.grad(lambda a, w_: jnp.sum(
+        _plain_conv(a, w_, activation == "silu") * cot), (0, 1))(x, w)
+    xt, wt = paddle.to_tensor(x), paddle.to_tensor(w)
+    xt.stop_gradient = wt.stop_gradient = False
+    got = F.causal_conv1d(xt, wt, activation=activation)
+    np.testing.assert_allclose(
+        np.asarray(got._data),
+        np.asarray(_plain_conv(x, w, activation == "silu")), atol=1e-6)
+    (got * paddle.to_tensor(cot)).sum().backward()
+    for t, g in zip((xt, wt), want):
+        np.testing.assert_allclose(np.asarray(t.grad._data), g,
+                                   atol=1e-5 * max(1.0, np.abs(g).max()))
+
+
+def _former_rule(q, k, v, g, beta, chunk):
+    """``_chunk_rule`` as PR 28 had it (float32 throughout): the norms in
+    front, ``blocks()`` that reshapes the sequence and the heads FIRST and
+    transposes afterwards, the result back by the same way. The oracle of
+    the layout the program keeps now."""
+    from paddle_tpu.nn.functional import linear_attention as la
+    b, t, hk, dk = q.shape
+    hv, dv = v.shape[2], v.shape[3]
+    r = hv // hk
+    q, k = la._l2norm(q) * dk ** -0.5, la._l2norm(k)
+    nb = min(la._BLOCK_CHUNKS, -(-t // chunk))
+    pad = -t % (chunk * nb)
+    n_blocks = (t + pad) // (chunk * nb)
+
+    def blocks(x, heads, to):
+        x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+        x = x.reshape((b, n_blocks, nb, chunk) + heads + x.shape[3:])
+        return x.transpose(to)
+    q = blocks(q, (hk,), (1, 0, 4, 2, 3, 5))
+    k = blocks(k, (hk,), (1, 0, 4, 2, 3, 5))
+    v = blocks(v, (hk, r), (1, 0, 4, 5, 2, 3, 6))
+    g = blocks(g, (hk, r), (1, 0, 4, 5, 2, 3))
+    beta = blocks(beta, (hk, r), (1, 0, 4, 5, 2, 3))
+    ii = jnp.arange(chunk)
+    lower, strict = ii[:, None] >= ii[None, :], ii[:, None] > ii[None, :]
+
+    def step(s, xs):
+        w_n, u_n, qg_n, aqk_n, kd_n, last_n = xs
+        vp = u_n - jnp.einsum("bhrcd,bhrde->bhrce", w_n, s)
+        o = jnp.einsum("bhrcd,bhrde->bhrce", qg_n, s) + jnp.einsum(
+            "bhrij,bhrje->bhrie", aqk_n, vp)
+        return last_n[..., None, None] * s + jnp.einsum(
+            "bhrcd,bhrce->bhrde", kd_n, vp), o
+
+    def block_of_chunks(s, xs):
+        q_, k_, v_, g_, beta_ = xs
+        gamma = jnp.cumsum(g_, axis=-1)
+        decay = jnp.exp(jnp.where(
+            lower, gamma[..., :, None] - gamma[..., None, :], -jnp.inf))
+        kk = jnp.einsum("bhnid,bhnjd->bhnij", k_, k_)[:, :, None]
+        a = jnp.where(strict, beta_[..., None] * kk * decay, 0.0)
+        kr = k_[:, :, None]
+        rhs = jnp.concatenate([kr * (beta_ * jnp.exp(gamma))[..., None],
+                               v_ * beta_[..., None]], axis=-1)
+        wu = jnp.matmul(la._inverse_unit_lower(a), rhs,
+                        precision=jax.lax.Precision.HIGHEST)
+        qg = q_[:, :, None] * jnp.exp(gamma)[..., None]
+        aqk = jnp.einsum("bhnid,bhnjd->bhnij", q_, k_)[:, :, None] * decay
+        kd = kr * jnp.exp(gamma[..., -1:] - gamma)[..., None]
+        xs = (wu[..., :dk], wu[..., dk:], qg, aqk, kd,
+              jnp.exp(gamma[..., -1]))
+        return jax.lax.scan(step, s,
+                            tuple(jnp.moveaxis(x, 3, 0) for x in xs))
+    _, o = jax.lax.scan(block_of_chunks,
+                        jnp.zeros((b, hk, r, dk, dv), jnp.float32),
+                        (q, k, v, g, beta))
+    o = o.transpose(2, 0, 1, 5, 3, 4, 6).reshape(b, t + pad, hv, dv)
+    return o[:, :t]
+
+
+def _former_mixer(m, x, w_qkvz, w_ba, conv_w, a_log, dt_bias, norm_w, w_out):
+    """``Qwen3NextGatedDeltaNet.forward`` as PR 28 had it: ONE product
+    ``[q | k | v | z]``, sliced; one convolution over ``[q | k | v]``,
+    sliced; the gated norm on ``[B, T, hv, dv]``."""
+    b, s = x.shape[0], x.shape[1]
+    key, value = m.hk * m.dk, m.hv * m.dv
+    qkvz = x @ w_qkvz
+    qkv = _plain_conv(qkvz[:, :, :2 * key + value], conv_w, True)
+    q = qkv[:, :, :key].reshape(b, s, m.hk, m.dk)
+    k = qkv[:, :, key:2 * key].reshape(b, s, m.hk, m.dk)
+    v = qkv[:, :, 2 * key:].reshape(b, s, m.hv, m.dv)
+    ba = x @ w_ba
+    g = -jnp.exp(a_log) * jax.nn.softplus(ba[..., m.hv:] + dt_bias)
+    o = _former_rule(q, k, v, g, jax.nn.sigmoid(ba[..., :m.hv]),
+                     m.chunk_size)
+    h = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + m.eps)
+    z = qkvz[:, :, 2 * key + value:].reshape(b, s, m.hv, m.dv)
+    return (h * norm_w * jax.nn.silu(z)).reshape(b, s, value) @ w_out
+
+
+@pytest.mark.parametrize("seq", [300, 296])
+def test_the_mixer_agrees_with_its_former_layout(seq):
+    """The mixer's result and every gradient on the layout it keeps now
+    (projections a consumer, heads moved as whole tiles, the norms inside
+    the blocks) against the former path, in float32, where each key head
+    serves two value heads and the sequence fills one block of 16 chunks
+    of 16 and part of a second; 300 is no multiple of 8 either, so the
+    gated norm takes its one-row view, 296 the tiles'."""
+    paddle.seed(9)
+    mixer = qwen3_next_tiny(num_layers=1).model.layers[0].mixer
+    assert mixer.hv // mixer.hk == 2
+    rng = np.random.default_rng(seq)
+    x = rng.standard_normal((2, seq, 64)).astype(np.float32)
+    cot = rng.standard_normal((2, seq, 64)).astype(np.float32)
+    params = [mixer.in_proj_qkvz.weight, mixer.in_proj_ba.weight,
+              mixer.conv_weight, mixer.A_log, mixer.dt_bias,
+              mixer.norm_weight, mixer.out_proj.weight]
+    want, vjp = jax.vjp(lambda x_, *ws: _former_mixer(mixer, x_, *ws),
+                        jnp.asarray(x), *[p._data for p in params])
+    want_grads = vjp(jnp.asarray(cot))
+    xt = paddle.to_tensor(x)
+    xt.stop_gradient = False
+    got = mixer(xt)
+    (got * paddle.to_tensor(cot)).sum().backward()
+    np.testing.assert_allclose(np.asarray(got._data), want,
+                               atol=1e-5 * np.abs(want).max())
+    for t, g in zip([xt] + params, want_grads):
+        np.testing.assert_allclose(np.asarray(t.grad._data), g,
+                                   atol=2e-5 * max(np.abs(g).max(), 1e-6))
+
+
 # ------------------------------------------------------------ expert layer
 D, FF, E, K = 32, 16, 8, 2
 
@@ -285,6 +426,55 @@ def test_a_skewed_router_drops_nothing_and_matches_the_reference():
     assert rec["pairs_dropped"] == 0
     assert rec["rows_computed"] == part.buffer_rows(2 * 64)
     np.testing.assert_allclose(out, _reference_moe(part, x), atol=1e-5)
+
+
+def test_rows_the_grouped_product_leaves_unwritten_reach_nothing(monkeypatch):
+    """``jax.lax.ragged_dot`` writes the rows of its groups and no others:
+    past them its result, and its input's gradient, are zeros on the CPU
+    and whatever the buffer held on the TPU, where they reached the
+    tokens' gradients (PERF.md section 6, PR 29: gradients 10^3-10^4 times
+    too large above every expert layer). Here those rows are NaN: the
+    layer's result and every gradient must come out as without them."""
+    real = jax.lax.ragged_dot
+
+    def poisoned(lhs, rhs, group_sizes, **kw):
+        inside = (jnp.arange(lhs.shape[0]) < jnp.sum(group_sizes))[:, None]
+
+        @jax.custom_vjp
+        def dot(lhs, rhs):
+            return jnp.where(inside, real(lhs, rhs, group_sizes, **kw),
+                             jnp.nan)
+
+        def fwd(lhs, rhs):
+            return dot(lhs, rhs), (lhs, rhs)
+
+        def bwd(res, ct):
+            d_lhs, d_rhs = jax.vjp(
+                lambda a, b: real(a, b, group_sizes, **kw), *res)[1](
+                    jnp.where(inside, ct, 0.0))
+            return jnp.where(inside, d_lhs, jnp.nan), d_rhs
+        dot.defvjp(fwd, bwd)
+        return dot(lhs, rhs)
+
+    x = np.random.default_rng(4).standard_normal((2, 24, D)).astype(
+        np.float32)
+    runs = []
+    for poison in (False, True):
+        if poison:
+            monkeypatch.setattr(jax.lax, "ragged_dot", poisoned)
+        part = _expert_layer([0, 1])
+        xt = paddle.to_tensor(x)
+        xt.stop_gradient = False
+        out = part(xt)
+        rec = routing_stats()["layers"][-1]
+        assert 0 < rec["pairs_local"] < rec["rows_computed"]
+        (out * out).sum().backward()
+        runs.append([np.asarray(t._data) for t in (
+            out, xt.grad, part.router.grad, part.experts_gate_up.grad,
+            part.experts_down.grad)])
+    for want, got in zip(*runs):
+        assert np.isfinite(got).all()
+        np.testing.assert_allclose(got, want, atol=1e-6)
 
 
 def test_a_pair_past_the_buffer_is_counted_not_lost_in_silence():
