@@ -51,11 +51,11 @@ import numpy as np
 
 SEED = 0
 
-# the gpt2_124m trainer cell (bench.py's on-chip shape)
+# the gpt2_124m trainer cell (GPT-2 124M at its published widths)
 TRAIN = {"build": "gpt2_124m", "batch": 8, "seq": 1024, "steps": 8,
          "lr": 3e-4}
 
-# the GPT-2-124M-width serving stack (bench_serving.py's on-chip dims).
+# the GPT-2-124M-width serving stack.
 # Prompt lengths are in tokens; "shared" is the prefix two requests share
 # (>= 2 KV blocks of the default 64 tokens).
 SERVE = {"hidden": 768, "heads": 12, "ffn": 3072, "layers": 12,
@@ -66,7 +66,7 @@ SERVE = {"hidden": 768, "heads": 12, "ffn": 3072, "layers": 12,
                       (0, 48)),
          "quant_prompts": (40, 100), "quant_new_tokens": 8}
 
-# the LLaMA-shaped hybrid-training cell (bench.py's on-chip llama width)
+# the LLaMA-shaped hybrid-training cell (hidden 1024, FFN 2816)
 HYBRID = {"hidden": 1024, "layers": 16, "heads": 16, "ffn": 2816,
           "vocab": 32000, "batch": 8, "seq": 1024, "steps": 3, "lr": 1e-4}
 

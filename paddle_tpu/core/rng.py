@@ -42,7 +42,7 @@ class _RngState(threading.local):
         # `_data is None` = the key was lazily created inside a to_static
         # trace that failed; the rollback (jit _execute) killed it. Rebuild
         # from the last seed so the retry reruns with live, tracked state.
-        # A DELETED device array (bench.py's inter-config memory release
+        # A DELETED device array (device.chip.release_device_memory
         # hard-deletes all live arrays) rebuilds the same way.
         dead = (self.key_tensor is None or self.key_tensor._data is None)
         if not dead:

@@ -1,26 +1,20 @@
-"""What a script that measures on the chip needs before it starts: a TPU
-or a refusal, the chip's published peak rates, and one place for the
-persistent compile cache. Shared by chip_smoke.py, bench.py,
-bench_serving.py, bench_decode.py and the profiling tools, so that none
-of them carries a CPU fallback, a default peak or a cache path of its own.
+"""What a script that runs on the chip needs before it starts: a TPU or a
+refusal, the record that names the device, and one place for the
+persistent compile cache. Shared by chip_smoke.py and the tools that
+stay (tools/decode_profile.py, decode_alias_probe.py, longctx_bench.py,
+vit_profile.py, llama_1b.py), so that none of them carries a CPU fallback
+or a cache path of its own. Peak rates are the benchmark's
+(benchmark/harness.py::PEAKS), on purpose.
 """
 from __future__ import annotations
 
 import os
 
-__all__ = ["require_tpu", "device_record", "peak_rates",
-           "use_compile_cache", "release_device_memory", "PEAK_RATES"]
+__all__ = ["require_tpu", "device_record", "use_compile_cache",
+           "release_device_memory"]
 
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
-
-# Published peaks of ONE chip, keyed by jax's ``device_kind``. A device
-# that is not here is an error, never a default.
-PEAK_RATES = {
-    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM
-    # at 819 GB/s
-    "TPU v5 lite": {"bf16_tflops": 197.0, "hbm_gbps": 819.0},
-}
 
 
 def require_tpu():
@@ -43,17 +37,6 @@ def device_record(dev) -> dict:
     import jax
     return {"platform": dev.platform, "kind": dev.device_kind,
             "count": len(jax.devices())}
-
-
-def peak_rates(device_kind: str) -> dict:
-    """The published peak rates of ``device_kind`` (``PEAK_RATES``)."""
-    try:
-        return PEAK_RATES[device_kind]
-    except KeyError:
-        raise ValueError(
-            f"no published peak rates for device_kind {device_kind!r}: add "
-            "it to paddle_tpu.device.chip.PEAK_RATES with its source "
-            f"(known: {sorted(PEAK_RATES)})") from None
 
 
 def use_compile_cache() -> str:

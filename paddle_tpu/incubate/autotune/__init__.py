@@ -2,17 +2,15 @@
 
 Capability parity: python/paddle/incubate/autotune.py :: set_config —
 the reference toggles kernel-algorithm search (cuDNN algo search, layout
-autotune, dataloader tuning). TPU-native meaning: XLA already autotunes
-its own fusions; the tunable surface HERE is the Pallas flash-attention
-tiling (PADDLE_TPU_FLASH_BQ/BK — swept by tools/attn_sweep.py) and the
-dataloader worker count. set_config maps the reference's {"kernel":
-{"enable": ..}, "dataloader": {...}} dict onto those knobs and records
-the config for get_config() introspection.
+autotune, dataloader tuning). TPU-native meaning: there is nothing to
+search. XLA autotunes its own fusions, and the Pallas kernels' tiles
+follow from their shapes (ops/pallas/flash_attention.py::_block_sizes).
+set_config keeps the reference's {"kernel": {"enable": ..}, "dataloader":
+{...}} dict for get_config() introspection and steers no kernel.
 """
 from __future__ import annotations
 
 import json
-import os
 
 __all__ = ["set_config", "get_config"]
 
@@ -22,18 +20,13 @@ _config = {"kernel": {"enable": False},
 
 
 def set_config(config=None):
-    """Accepts a dict (or a path to a JSON file, like the reference).
-
-    kernel.enable=True with optional kernel.tuning_range [bq, bk] pins
-    the flash tile caps via the PADDLE_TPU_FLASH_* env the kernels read
-    at trace time."""
+    """Accepts a dict (or a path to a JSON file, like the reference) and
+    records it for get_config(); None enables every section. A
+    kernel.tuning_range is recorded like any other key: tiles follow
+    from shapes, so no kernel reads it."""
     global _config
     if config is None:
-        # reset-to-enabled-defaults: no tuning_range anymore, so the tile
-        # caps must unpin too (kernels read env at trace time)
         _config = {k: {"enable": True} for k in _config}
-        os.environ.pop("PADDLE_TPU_FLASH_BQ", None)
-        os.environ.pop("PADDLE_TPU_FLASH_BK", None)
         return
     if isinstance(config, str):
         with open(config) as f:
@@ -42,17 +35,6 @@ def set_config(config=None):
         if key not in _config or not isinstance(val, dict):
             continue
         _config[key] = {**_config[key], **val}
-    kr = _config.get("kernel", {})
-    rng = kr.get("tuning_range")
-    if kr.get("enable") and rng:
-        os.environ["PADDLE_TPU_FLASH_BQ"] = str(int(rng[0]))
-        os.environ["PADDLE_TPU_FLASH_BK"] = str(int(rng[-1]))
-    else:
-        # disabling (or dropping tuning_range) must UNPIN the tile caps —
-        # the kernels read env at trace time, so stale values would
-        # silently tile every later flash kernel
-        os.environ.pop("PADDLE_TPU_FLASH_BQ", None)
-        os.environ.pop("PADDLE_TPU_FLASH_BK", None)
 
 
 def get_config():

@@ -85,42 +85,6 @@ class MoELayer(Layer):
                                             activation)
 
     def forward(self, x):
-        import os
-        if os.environ.get("PADDLE_TPU_MOE_IDENTITY_DISPATCH") == "1":
-            # BENCHMARK PROBE, not a routing mode: fixed round-robin
-            # chunking replaces the gate + mask einsums, keeping the
-            # expert compute (shapes included) identical — full-step time
-            # minus this twin's time isolates the gate+dispatch+combine
-            # cost, the decomposition BASELINE configs[4] names (reference
-            # metric: global_scatter/global_gather alltoall step time)
-            b, s, d = x.shape[0], x.shape[1], x.shape[2]
-            e = self.num_experts
-            # the gate never runs in this probe: clear any aux_loss a
-            # PREVIOUS trace left behind (a stale tracer would leak
-            # into this trace via the model's aux_loss() sum)
-            self.gate.aux_loss = None
-            # SAME capacity as the real gate so the expert compute is
-            # genuinely shape-identical ([E, B, gate.capacity(s), d]) —
-            # tokens round-robin into the e*cap slots, zero-padded
-            cap = self.gate.capacity(s)
-
-            def rr(xx):
-                slots = e * cap
-                pad = slots - s
-                xp = jnp.pad(xx, ((0, 0), (0, max(pad, 0)), (0, 0))) \
-                    if pad > 0 else xx[:, :slots]
-                ei = xp.reshape(xx.shape[0], e, cap, xx.shape[2])
-                return jnp.swapaxes(ei, 0, 1)            # [E,B,C,d]
-            expert_in = apply_op(rr, x)
-            expert_out = self.experts(expert_in)
-
-            def rr_inv(eo):
-                back = jnp.swapaxes(eo, 0, 1)            # [B,E,C,d]
-                back = back.reshape(back.shape[0], e * cap, d)
-                if e * cap >= s:
-                    return back[:, :s]
-                return jnp.pad(back, ((0, 0), (0, s - e * cap), (0, 0)))
-            return apply_op(rr_inv, expert_out)
         combine, dispatch, aux = self.gate(x)  # [B,S,E,C] masks
 
         def dispatch_fn(xx, dd):

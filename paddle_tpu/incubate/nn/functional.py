@@ -55,36 +55,15 @@ def fused_feedforward(x, linear1_weight, linear2_weight, linear1_bias=None,
                       pre_layer_norm=False, training=True, mode="upscale_in_train",
                       name=None):
     """Parity: fused_feedforward_op.cu — LN→linear→act→dropout→linear→dropout
-    →residual(+LN). When PADDLE_TPU_FUSED_FFN=1, the activation is exact
-    gelu, the dropouts are inert, and both biases exist, the middle
-    linear→gelu→linear runs as the ONE-kernel Pallas fused FFN
-    (ops/pallas/fused_ffn.py) — the [*, F] intermediate never touches
-    HBM."""
-    import os
+    →residual(+LN), as an XLA composite."""
     residual = x
     if pre_layer_norm:
         x = F.layer_norm(x, x.shape[-1:], ln1_scale, ln1_bias, ln1_epsilon)
-    # inert = the composite's dropouts are identity: zero rates always,
-    # or eval mode under upscale_in_train (downscale_in_infer SCALES at
-    # inference — not inert)
-    drop_inert = (dropout1_rate == 0.0 and dropout2_rate == 0.0) or (
-        not training and mode == "upscale_in_train")
-    # mesh query only, no pallas import
-    from ...parallel import no_multi_device_mesh
-    if (os.environ.get("PADDLE_TPU_FUSED_FFN") == "1"
-            and activation == "gelu" and drop_inert
-            and linear1_bias is not None and linear2_bias is not None
-            and no_multi_device_mesh()):  # pallas can't auto-partition
-        from ...ops.pallas.fused_ffn import fused_ffn
-        out = apply_op(lambda a, w1, b1, w2, b2: fused_ffn(
-            a, w1, b1, w2, b2, "gelu"), x, linear1_weight, linear1_bias,
-            linear2_weight, linear2_bias)
-    else:
-        out = F.linear(x, linear1_weight, linear1_bias)
-        out = getattr(F, activation)(out)
-        out = F.dropout(out, dropout1_rate, training=training, mode=mode)
-        out = F.linear(out, linear2_weight, linear2_bias)
-        out = F.dropout(out, dropout2_rate, training=training, mode=mode)
+    out = F.linear(x, linear1_weight, linear1_bias)
+    out = getattr(F, activation)(out)
+    out = F.dropout(out, dropout1_rate, training=training, mode=mode)
+    out = F.linear(out, linear2_weight, linear2_bias)
+    out = F.dropout(out, dropout2_rate, training=training, mode=mode)
     out = residual + out
     if not pre_layer_norm:
         out = F.layer_norm(out, out.shape[-1:], ln2_scale, ln2_bias,
@@ -203,11 +182,8 @@ def _decode_attn(q, cache, ts, s, attn_mask):
     """Cache attention for the decode step. TPU: the Pallas flash-decode
     kernel over the full static-shape cache with length masking (no
     per-step recompiles); fallback: dense sdpa over the valid prefix."""
-    import os
-    use_pallas = attn_mask is None and (
-        jax.default_backend() == "tpu" or
-        os.environ.get("PADDLE_TPU_FORCE_PALLAS") == "1")
-    if use_pallas:
+    from ...ops import pallas
+    if attn_mask is None and pallas._enabled():
         from ...ops.pallas import decode_attention as da
         kc = cache._data[0]          # [B, H, Smax, D]
         if da.is_supported(tuple(q.shape),

@@ -525,7 +525,7 @@ class ServingEngine:
         # decode gang hostage (Sarathi-style chunked prefill).
         # token_budget=0 restores the legacy PHASE-prefill scheduler
         # (blocking bulk/scan prefill at admission) — kept as the A/B
-        # baseline and for `bench_serving.py --chunked`.
+        # baseline (tests/test_budget_scheduler.py holds token parity).
         # default: C = max(4 x decode_chunk, spec_k + 1) columns per
         # row — wide enough that a classic-length prompt (and a full
         # draft) lands in ONE dispatch; measured on the classic CPU
@@ -1327,9 +1327,8 @@ class ServingEngine:
         """The EXACT device arrays the serving step dispatches with:
         the stacked layer pytree, the embedding params, and the
         (possibly quantized / vocab-sharded) LM-head arrays. One list
-        so the weight gauges, the conftest identity reconciliation and
-        bench_serving's --mesh-weights A/B all account the same
-        bytes."""
+        so the weight gauges and the conftest identity reconciliation
+        account the same bytes."""
         dec = self.dec
         arrs = list(dec._stacked().values())
         arrs += [p._data for p in dec._embed_params]

@@ -84,25 +84,6 @@ class GPTMLP(Layer):
                               math.sqrt(2 * c.num_layers))))
 
     def forward(self, x):
-        import os
-        if os.environ.get("PADDLE_TPU_FUSED_FFN") == "1" \
-                and type(self.fc1) is Linear and type(self.fc2) is Linear:
-            # Pallas fused bias+gelu+matmul (ops/pallas/fused_ffn.py):
-            # the [M, F] gelu intermediate never touches HBM. Opt-in
-            # pending the on-TPU A/B vs the XLA composite (LN lesson:
-            # pallas_call is a fusion barrier — measure first). Guarded
-            # like the llama fast paths: plain Linear layers only, and
-            # no multi-device mesh — jax cannot auto-partition a
-            # pallas_call, whichever axis shards its operands. The mesh
-            # query lives in ..parallel so the pallas import chain only
-            # loads once the flag AND the guard pass.
-            from ..parallel import no_multi_device_mesh
-            if no_multi_device_mesh():
-                from ..ops.pallas.fused_ffn import fused_ffn
-                from ..tensor.tensor import apply_op
-                return apply_op(fused_ffn, x, self.fc1.weight,
-                                self.fc1.bias, self.fc2.weight,
-                                self.fc2.bias)
         return self.fc2(F.gelu(self.fc1(x), approximate=True))
 
 

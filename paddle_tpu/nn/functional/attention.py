@@ -50,21 +50,6 @@ def _sdpa_ref(q, k, v, mask, dropout_p, causal, scale, dropout_key=None):
     return jnp.swapaxes(out, 1, 2)  # back to [B,S,H,D]
 
 
-def _use_pallas(q_shape, k_shape, dtype) -> bool:
-    """Pallas only on TPU (interpret mode off-TPU is slower than the XLA
-    composite); PADDLE_TPU_FORCE_PALLAS=1 overrides for dispatch tests."""
-    import os
-    if jax.default_backend() != "tpu" and \
-            os.environ.get("PADDLE_TPU_FORCE_PALLAS") != "1":
-        return False
-    if q_shape[2] % k_shape[2] != 0:   # GQA requires kv_heads | q_heads
-        return False
-    # no blanket except: an import or gate error must surface, not
-    # silently downgrade every attention call to the O(S^2) composite
-    from ...ops.pallas import flash_attention as fa
-    return fa.is_supported(q_shape, dtype)
-
-
 def _per_shard(q_shape, k_shape):
     """How the flash kernel runs under the active mesh. A pallas_call
     cannot sit under GSPMD auto-partitioning — on more than one device jax
@@ -108,20 +93,21 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
     mask_arr = attn_mask._data if isinstance(attn_mask, Tensor) else attn_mask
     drop_p = float(dropout_p) if training else 0.0
 
-    # dropout routing: the flash kernel handles dropout with in-kernel
-    # hardware PRNG (zero HBM mask traffic) and is the TRAINING default —
-    # measured on v5e at the GPT-2 bench shape it is both faster to compile
-    # (41s vs 88s) and faster per step than the composite (which must
-    # materialize O(S^2) probs). PADDLE_TPU_FLASH_DROPOUT=0 opts out.
-    import os
-    flash_drop_ok = drop_p == 0.0 or \
-        os.environ.get("PADDLE_TPU_FLASH_DROPOUT", "1") != "0"
+    # the one question: does the flash kernel take these shapes, this
+    # dtype, this mask, under this mesh? Dropout does not enter: the kernel
+    # draws its mask in-kernel (no O(S^2) mask in HBM), so with dropout it
+    # is taken exactly when it would be without. No blanket except: an
+    # import or gate error must surface, not silently downgrade every
+    # attention call to the O(S^2) composite.
+    from ...ops import pallas
+    fa = pallas.flash_attention
+    q_shape, k_shape = tuple(query.shape), tuple(key.shape)
     wrap = None
-    if mask_arr is None and flash_drop_ok and \
-            _use_pallas(tuple(query.shape), tuple(key.shape), query.dtype):
-        wrap = _per_shard(tuple(query.shape), tuple(key.shape))
+    if mask_arr is None and pallas._enabled() \
+            and q_shape[2] % k_shape[2] == 0 \
+            and fa.is_supported(q_shape, query.dtype):
+        wrap = _per_shard(q_shape, k_shape)
     if wrap is not None:
-        from ...ops.pallas import flash_attention as fa
         seed = jnp.zeros((), jnp.int32)
         if drop_p > 0.0:
             import jax.random as jrandom

@@ -1,9 +1,10 @@
 """Normalization functionals. Parity: python/paddle/nn/functional/norm.py.
 
 layer_norm here is the reference's north-star Phi kernel
-(paddle/phi/kernels/gpu/layer_norm_kernel.cu :: LayerNormKernel); on TPU the
-fused path is the Pallas kernel in paddle_tpu.ops.pallas.layer_norm, with this
-jnp composite as the autodiff-friendly fallback (XLA fuses it well already).
+(paddle/phi/kernels/gpu/layer_norm_kernel.cu :: LayerNormKernel); on TPU it
+is this jnp composite, which XLA fuses into its neighbours' epilogues (a
+hand-written Pallas kernel is a fusion barrier and lost to it on the chip:
+PERF.md section 6, PR 30).
 """
 from __future__ import annotations
 
@@ -15,53 +16,11 @@ __all__ = ["layer_norm", "batch_norm", "instance_norm", "group_norm",
            "local_response_norm", "rms_norm"]
 
 
-def _pallas_ln_ok(x, normalized_shape, weight, bias, need_bias=True) -> bool:
-    """Fused-kernel gate: last-dim norm, affine params matching x's dtype,
-    on TPU (the composite promotes mixed dtypes; the kernel keeps x.dtype,
-    so mixed-dtype configs must take the composite for backend parity).
-
-    OPT-IN (PADDLE_TPU_PALLAS_LN=1), and the gate covers BOTH F.layer_norm
-    and F.rms_norm: a pallas_call is a fusion barrier, so every norm pays
-    its own HBM round-trip, while XLA fuses the composite into the
-    surrounding matmul/elementwise epilogues. Measured r3 s4: the LLaMA
-    stage3 config (rms_norm hot path) gained 31.8k -> 38.2k tok/s with
-    the composite default + fused flash bwd in the same run; GPT-2
-    (layer_norm) was neutral-to-positive. The kernels stay (capability
-    parity for layer_norm_kernel.cu + direct callers/tests)."""
-    import jax
-    import os
-    if os.environ.get("PADDLE_TPU_PALLAS_LN") != "1" and \
-            os.environ.get("PADDLE_TPU_FORCE_PALLAS") != "1":
-        return False
-    if jax.default_backend() != "tpu" and \
-            os.environ.get("PADDLE_TPU_FORCE_PALLAS") != "1":
-        return False
-    from ...parallel import no_multi_device_mesh
-    # a pallas_call cannot be auto-partitioned
-    if not no_multi_device_mesh():
-        return False
-    # an import or gate error raises (no silent composite downgrade)
-    from ...ops.pallas import layer_norm as pln
-    if len(tuple(normalized_shape)) != 1 or weight is None:
-        return False
-    if need_bias and bias is None:
-        return False
-    if weight.dtype != x.dtype or (bias is not None
-                                   and bias.dtype != x.dtype):
-        return False
-    return pln.is_supported(tuple(x.shape), x.dtype)
-
-
 def layer_norm(x, normalized_shape, weight=None, bias=None, epsilon=1e-5,
                name=None):
     if isinstance(normalized_shape, int):
         normalized_shape = (normalized_shape,)
     n_axes = len(tuple(normalized_shape))
-
-    if _pallas_ln_ok(x, normalized_shape, weight, bias):
-        from ...ops.pallas import layer_norm as pln
-        return apply_op(lambda a, w, b: pln.layer_norm(a, w, b, epsilon),
-                        x, weight, bias)
 
     def core(a, *wb):
         axes = tuple(range(a.ndim - n_axes, a.ndim))
@@ -86,11 +45,6 @@ def layer_norm(x, normalized_shape, weight=None, bias=None, epsilon=1e-5,
 
 def rms_norm(x, weight=None, epsilon=1e-6, name=None):
     """RMSNorm (LLaMA-family). Stats in fp32, output in input dtype."""
-    if weight is not None and _pallas_ln_ok(x, (x.shape[-1],), weight, None,
-                                            need_bias=False):
-        from ...ops.pallas import layer_norm as pln
-        return apply_op(lambda a, w: pln.rms_norm(a, w, epsilon), x, weight)
-
     def core(a, *w):
         var = jnp.mean(jnp.square(a.astype(jnp.float32)), axis=-1, keepdims=True)
         out = a.astype(jnp.float32) * jnp.reciprocal(jnp.sqrt(var + epsilon))

@@ -16,7 +16,7 @@ __all__ = ["LayerNorm", "RMSNorm", "BatchNorm", "BatchNorm1D", "BatchNorm2D",
 class LayerNorm(Layer):
     """Parity: nn/layer/norm.py :: LayerNorm → Phi layer_norm kernel
     (paddle/phi/kernels/gpu/layer_norm_kernel.cu). On TPU: fp32-stat composite
-    that XLA fuses; Pallas kernel available via ops.pallas.layer_norm."""
+    that XLA fuses."""
 
     def __init__(self, normalized_shape, epsilon=1e-5, weight_attr=None,
                  bias_attr=None, name=None):

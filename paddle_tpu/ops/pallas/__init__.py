@@ -4,7 +4,6 @@ Parity target: the reference's GPU kernel library (paddle/phi/kernels/gpu/,
 paddle/fluid/operators/fused/) re-designed as TPU Mosaic kernels:
 
   flash_attention   — flash_attn_kernel.cu :: FlashAttnKernel
-  layer_norm        — layer_norm_kernel.cu :: LayerNormKernel
   decode_attention  — fused_multi_transformer_op.cu (KV-cache decode path; built in a later milestone this round)
 
 Each module exposes ``is_supported(...)`` so functional wrappers can fall
@@ -23,5 +22,12 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
+def _enabled() -> bool:
+    """Whether the functional wrappers take a kernel at all: on a TPU. Off
+    it interpret mode is slower than the XLA composite, so they take that.
+    ``_interpret()``'s sibling, read the same way: a dispatch test flips it
+    with one monkeypatch and runs the kernels interpreted."""
+    return jax.default_backend() == "tpu"
+
+
 from . import flash_attention  # noqa: F401
-from . import layer_norm  # noqa: F401

@@ -48,13 +48,14 @@ def is_supported(q_shape, dtype) -> bool:
 
 
 def _block_sizes(sq: int, sk: int, d: int = 64):
-    """1024-wide tiles (default cap): the [bq,d]x[d,bk] and [bq,bk]x[bk,d]
-    dots must be large enough to fill the MXU pipeline — 128x128 tiles
-    measure ~5-9 TFLOP/s on v5e, 512x512 ~12, 1024x1024 ~16 (r3 s4 sweep:
+    """The tiles follow from the shapes alone. 1024-wide (the cap): the
+    [bq,d]x[d,bk] and [bq,bk]x[bk,d] dots must be large enough to fill
+    the MXU pipeline — 128x128 tiles measure ~5-9 TFLOP/s on v5e,
+    512x512 ~12, 1024x1024 ~16 (r3 s4 sweep:
     fwd+bwd 4.76 -> 3.56 ms/layer at the GPT-2 headline shape; headline
     step 91.7 -> 86.6 ms). VMEM per program at 1024 tiles is ~6 MB
     (s/p [1024,1024] f32 + q/k/v/acc tiles), still < the ~16 MB budget.
-    Past head_dim 128 the default cap is 512: at 256 the dk/dv kernel's
+    Past head_dim 128 the cap is 512: at 256 the dk/dv kernel's
     1024 tiles ask for 17.05 MB of the 16 MB the v5e's compiler grants."""
     def pick(n, cap):
         if n < cap:
@@ -66,21 +67,8 @@ def _block_sizes(sq: int, sk: int, d: int = 64):
         cands = [c for c in (cap, cap // 2) if c >= 256] or [cap]
         return min(cands, key=lambda c: (math.ceil(n / c) * c, -c))
 
-    import os
-
-    def cap_from_env(var, default):
-        # tuning knob: clamp to [8, 4096] and round down to a power of two
-        # so a bad value degrades to a valid Mosaic block, never a crash
-        try:
-            v = int(os.environ.get(var, default))
-        except ValueError:
-            v = default
-        v = min(max(v, 8), 4096)
-        return 1 << (v.bit_length() - 1)
-
     cap = 1024 if d <= 128 else 512
-    return (pick(sq, cap_from_env("PADDLE_TPU_FLASH_BQ", cap)),
-            pick(sk, cap_from_env("PADDLE_TPU_FLASH_BK", cap)))
+    return pick(sq, cap), pick(sk, cap)
 
 
 # ---------------------------------------------------------------------------
@@ -523,20 +511,18 @@ def _bwd(q, k, v, o, lse, do, drop=None, *, causal, scale, bq, bk):
 
     if sq_p == bq and sk_p == bk:
         # whole slice is one block: fused dq/dk/dv kernel (no S/dP
-        # recompute, single read of q/k/v/do)
-        import os
-        if os.environ.get("PADDLE_TPU_FLASH_SPLIT_BWD") != "1":
-
-            dq, dk, dv = _bwd_fused(
-                q_, k_, v_, do_, lse_, delta_, drop, drop_arg,
-                causal=causal, scale=scale, sq=sq, sk=sk, group=group)
-            dq = dq[:, :, :sq]
-            dk = dk[:, :, :sk]
-            dv = dv[:, :, :sk]
-            if group > 1:
-                dk = dk.reshape(b, hk, group, sk, d).sum(axis=2)
-                dv = dv.reshape(b, hk, group, sk, d).sum(axis=2)
-            return dq, dk.astype(k.dtype), dv.astype(v.dtype)
+        # recompute, single read of q/k/v/do); more blocks take the
+        # split dKV + dQ pair below
+        dq, dk, dv = _bwd_fused(
+            q_, k_, v_, do_, lse_, delta_, drop, drop_arg,
+            causal=causal, scale=scale, sq=sq, sk=sk, group=group)
+        dq = dq[:, :, :sq]
+        dk = dk[:, :, :sk]
+        dv = dv[:, :, :sk]
+        if group > 1:
+            dk = dk.reshape(b, hk, group, sk, d).sum(axis=2)
+            dv = dv.reshape(b, hk, group, sk, d).sum(axis=2)
+        return dq, dk.astype(k.dtype), dv.astype(v.dtype)
 
     qspec = pl.BlockSpec((1, 1, bq, d), lambda b_, h_, j, i: (b_, h_, i, 0))
     kspec = pl.BlockSpec((1, 1, bk, d),

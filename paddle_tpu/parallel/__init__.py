@@ -12,7 +12,6 @@ Key entry points:
 """
 from __future__ import annotations
 
-import math
 from typing import Callable, Optional
 
 import jax
@@ -192,20 +191,6 @@ def shard_batch(x, mesh: Optional[Mesh] = None):
     sh = NamedSharding(mesh, data_spec(arr.ndim, mesh))
     out = jax.device_put(arr, sh)
     return Tensor(out) if not isinstance(x, Tensor) else Tensor(out)
-
-
-def no_multi_device_mesh() -> bool:
-    """Guard for opt-in Pallas fast paths (fused FFN, Pallas LN): a
-    pallas_call cannot sit under GSPMD auto-partitioning — on more than
-    one device jax refuses to lower it ("Mosaic kernels cannot be
-    automatically partitioned"), whichever mesh axis shards the operands;
-    interpret mode on a virtual CPU mesh hides this. Callers route to the
-    XLA composite whenever a multi-device mesh is active (the flash
-    kernel instead runs per shard, see nn/functional/attention.py).
-    Lives here (a pure mesh query) so consulting it never drags in the
-    pallas import chain while the feature flag is off."""
-    mesh = current_mesh()
-    return mesh is None or math.prod(dict(mesh.shape).values()) == 1
 
 
 def with_spec(t: Tensor, *spec) -> Tensor:

@@ -256,7 +256,7 @@ def main(argv=None):
     role_list = _parse_roles(args.roles) if args.roles else None
 
     # the mesh needs devices before the first jax import (CPU hosts:
-    # forced host devices — same lever as bench_serving --mesh)
+    # forced host devices)
     if args.mesh_mp > 1:
         flags = os.environ.get("XLA_FLAGS", "")
         if "host_platform_device_count" not in flags:

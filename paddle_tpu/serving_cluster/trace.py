@@ -23,9 +23,9 @@ that placed it, and both replicas' engine spans (attempt 1 on the
 killed replica, attempt 2 on the failover target) line up on one
 timeline under one trace id.
 
-The output passes ``telemetry.validate_chrome_trace`` (benches and
-tests gate on it: ``bench_serving.py --cluster`` fails on an invalid
-merged trace, the same discipline as the single-engine export gate).
+The output passes ``telemetry.validate_chrome_trace`` (the tests gate on
+it: ``tests/test_serving_cluster.py::test_cluster_trace_merged_export``,
+the same discipline as the single-engine export gate).
 """
 from __future__ import annotations
 

@@ -213,31 +213,6 @@ def _flash(grad, dropout_p=0.0, qkv=_QKV, kv_heads=None):
     return fn, (_one(qkv, BF), _one(kv, BF), _one(kv, BF))
 
 
-def _ffn(grad, monkeypatch):
-    from paddle_tpu.ops.pallas.fused_ffn import fused_ffn
-    fn = fused_ffn
-    if grad:
-        # the two-kernel backward is opt-in (the default backward is XLA
-        # matmuls); compile the kernels it would run
-        monkeypatch.setenv("PADDLE_TPU_FUSED_FFN_BWD", "1")
-        fn = _sum_grad(fused_ffn, (0, 1, 2, 3, 4))
-    return fn, (_one((8192, 768), BF), _one((768, 3072), BF),
-                _one((3072,), BF), _one((3072, 768), BF),
-                _one((768,), BF))
-
-
-def _layer_norm(grad):
-    from paddle_tpu.ops.pallas.layer_norm import layer_norm
-    fn = _sum_grad(layer_norm, (0, 1, 2)) if grad else layer_norm
-    return fn, (_one((8192, 768), BF), _one((768,), BF), _one((768,), BF))
-
-
-def _rms_norm(grad):
-    from paddle_tpu.ops.pallas.layer_norm import rms_norm
-    fn = _sum_grad(rms_norm, (0, 1)) if grad else rms_norm
-    return fn, (_one((8192, 1024), BF), _one((1024,), BF))
-
-
 def _ring_chunk(grad):
     from paddle_tpu.ops.pallas.ring_chunk_attention import (
         ring_chunk_attention)
@@ -377,12 +352,6 @@ _CASES = {
         False, qkv=(2, 8192, 16, 256), kv_heads=2),
     "flash_fwd_bwd_gqa_d256_seq8192": lambda mp: _flash(
         True, qkv=(2, 8192, 16, 256), kv_heads=2),
-    "fused_ffn_fwd": lambda mp: _ffn(False, mp),
-    "fused_ffn_bwd": lambda mp: _ffn(True, mp),
-    "layer_norm_fwd": lambda mp: _layer_norm(False),
-    "layer_norm_fwd_bwd": lambda mp: _layer_norm(True),
-    "rms_norm_fwd": lambda mp: _rms_norm(False),
-    "rms_norm_fwd_bwd": lambda mp: _rms_norm(True),
     "ring_chunk_fwd": lambda mp: _ring_chunk(False),
     "ring_chunk_fwd_bwd": lambda mp: _ring_chunk(True),
     # weight_quant="int4": the four matmuls of a layer, decode rows

@@ -323,6 +323,69 @@ class TestRouterElastic:
         reasons = [e["reason"] for e in router.audit]
         assert "migrated" in reasons and "scale_down" in reasons
 
+    def test_warm_migration_traces_nothing_and_accounts_every_prompt_token(
+            self):
+        """The scale drill's two count gates that no other test held: a
+        drain between WARM replicas (each has already exported and
+        imported once) traces nothing new on either, and over the whole
+        drill prefill computed + saved == prompt tokens submitted (a
+        migrated stream ships KV, it never replays its prompt)."""
+        fmt, embed, head = _model()
+        clock = [0.0]
+        # the prefill counters run where a prefix cache is configured
+        reps = [LocalReplica(f"replica{i}",
+                             _engine(fmt, embed, head,
+                                     prefix_cache_blocks=8),
+                             threaded=False, clock=lambda: clock[0])
+                for i in range(2)]
+        router = Router(reps, policy="round_robin", hb_dead_s=1e9,
+                        snap_max_age_s=0.0, clock=lambda: clock[0])
+        submitted = [0]
+
+        def migrate_once(seed):
+            prompt = _prompt(10, seed=seed)
+            submitted[0] += len(prompt)
+            want = _oracle(fmt, embed, head, prompt, 8)
+            gid = router.submit(prompt, max_new_tokens=8)
+            victim = router._table[gid].replica
+            vrep = router.replicas[victim]
+            got = []
+            deadline = time.monotonic() + WAIT_S
+            while len(got) < 3:
+                assert time.monotonic() < deadline
+                vrep.pump()
+                got += router.harvest(gid)[0]
+            summary = router.remove_replica(victim)
+            assert summary["migrated"] == 1 and \
+            summary["failed_over"] == 0, summary
+            other = router.replicas[router._table[gid].replica]
+            done = False
+            while not done:
+                assert time.monotonic() < deadline
+                other.pump()
+                new, done, _ = router.harvest(gid)
+                got += new
+            assert got == want
+            # the drain closed the handle: the same (warm) engine comes
+            # back under the retired name, as a replaced process would
+            router.add_replica(LocalReplica(
+                victim, vrep.engine, threaded=False,
+                clock=lambda: clock[0]))
+            return victim
+
+        victims = [migrate_once(seed) for seed in (11, 12, 13, 14)]
+        engines = [r.engine for r in reps]
+        assert {r.name for r in reps} <= set(victims), victims
+        traces = [e.metrics()["traces"] for e in engines]
+        victims += [migrate_once(seed) for seed in (15, 16)]
+        assert [e.metrics()["traces"] for e in engines] == traces
+        assert router.migrations_total == 6
+        assert router.migration_aborts_total == 0
+        assert router.failovers_total == 0
+        ms = [e.metrics() for e in engines]
+        assert sum(m["prefill_tokens_computed"]
+                   + m["prefill_tokens_saved"] for m in ms) == submitted[0]
+
     def test_deadline_survives_repeated_migration(self):
         """A deadline_s stream migrated TWICE keeps its real remaining
         budget: every leg computes remaining from the PRISTINE

@@ -11,7 +11,6 @@ import numpy as np
 import pytest
 
 from paddle_tpu.ops.pallas import flash_attention as fa
-from paddle_tpu.ops.pallas import layer_norm as pln
 
 
 def _sdpa_ref(q, k, v, causal):
@@ -67,12 +66,11 @@ def test_flash_attention_fwd_bwd(b, sq, h, hk, d, causal, sk):
 
 @pytest.mark.parametrize("causal", [False, True])
 def test_flash_attention_multiblock_split_bwd(monkeypatch, causal):
-    """Force 64-wide tiles so a 128/160-seq case runs the MULTI-block
-    grids and the split dKV/dQ backward (every default-tiling test shape
-    is single-block now that caps are 1024, and the fused single-block
+    """64-wide tiles make a 128/160-seq case run the MULTI-block grids
+    and the split dKV/dQ backward (every default-tiling test shape is
+    single-block now that caps are 1024, and the fused single-block
     backward handles those)."""
-    monkeypatch.setenv("PADDLE_TPU_FLASH_BQ", "64")
-    monkeypatch.setenv("PADDLE_TPU_FLASH_BK", "64")
+    monkeypatch.setattr(fa, "_block_sizes", lambda sq, sk, d=64: (64, 64))
     rng = np.random.RandomState(1)
     b, sq, h, hk, d, sk = 1, 128, 4, 2, 32, 160
     q = jnp.asarray(rng.randn(b, sq, h, d), jnp.float32)
@@ -98,17 +96,18 @@ def test_flash_fused_vs_split_bwd_dropout(monkeypatch):
     forward's mask from (seed, b, h, q-block, k-block) tile seeding, and a
     drift here corrupts training only on one dispatch path."""
     rng = np.random.RandomState(2)
-    b, s, h, d = 1, 64, 2, 32
+    b, s, h, d = 1, 128, 2, 32
     q = jnp.asarray(rng.randn(b, s, h, d), jnp.float32)
     k = jnp.asarray(rng.randn(b, s, h, d), jnp.float32)
     v = jnp.asarray(rng.randn(b, s, h, d), jnp.float32)
     seed = jnp.asarray(7, jnp.int32)
 
     def g(path_split):
+        # the default tiles make the 128-row slice one block (fused);
+        # 64-wide tiles make it two (the split pair)
         if path_split:
-            monkeypatch.setenv("PADDLE_TPU_FLASH_SPLIT_BWD", "1")
-        else:
-            monkeypatch.delenv("PADDLE_TPU_FLASH_SPLIT_BWD", raising=False)
+            monkeypatch.setattr(fa, "_block_sizes",
+                                lambda sq, sk, d=64: (64, 64))
         return jax.grad(lambda *a: jnp.sum(fa.flash_attention(
             *a, causal=True, dropout_p=0.3, dropout_seed=seed) * 0.1),
             (0, 1, 2))(q, k, v)
@@ -193,48 +192,6 @@ def test_flash_attention_bf16():
                                atol=3e-2, rtol=3e-2)
 
 
-@pytest.mark.parametrize("n,d", [(256, 512), (64, 768), (40, 384)])
-def test_layer_norm_fwd_bwd(n, d):
-    rng = np.random.RandomState(0)
-    x = jnp.asarray(rng.randn(n, d), jnp.float32)
-    g = jnp.asarray(rng.randn(d), jnp.float32)
-    b = jnp.asarray(rng.randn(d), jnp.float32)
-
-    y = pln.layer_norm(x, g, b)
-    mean = x.mean(-1, keepdims=True)
-    var = x.var(-1, keepdims=True)
-    y_ref = (x - mean) / jnp.sqrt(var + 1e-5) * g + b
-    np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref), atol=1e-4)
-
-    f = lambda x, g, b: jnp.sum(jnp.sin(pln.layer_norm(x, g, b)))
-    fr = lambda x, g, b: jnp.sum(jnp.sin(
-        (x - x.mean(-1, keepdims=True)) /
-        jnp.sqrt(x.var(-1, keepdims=True) + 1e-5) * g + b))
-    g1 = jax.grad(f, (0, 1, 2))(x, g, b)
-    g2 = jax.grad(fr, (0, 1, 2))(x, g, b)
-    for a, bb in zip(g1, g2):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(bb),
-                                   atol=1e-3, rtol=1e-3)
-
-
-def test_rms_norm_fwd_bwd():
-    rng = np.random.RandomState(0)
-    x = jnp.asarray(rng.randn(128, 512), jnp.float32)
-    g = jnp.asarray(rng.randn(512), jnp.float32)
-    y = pln.rms_norm(x, g)
-    y_ref = x / jnp.sqrt((x * x).mean(-1, keepdims=True) + 1e-6) * g
-    np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref), atol=1e-4)
-
-    f = lambda x, g: jnp.sum(jnp.sin(pln.rms_norm(x, g)))
-    fr = lambda x, g: jnp.sum(jnp.sin(
-        x / jnp.sqrt((x * x).mean(-1, keepdims=True) + 1e-6) * g))
-    g1 = jax.grad(f, (0, 1))(x, g)
-    g2 = jax.grad(fr, (0, 1))(x, g)
-    for a, bb in zip(g1, g2):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(bb),
-                                   atol=1e-3, rtol=1e-3)
-
-
 def test_functional_layer_norm_uses_tape():
     """F.layer_norm still differentiates through the Tensor tape."""
     import paddle_tpu as paddle
@@ -249,27 +206,13 @@ def test_functional_layer_norm_uses_tape():
 
 
 def test_forced_pallas_dispatch_through_tape(monkeypatch):
-    """PADDLE_TPU_FORCE_PALLAS=1 routes F.layer_norm / F.rms_norm / sdpa
-    through the Pallas kernels (interpret mode on CPU) including backward —
-    catches apply_op→custom_vjp wiring breaks before they hit real TPU."""
-    monkeypatch.setenv("PADDLE_TPU_FORCE_PALLAS", "1")
+    """With the one gate flipped, sdpa routes through the Pallas flash
+    kernel (interpret mode on CPU) including backward — catches
+    apply_op→custom_vjp wiring breaks before they hit real TPU."""
+    from paddle_tpu.ops import pallas
+    monkeypatch.setattr(pallas, "_enabled", lambda: True)
     import paddle_tpu as paddle
     import paddle_tpu.nn.functional as F
-
-    x = paddle.to_tensor(np.random.randn(4, 128).astype(np.float32),
-                         stop_gradient=False)
-    w = paddle.to_tensor(np.ones(128, np.float32), stop_gradient=False)
-    b = paddle.to_tensor(np.zeros(128, np.float32), stop_gradient=False)
-    y = F.layer_norm(x, 128, w, b)
-    y.sum().backward()
-    assert x.grad is not None and w.grad is not None and b.grad is not None
-
-    x2 = paddle.to_tensor(np.random.randn(2, 64).astype(np.float32),
-                         stop_gradient=False)
-    w2 = paddle.to_tensor(np.ones(64, np.float32), stop_gradient=False)
-    y2 = F.rms_norm(x2, w2)
-    y2.sum().backward()
-    assert x2.grad is not None and w2.grad is not None
 
     q = paddle.to_tensor(np.random.randn(2, 16, 4, 32).astype(np.float32),
                          stop_gradient=False)
@@ -492,8 +435,12 @@ class TestFlashDropout:
                                        atol=2e-4, rtol=2e-4)
 
     def test_sdpa_routes_dropout_to_flash(self, monkeypatch):
-        monkeypatch.setenv("PADDLE_TPU_FORCE_PALLAS", "1")
-        monkeypatch.setenv("PADDLE_TPU_FLASH_DROPOUT", "1")
+        from paddle_tpu.ops import pallas
+        monkeypatch.setattr(pallas, "_enabled", lambda: True)
+        taken = []
+        real = fa.flash_attention
+        monkeypatch.setattr(fa, "flash_attention", lambda *a, **kw: (
+            taken.append(kw["dropout_p"]), real(*a, **kw))[1])
         import paddle_tpu as paddle
         import paddle_tpu.nn.functional as F
         paddle.seed(4)
@@ -505,215 +452,11 @@ class TestFlashDropout:
                                                 training=True, is_causal=True)
         o_ref = F.scaled_dot_product_attention(q.detach(), k, v,
                                                dropout_p=0.0, is_causal=True)
+        assert taken == [0.9, 0.0]
         assert not np.allclose(np.asarray(o_drop._data),
                                np.asarray(o_ref._data))
         o_drop.sum().backward()
         assert q.grad is not None
-
-
-class TestFusedFFN:
-    """Pallas fused bias+gelu+matmul FFN (reference anchor:
-    fused_feedforward_op.cu) vs the XLA composite — fwd, grads, and the
-    GPTMLP opt-in dispatch."""
-
-    def _args(self, M=64, K=128, F=256, dtype=jnp.float32):
-        rng = np.random.RandomState(0)
-        return (jnp.asarray(rng.randn(M, K), dtype),
-                jnp.asarray(rng.randn(K, F) * 0.05, dtype),
-                jnp.asarray(rng.randn(F) * 0.1, dtype),
-                jnp.asarray(rng.randn(F, K) * 0.05, dtype),
-                jnp.asarray(rng.randn(K) * 0.1, dtype))
-
-    def test_fwd_and_grads_match_composite(self):
-        from paddle_tpu.ops.pallas.fused_ffn import (_composite,
-                                                     ffn_is_supported,
-                                                     fused_ffn)
-        args = self._args()
-        assert ffn_is_supported(64, 128, 256, jnp.float32)
-        np.testing.assert_allclose(np.asarray(fused_ffn(*args)),
-                                   np.asarray(_composite(*args)),
-                                   atol=1e-5, rtol=1e-5)
-        lf = lambda fn: (lambda *a: jnp.sum(fn(*a) ** 2))
-        g1 = jax.grad(lf(fused_ffn), argnums=(0, 1, 2, 3, 4))(*args)
-        g2 = jax.grad(lf(_composite), argnums=(0, 1, 2, 3, 4))(*args)
-        for a, b in zip(g1, g2):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       atol=5e-3, rtol=1e-3)
-
-    def test_llama_shape_f_not_multiple_of_512(self):
-        """F=2816 (the LLaMA 1024/2816 shape) is a 128- but not
-        512-multiple: bf must step down to a divisor — a truncating
-        nf = f // bf would silently drop the last 256 columns."""
-        from paddle_tpu.ops.pallas.fused_ffn import _composite, fused_ffn
-        rng = np.random.RandomState(3)
-        M, K, F = 16, 128, 2816
-        x = jnp.asarray(rng.randn(M, K), jnp.float32)
-        w1 = jnp.asarray(rng.randn(K, F) * 0.03, jnp.float32)
-        b1 = jnp.asarray(rng.randn(F) * 0.1, jnp.float32)
-        w2 = jnp.asarray(rng.randn(F, K) * 0.03, jnp.float32)
-        b2 = jnp.asarray(rng.randn(K) * 0.1, jnp.float32)
-        np.testing.assert_allclose(
-            np.asarray(fused_ffn(x, w1, b1, w2, b2)),
-            np.asarray(_composite(x, w1, b1, w2, b2)),
-            atol=2e-4, rtol=1e-4)
-
-    def test_fallback_on_untileable_shapes(self):
-        from paddle_tpu.ops.pallas.fused_ffn import _composite, fused_ffn
-        rng = np.random.RandomState(1)
-        # K=96 not a 128-multiple: must fall back, not crash
-        x = jnp.asarray(rng.randn(16, 96), jnp.float32)
-        w1 = jnp.asarray(rng.randn(96, 192) * 0.05, jnp.float32)
-        b1 = jnp.zeros(192, jnp.float32)
-        w2 = jnp.asarray(rng.randn(192, 96) * 0.05, jnp.float32)
-        b2 = jnp.zeros(96, jnp.float32)
-        np.testing.assert_allclose(
-            np.asarray(fused_ffn(x, w1, b1, w2, b2)),
-            np.asarray(_composite(x, w1, b1, w2, b2)), atol=1e-5)
-
-    def test_gptmlp_dispatch_matches(self, monkeypatch):
-        import paddle_tpu as paddle
-        from paddle_tpu.models.gpt import GPTConfig, GPTMLP
-        c = GPTConfig(hidden_size=128, intermediate_size=256, num_layers=2)
-        paddle.seed(13)
-        mlp = GPTMLP(c)
-        x = paddle.to_tensor(np.random.RandomState(2).randn(
-            2, 16, 128).astype(np.float32))
-        monkeypatch.delenv("PADDLE_TPU_FUSED_FFN", raising=False)
-        ref = mlp(x)
-        monkeypatch.setenv("PADDLE_TPU_FUSED_FFN", "1")
-        out = mlp(x)
-        np.testing.assert_allclose(np.asarray(out._data),
-                                   np.asarray(ref._data), atol=1e-5)
-        # and grads flow through the tape
-        loss = (out ** 2).mean()
-        loss.backward()
-        assert mlp.fc1.weight.grad is not None
-
-    def test_exact_gelu_activation_matches(self):
-        """activation='gelu' (exact/erf — the reference
-        fused_feedforward_op's act) must match the composite, fwd+bwd."""
-        from paddle_tpu.ops.pallas.fused_ffn import _composite, fused_ffn
-        args = self._args()
-        np.testing.assert_allclose(
-            np.asarray(fused_ffn(*args, "gelu")),
-            np.asarray(_composite(*args, "gelu")), atol=1e-5, rtol=1e-5)
-        lf = lambda fn: (lambda *a: jnp.sum(fn(*a, "gelu") ** 2))
-        g1 = jax.grad(lf(fused_ffn), argnums=(0, 1, 2, 3, 4))(*args)
-        g2 = jax.grad(lf(_composite), argnums=(0, 1, 2, 3, 4))(*args)
-        for a, b in zip(g1, g2):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       atol=5e-3, rtol=1e-3)
-
-    def test_incubate_fused_feedforward_routes_to_kernel(self, monkeypatch):
-        """incubate.nn.functional.fused_feedforward under the opt-in env
-        must equal its composite path exactly (inert dropout, exact
-        gelu)."""
-        import paddle_tpu as paddle
-        from paddle_tpu.incubate.nn.functional import fused_feedforward
-        rng = np.random.RandomState(5)
-        x = paddle.to_tensor(rng.randn(2, 16, 128).astype(np.float32))
-        w1 = paddle.to_tensor((rng.randn(128, 256) * 0.05).astype(np.float32))
-        b1 = paddle.to_tensor((rng.randn(256) * 0.1).astype(np.float32))
-        w2 = paddle.to_tensor((rng.randn(256, 128) * 0.05).astype(np.float32))
-        b2 = paddle.to_tensor((rng.randn(128) * 0.1).astype(np.float32))
-        kw = dict(dropout1_rate=0.0, dropout2_rate=0.0, activation="gelu",
-                  pre_layer_norm=True,
-                  ln1_scale=paddle.to_tensor(np.ones(128, np.float32)),
-                  ln1_bias=paddle.to_tensor(np.zeros(128, np.float32)))
-        monkeypatch.delenv("PADDLE_TPU_FUSED_FFN", raising=False)
-        ref = fused_feedforward(x, w1, w2, b1, b2, **kw)
-        monkeypatch.setenv("PADDLE_TPU_FUSED_FFN", "1")
-        out = fused_feedforward(x, w1, w2, b1, b2, **kw)
-        np.testing.assert_allclose(np.asarray(out._data),
-                                   np.asarray(ref._data),
-                                   atol=1e-5, rtol=1e-5)
-
-
-class TestFusedFFNMeshGuard:
-    def test_mp_mesh_routes_to_composite(self, monkeypatch):
-        """Advisor r4: PADDLE_TPU_FUSED_FFN=1 under a model-parallel mesh
-        must NOT hand sharded operands to a pallas_call (SPMD barrier) —
-        both the GPTMLP and incubate fused_feedforward env paths route to
-        the XLA composite whenever an mp>=2 mesh is active."""
-        import paddle_tpu as paddle
-        import paddle_tpu.ops.pallas.fused_ffn as ffn_mod
-        from paddle_tpu.models.gpt import GPTConfig, GPTMLP
-
-        class FakeMesh:
-            shape = {"mp": 2}
-        monkeypatch.setattr("paddle_tpu.parallel.current_mesh",
-                            lambda: FakeMesh())
-
-        def boom(*a, **k):
-            raise AssertionError("fused_ffn kernel reached under mp mesh")
-        monkeypatch.setattr(ffn_mod, "fused_ffn", boom)
-        monkeypatch.setenv("PADDLE_TPU_FUSED_FFN", "1")
-
-        c = GPTConfig(hidden_size=128, intermediate_size=256, num_layers=2)
-        paddle.seed(14)
-        mlp = GPTMLP(c)
-        x = paddle.to_tensor(np.random.RandomState(3).randn(
-            2, 8, 128).astype(np.float32))
-        out = mlp(x)   # must take the composite, not raise
-        assert np.isfinite(np.asarray(out._data)).all()
-
-        from paddle_tpu.incubate.nn.functional import fused_feedforward
-        rng = np.random.RandomState(6)
-        w1 = paddle.to_tensor((rng.randn(128, 256) * .05).astype(np.float32))
-        b1 = paddle.to_tensor(np.zeros(256, np.float32))
-        w2 = paddle.to_tensor((rng.randn(256, 128) * .05).astype(np.float32))
-        b2 = paddle.to_tensor(np.zeros(128, np.float32))
-        out2 = fused_feedforward(x, w1, w2, b1, b2, dropout1_rate=0.0,
-                                 dropout2_rate=0.0, activation="gelu",
-                                 pre_layer_norm=False)
-        assert np.isfinite(np.asarray(out2._data)).all()
-
-
-class TestFusedFFNBwdKernels:
-    """r5 verdict #5: the two-kernel Pallas backward (opt-in
-    PADDLE_TPU_FUSED_FFN_BWD=1) must match the composite backward for
-    both activations — all five grads, fp32-accumulated."""
-
-    @pytest.mark.parametrize("act", ["gelu_tanh", "gelu"])
-    def test_bwd_kernels_match_composite(self, act, monkeypatch):
-        from paddle_tpu.ops.pallas.fused_ffn import _composite, fused_ffn
-        rng = np.random.RandomState(9)
-        m, k, f = 24, 128, 256
-        x = jnp.asarray(rng.randn(m, k) * 0.5, jnp.float32)
-        w1 = jnp.asarray(rng.randn(k, f) * 0.05, jnp.float32)
-        b1 = jnp.asarray(rng.randn(f) * 0.1, jnp.float32)
-        w2 = jnp.asarray(rng.randn(f, k) * 0.05, jnp.float32)
-        b2 = jnp.asarray(rng.randn(k) * 0.1, jnp.float32)
-        lf = lambda fn: (lambda *a: jnp.sum(fn(*a, act) ** 2))
-        monkeypatch.delenv("PADDLE_TPU_FUSED_FFN_BWD", raising=False)
-        ref = jax.grad(lf(_composite), argnums=(0, 1, 2, 3, 4))(
-            x, w1, b1, w2, b2)
-        monkeypatch.setenv("PADDLE_TPU_FUSED_FFN_BWD", "1")
-        got = jax.grad(lf(fused_ffn), argnums=(0, 1, 2, 3, 4))(
-            x, w1, b1, w2, b2)
-        for name, a, b in zip("dx dw1 db1 dw2 db2".split(), got, ref):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       atol=5e-3, rtol=1e-3, err_msg=name)
-
-    def test_bwd_kernels_batched_leading_dims(self, monkeypatch):
-        """[B, S, K] inputs flatten to [M, K]; grads reshape back."""
-        from paddle_tpu.ops.pallas.fused_ffn import fused_ffn
-        rng = np.random.RandomState(10)
-        x = jnp.asarray(rng.randn(2, 16, 128) * 0.5, jnp.bfloat16)
-        w1 = jnp.asarray(rng.randn(128, 256) * 0.05, jnp.bfloat16)
-        b1 = jnp.asarray(rng.randn(256) * 0.1, jnp.bfloat16)
-        w2 = jnp.asarray(rng.randn(256, 128) * 0.05, jnp.bfloat16)
-        b2 = jnp.asarray(rng.randn(128) * 0.1, jnp.bfloat16)
-        lf = lambda *a: jnp.sum(fused_ffn(*a).astype(jnp.float32) ** 2)
-        monkeypatch.delenv("PADDLE_TPU_FUSED_FFN_BWD", raising=False)
-        ref = jax.grad(lf, argnums=(0, 1, 2, 3, 4))(x, w1, b1, w2, b2)
-        monkeypatch.setenv("PADDLE_TPU_FUSED_FFN_BWD", "1")
-        got = jax.grad(lf, argnums=(0, 1, 2, 3, 4))(x, w1, b1, w2, b2)
-        for a, b in zip(got, ref):
-            assert a.shape == b.shape and a.dtype == b.dtype
-            np.testing.assert_allclose(
-                np.asarray(a, np.float32), np.asarray(b, np.float32),
-                atol=0.15, rtol=0.05)
 
 
 def test_decode_attention_stacked_i8_write_parity():

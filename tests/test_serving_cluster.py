@@ -1487,6 +1487,57 @@ class TestGrayFailureDefense:
         assert r.audit_counts["hedge"] == 1
         assert r.poll(gid)["resubmits"] == 1
 
+    def test_hedge_on_real_engines_token_identical_zero_retraces(self):
+        """The gray drill's count gates on REAL engines (virtual clock):
+        the owner is alive but never pumped, the hedge leg on the other
+        replica wins, the client sees exactly the oracle's greedy
+        tokens (no loser token double-billed), nothing is declared dead
+        or failed over, and warm engines trace nothing new for any of
+        it."""
+        fmt, embed, head = _model()
+        clk = _Clock()
+        reps = [LocalReplica(n, _engine(fmt, embed, head), threaded=False,
+                             clock=clk) for n in ("a", "b")]
+        r = self._hedge_router(*reps, clk, policy="round_robin",
+                               hb_dead_s=1e9)
+        by_name = {rep.name: rep for rep in reps}
+
+        def run(seed):
+            prompt = [int(t) for t in
+                      np.random.RandomState(seed).randint(1, V, (10,))]
+            gid = r.submit(prompt, max_new_tokens=6)
+            owner = r.poll(gid)["replica"]
+            other = by_name["b" if owner == "a" else "a"]
+            assert r.harvest(gid)[0] == []     # not overdue yet
+            clk.t += 1.0                       # way past p95 x margin
+            r.harvest(gid)                     # arms the hedge
+            got, done = [], False
+            deadline = time.monotonic() + WAIT_S
+            while not done:
+                assert time.monotonic() < deadline
+                other.pump()                   # the owner stays silent
+                new, done, state = r.harvest(gid)
+                got += new
+            assert state == "finished"
+            assert got == _oracle(fmt, embed, head, prompt, 6)
+            assert r.poll(gid)["replica"] == other.name
+            # the released loser leg runs out on its own engine; none
+            # of its tokens reach the stream
+            while by_name[owner].engine.has_work:
+                assert time.monotonic() < deadline
+                by_name[owner].pump()
+            assert r.harvest(gid) == ([], True, "finished")
+            return owner
+
+        owners = {run(0), run(1)}              # warm-up: both directions
+        assert owners == {"a", "b"}
+        traces = [rep.engine.metrics()["traces"] for rep in reps]
+        run(2)
+        run(3)
+        assert [rep.engine.metrics()["traces"] for rep in reps] == traces
+        assert r.hedges_total == r.hedge_wins_total == 4
+        assert r.failovers_total == 0 and not r.dead
+
     def test_hedge_loses_when_owner_answers_first(self):
         """The owner producing its first token makes the hedge leg the
         loser: released immediately, zero hedge wins, and the stream is

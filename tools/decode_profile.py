@@ -1,8 +1,8 @@
 """Ablation profile of the FusedDecoder per-token decode cost.
 
-bench_decode r3 s4 measured ~0.58 s fixed + ~10 ms/token marginal against
-a ~1 ms/token memory floor; this tool isolates where the marginal cost
-lives by timing compiled 64-token decode chunks with pieces swapped out:
+An early round measured ~0.58 s fixed + ~10 ms/token marginal against a
+~1 ms/token memory floor; this tool isolates where the marginal cost lives
+by timing compiled 64-token decode chunks with pieces swapped out:
 
   full         — the real chunk scan (attend kernel + cache update + head)
   dense_attend — decode-kernel dispatch gate forced off, so attention

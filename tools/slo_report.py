@@ -12,7 +12,7 @@ reasons, and per-trace-id request journeys (attempt > 1 = failover).
 Usage:
     curl -s localhost:8100/metrics > /tmp/cluster.prom
     python tools/slo_report.py --metrics /tmp/cluster.prom \
-        [--trace /tmp/cluster_trace.json] [--bench BENCH_serving.json]
+        [--trace /tmp/cluster_trace.json]
 
 Import-light on purpose (stdlib + numpy via telemetry's parser): the
 post-mortem tool must run on a box with no jax. Exit 0 on success, 1
@@ -21,7 +21,6 @@ when a given artifact is missing/invalid.
 from __future__ import annotations
 
 import argparse
-import json
 import os
 import re
 import sys
@@ -164,23 +163,6 @@ def report_trace(path, out):
     return 0
 
 
-def report_bench(path, out):
-    try:
-        with open(path) as f:
-            rec = json.load(f)
-    except (OSError, ValueError) as e:
-        out.append(f"slo_report: cannot read bench {path!r}: {e}")
-        return 1
-    slo = (rec.get("cluster") or {}).get("slo")
-    if slo is None:
-        out.append(f"slo_report: {path!r} has no cluster 'slo' block "
-                   "(run bench_serving.py --cluster first)")
-        return 1
-    out.append(f"== BENCH cluster slo ({os.path.basename(path)}) ==")
-    out.append("  " + json.dumps(slo))
-    return 0
-
-
 def main(argv=None):
     ap = argparse.ArgumentParser(
         prog="python tools/slo_report.py",
@@ -189,11 +171,8 @@ def main(argv=None):
                     help="saved /metrics exposition file(s)")
     ap.add_argument("--trace", default=None,
                     help="merged cluster Perfetto trace json")
-    ap.add_argument("--bench", default=None,
-                    help="BENCH_serving.json (reads the cluster slo "
-                         "block)")
     args = ap.parse_args(argv)
-    if not args.metrics and args.trace is None and args.bench is None:
+    if not args.metrics and args.trace is None:
         ap.print_help()
         return 1
     out, rc = [], 0
@@ -201,8 +180,6 @@ def main(argv=None):
         rc |= report_metrics(p, out)
     if args.trace is not None:
         rc |= report_trace(args.trace, out)
-    if args.bench is not None:
-        rc |= report_bench(args.bench, out)
     print("\n".join(out))
     return rc
 

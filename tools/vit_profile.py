@@ -64,7 +64,7 @@ def main():
         float(np.asarray(out._data).sum())
         ts = []
         chunk = max(steps // 3, 1)
-        for _ in range(3):          # median of chunks, like bench.py
+        for _ in range(3):          # median of chunks
             t0 = time.perf_counter()
             for _ in range(chunk):
                 out = step(x, y)
