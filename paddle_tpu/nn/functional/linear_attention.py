@@ -22,8 +22,20 @@ strong the decay.
 
 Decays and the solve run in float32; the matrix products take their
 operands in ``matmul_dtype`` (the dtype of ``v`` unless stated; bf16 in a
-bf16 model) and accumulate in float32. The backward pass is JAX's own
-through the rule; the convolution's is written out (``_causal_conv_bwd``).
+bf16 model) and accumulate in float32. The convolution's backward pass is
+written out (``_causal_conv_bwd``).
+
+Two forms of one arithmetic, chosen per call from backend, shapes, dtypes
+and mesh by ``ops/pallas/gated_delta_rule.is_supported`` (on a TPU, chunks
+of 64, heads of a multiple of 128, no multi-device mesh): the KERNEL
+``gdn_chunk_rule_fwd``, which keeps a chunk's matrices and the state in
+VMEM, and the COMPOSITE ``_chunk_rule`` below, batched XLA products over
+blocks of 16 chunks. The composite's backward pass is JAX's own through its
+checkpointed scan. The kernel's is that same program (``_kernel_rule_bwd``):
+the kernel saves the state at each block's start, and a reverse scan
+replays each block through the composite's ``block_of_chunks`` and carries
+``dS``. So ``block_of_chunks`` is the one definition of the rule outside
+the kernel, and every gradient is the composite's.
 
 Layout. The TPU keeps the last two axes of an array in (8, 128) tiles: 8
 rows in the sublanes, 128 columns in the lanes. A ``[B, T, H * 128]``
@@ -40,8 +52,10 @@ to split the sequence into ``(T / 8, 8)`` FIRST and move the heads in
 front of the 8: then every step is a move of whole tiles, or no move at
 all. ``_chunk_rule`` forms its blocks that way and returns its result by
 the mirrored path; a caller that works on heads (the layer's gated norm)
-does the same with ``TILE_ROWS``. A kernel for the rule would read the
-same layout through a ``BlockSpec`` of ``(1, chunk, 128)``.
+does the same with ``TILE_ROWS``. The kernel reads the same layout in
+place, through ``BlockSpec``s of ``(1, 16 chunks, heads * 128)``, and writes
+its result the same way: only the composite, and so the backward pass,
+still moves tiles.
 """
 from __future__ import annotations
 
@@ -50,6 +64,9 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ...inference.telemetry import runtime_counter
+from ...ops.pallas import gated_delta_rule
+from ...parallel import current_mesh
 from ...tensor.tensor import apply_op
 
 __all__ = ["causal_conv1d", "chunk_gated_delta_rule"]
@@ -160,10 +177,16 @@ def _inverse_unit_lower(a):
     return inv
 
 
-def _chunk_rule(q, k, v, g, beta, *, chunk, mm):
-    b, t, hk, dk = q.shape
-    hv, dv = v.shape[2], v.shape[3]
-    r, out = hv // hk, v.dtype      # each key head serves r value heads
+def _composite(q_shape, v_shape, out, chunk, mm):
+    """The rule's composite for these shapes, in the three pieces its two
+    users share: ``to_blocks(q, k, v, g, beta)`` (the operands a block of
+    ``nb`` chunks, [G, B, ...]), ``block_of_chunks(s, xs)`` (one block from
+    its start state ``s`` [B,hk,r,dk,dv]: the one definition of the rule
+    outside the kernel) and ``from_blocks(o)`` ([G,nb,B,hk,r,C,dv] ->
+    [B,T,hv,dv] in ``out``); then the shapes of ``s`` and of that ``o``."""
+    b, t, hk, dk = q_shape
+    hv, dv = v_shape[2], v_shape[3]
+    r = hv // hk                    # each key head serves r value heads
     nb = min(_BLOCK_CHUNKS, -(-t // chunk))     # chunks a block
     pad = -t % (chunk * nb)         # a padded token decays nothing (g 0)
     n_blocks = (t + pad) // (chunk * nb)    # and writes nothing (beta, k 0)
@@ -184,9 +207,10 @@ def _chunk_rule(q, k, v, g, beta, *, chunk, mm):
         x = x.reshape(b, n_blocks, nb, chunk, hk, r)
         return x.transpose(1, 0, 4, 5, 2, 3)
 
-    q, k = blocks(q, (hk,)), blocks(k, (hk,))           # [G,B,hk,nb,C,dk]
-    v = blocks(v, (hk, r))                              # [G,B,hk,r,nb,C,dv]
-    g, beta = gate_blocks(g), gate_blocks(beta)
+    def to_blocks(q, k, v, g, beta):
+        return (blocks(q, (hk,)), blocks(k, (hk,)),     # [G,B,hk,nb,C,dk]
+                blocks(v, (hk, r)),                     # [G,B,hk,r,nb,C,dv]
+                gate_blocks(g), gate_blocks(beta))
 
     def dot(spec, x, y):
         return jnp.einsum(spec, x.astype(mm), y.astype(mm),
@@ -204,12 +228,9 @@ def _chunk_rule(q, k, v, g, beta, *, chunk, mm):
         s = last_n[..., None, None] * s + dot("bhrcd,bhrce->bhrde", kd_n, vp)
         return s, o
 
-    @jax.checkpoint
     def block_of_chunks(s, xs):
         """What needs no state for ``nb`` chunks at once, then the scan
-        over them. Checkpointed: the backward pass keeps the state at the
-        block's start and recomputes the block, so that the rule's working
-        set is one block's and not the sequence's."""
+        over them."""
         q_, k_, v_, g_, beta_ = xs
         q_, k_ = _l2norm(q_) * dk ** -0.5, _l2norm(k_)
         gamma = jnp.cumsum(g_, axis=-1)                 # [B,hk,r,nb,C]
@@ -232,13 +253,64 @@ def _chunk_rule(q, k, v, g, beta, *, chunk, mm):
         s, o = jax.lax.scan(step, s, tuple(jnp.moveaxis(x, 3, 0) for x in xs))
         return s, o                                     # o [nb,B,hk,r,C,dv]
 
-    _, o = jax.lax.scan(block_of_chunks,
-                        jnp.zeros((b, hk, r, dk, dv), _F32),
-                        (q, k, v, g, beta))
-    # [G,nb,B,hk,r,C,dv] -> [B,T,hv,dv] by the mirrored path
-    o = o.reshape(n_blocks, nb, b, hv, chunk // TILE_ROWS, TILE_ROWS, dv)
-    o = o.transpose(2, 0, 1, 4, 5, 3, 6).reshape(b, t + pad, hv, dv)
-    return o[:, :t].astype(out)
+    def from_blocks(o):
+        """[G,nb,B,hk,r,C,dv] -> [B,T,hv,dv] by the mirrored path."""
+        o = o.reshape(n_blocks, nb, b, hv, chunk // TILE_ROWS, TILE_ROWS, dv)
+        o = o.transpose(2, 0, 1, 4, 5, 3, 6).reshape(b, t + pad, hv, dv)
+        return o[:, :t].astype(out)
+
+    return (to_blocks, block_of_chunks, from_blocks, (b, hk, r, dk, dv),
+            (n_blocks, nb, b, hk, r, chunk, dv))
+
+
+def _chunk_rule(q, k, v, g, beta, *, chunk, mm):
+    """The composite, whole. ``block_of_chunks`` is checkpointed: the
+    backward pass keeps the state at each block's start and recomputes the
+    block, so that the rule's working set is one block's and not the
+    sequence's."""
+    to_blocks, block_of_chunks, from_blocks, state, _ = _composite(
+        q.shape, v.shape, v.dtype, chunk, mm)
+    _, o = jax.lax.scan(jax.checkpoint(block_of_chunks),
+                        jnp.zeros(state, _F32), to_blocks(q, k, v, g, beta))
+    return from_blocks(o)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _kernel_rule(q, k, v, g, beta, mm):
+    """The rule with its forward as ``ops/pallas/gated_delta_rule``'s
+    kernel (chunks of 64). Its backward is the composite's own, as JAX
+    derives it from ``_chunk_rule``'s checkpointed scan: a reverse scan
+    over the blocks that replays each from its start state, which the
+    kernel saved, and carries ``dS``."""
+    return _kernel_rule_fwd(q, k, v, g, beta, mm)[0]
+
+
+def _kernel_rule_fwd(q, k, v, g, beta, mm):
+    o, states = gated_delta_rule.gdn_chunk_rule_fwd(
+        q, k, v, g, beta, mm=mm, block_chunks=_BLOCK_CHUNKS)
+    return o, (q, k, v, g, beta, states)
+
+
+def _kernel_rule_bwd(mm, res, do):
+    *inputs, states = res
+    q, v = inputs[0], inputs[2]
+    to_blocks, block_of_chunks, from_blocks, state, o_blocks = _composite(
+        q.shape, v.shape, v.dtype, gated_delta_rule.CHUNK, mm)
+    xs, blocks_vjp = jax.vjp(to_blocks, *inputs)
+    do, = jax.linear_transpose(
+        from_blocks, jax.ShapeDtypeStruct(o_blocks, _F32))(do)
+
+    def block(ds, saved):
+        s, x, do_ = saved
+        ds, dx = jax.vjp(block_of_chunks, s, x)[1]((ds, do_))
+        return ds, dx
+    _, dxs = jax.lax.scan(
+        block, jnp.zeros(state, _F32),
+        (states.reshape(states.shape[:1] + state), xs, do), reverse=True)
+    return blocks_vjp(dxs)
+
+
+_kernel_rule.defvjp(_kernel_rule_fwd, _kernel_rule_bwd)
 
 
 def chunk_gated_delta_rule(q, k, v, g, beta, chunk_size=64,
@@ -255,7 +327,10 @@ def chunk_gated_delta_rule(q, k, v, g, beta, chunk_size=64,
     ``matmul_dtype`` (default: ``v``'s dtype) and accumulate in float32; the
     result comes in ``v``'s dtype. 16 chunks at a time are prepared
     together and recomputed in the backward pass: the working set is one
-    block's whatever ``T``."""
+    block's whatever ``T``. Takes the Pallas kernel where
+    ``gated_delta_rule.is_supported`` says so and the composite elsewhere
+    (the module docstring); ``paddle_gdn_rule_kernel_traces_total`` or
+    ``paddle_gdn_rule_composite_traces_total`` counts each trace."""
     if chunk_size < 8 or chunk_size & (chunk_size - 1):
         raise ValueError(f"chunk_gated_delta_rule: chunk_size {chunk_size} "
                          "is not a power of two >= 8")
@@ -264,8 +339,17 @@ def chunk_gated_delta_rule(q, k, v, g, beta, chunk_size=64,
             f"chunk_gated_delta_rule: {q.shape[2]} key heads do not divide "
             f"{v.shape[2]} value heads")
 
-    def f(q_, k_, v_, g_, beta_):
+    mesh = current_mesh()
+
+    def f(*arrays):
+        mm = jnp.dtype(matmul_dtype or arrays[2].dtype)
+        kernel = gated_delta_rule.is_supported(
+            arrays[0].shape, arrays[2].shape, int(chunk_size),
+            [a.dtype for a in arrays], mm, mesh, _BLOCK_CHUNKS)
+        runtime_counter("paddle_gdn_rule_kernel_traces_total" if kernel
+                        else "paddle_gdn_rule_composite_traces_total", 1)
         with jax.named_scope("gdn.chunk_rule"):
-            return _chunk_rule(q_, k_, v_, g_, beta_, chunk=int(chunk_size),
-                               mm=matmul_dtype or v_.dtype)
+            if kernel:
+                return _kernel_rule(*arrays, mm)
+            return _chunk_rule(*arrays, chunk=int(chunk_size), mm=mm)
     return apply_op(f, q, k, v, g, beta)

@@ -53,13 +53,37 @@ def _qwen3next_tiny():
     return model, rng.randint(0, 128, (2, 64)).astype(np.int64)
 
 
-@pytest.mark.parametrize("build", [_gpt2_tiny, _qwen3next_tiny],
-                         ids=["gpt2_tiny", "qwen3next_tiny"])
-def test_training_trace_reads_no_kernel_env(build, monkeypatch, flash_calls):
+def _qwen3next_heads_of_128():
+    """The tiny model with the Gated DeltaNet heads at the published 128
+    and chunks of 64: what the delta rule's kernel takes."""
+    from paddle_tpu.models.qwen3_next import qwen3_next_tiny
+    model = qwen3_next_tiny(vocab_size=128, experts_held=[0, 1, 2, 3],
+                            recompute=True, linear_num_key_heads=1,
+                            linear_num_value_heads=2, linear_key_head_dim=128,
+                            linear_value_head_dim=128, chunk_size=64)
+    rng = np.random.RandomState(0)
+    return model, rng.randint(0, 128, (2, 64)).astype(np.int64)
+
+
+def _rule_traces():
+    from paddle_tpu.inference.telemetry import runtime_counter
+    return tuple(runtime_counter(f"paddle_gdn_rule_{which}_traces_total")
+                 for which in ("kernel", "composite"))
+
+
+@pytest.mark.parametrize("build,rule", [
+    (_gpt2_tiny, (False, False)), (_qwen3next_tiny, (False, True)),
+    (_qwen3next_heads_of_128, (True, False))],
+    ids=["gpt2_tiny", "qwen3next_tiny", "qwen3next_rule_kernel"])
+def test_training_trace_reads_no_kernel_env(build, rule, monkeypatch,
+                                            flash_calls):
     """Both cells' families, the step under ``to_static`` as the benchmark
     runs it: while it is traced (twice: the optimizer's slots appear in the
     first call) no ``PADDLE_TPU_*`` variable is read but the two named
-    debts (ROADMAP.md D11)."""
+    debts (ROADMAP.md D11). ``rule``: whether the traces took the delta
+    rule's kernel, and its composite (heads of 16 in chunks of 16 are not
+    the kernel's; GPT-2 has no such layer)."""
+    before = _rule_traces()
     paddle.seed(5)
     model, ids = build()
     model.bfloat16()
@@ -90,6 +114,7 @@ def test_training_trace_reads_no_kernel_env(build, monkeypatch, flash_calls):
     assert flash_calls, "the flash kernel was not in the traced step"
     levers = {k for k in read if k.startswith("PADDLE_TPU_")}
     assert levers <= {"PADDLE_TPU_PRNG_IMPL", "PADDLE_TPU_FUSE_EAGER_STEP"}
+    assert tuple(b > a for a, b in zip(before, _rule_traces())) == rule
 
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -214,6 +239,74 @@ def test_sdpa_takes_the_kernel_when_it_can(q, k, mask, mesh, taken,
                                              is_causal=True)
         assert tuple(out.shape) == q
     assert bool(flash_calls) is taken
+
+
+# --------------------------------- the delta rule: kernel or composite
+_CELL_Q, _CELL_V = (2, 8192, 16, 128), (2, 8192, 32, 128)
+
+
+@pytest.mark.parametrize("on,q,v,chunk,dtype,mm,mesh,taken", [
+    (True, _CELL_Q, _CELL_V, 64, "float32", "bfloat16", None, True),
+    (True, (2, 100, 1, 128), (2, 100, 1, 128), 64, "bfloat16", "float32",
+     None, True),
+    (False, _CELL_Q, _CELL_V, 64, "float32", "bfloat16", None, False),
+    (True, _CELL_Q, _CELL_V, 16, "float32", "bfloat16", None, False),
+    (True, (2, 64, 2, 16), (2, 64, 4, 128), 64, "float32", "float32", None,
+     False),
+    (True, (2, 64, 2, 128), (2, 64, 4, 8), 64, "float32", "float32", None,
+     False),
+    (True, (2, 64, 2, 128), (2, 64, 3, 128), 64, "float32", "float32", None,
+     False),
+    (True, (2, 64, 2, 512), (2, 64, 4, 512), 64, "float32", "float32", None,
+     False),
+    (True, _CELL_Q, _CELL_V, 64, "float16", "float16", None, False),
+    (True, _CELL_Q, _CELL_V, 64, "float32", "bfloat16", _pp2_mesh, False),
+], ids=["the_cell_on_a_tpu", "one_head_any_length", "backend_off",
+        "chunk_16", "d_k_16", "d_v_8", "heads_no_multiple",
+        "tiles_past_vmem", "float16", "pp_mesh"])
+def test_delta_rule_kernel_is_chosen_from_shapes(on, q, v, chunk, dtype, mm,
+                                                 mesh, taken, monkeypatch):
+    from paddle_tpu.ops.pallas import gated_delta_rule as gdr
+    monkeypatch.setattr(pallas, "_enabled", lambda: on)
+    assert gdr.is_supported(q, v, chunk, [jnp.dtype(dtype)] * 5,
+                            jnp.dtype(mm), mesh and mesh(), 16) is taken
+
+
+@pytest.mark.parametrize("on,dk,chunk,mesh,kernel", [
+    (True, 128, 64, None, True), (False, 128, 64, None, False),
+    (True, 16, 64, None, False), (True, 128, 16, None, False),
+    (True, 128, 64, _pp2_mesh, False),
+], ids=["kernel", "backend_off", "d_k_16", "chunk_16", "pp_mesh"])
+def test_delta_rule_dispatch_moves_its_counter(on, dk, chunk, mesh, kernel,
+                                               monkeypatch):
+    """``chunk_gated_delta_rule`` asks ``is_supported`` and nothing else;
+    whichever it takes, the result is the rule's and one of the two
+    counters moves."""
+    from paddle_tpu.nn.functional import linear_attention as la
+    from paddle_tpu.ops.pallas import gated_delta_rule as gdr
+    monkeypatch.setattr(pallas, "_enabled", lambda: on)
+    if mesh is not None:
+        held = mesh()
+        monkeypatch.setattr(la, "current_mesh", lambda: held)
+    calls = []
+    real = gdr.gdn_chunk_rule_fwd
+    monkeypatch.setattr(gdr, "gdn_chunk_rule_fwd", lambda *a, **kw: (
+        calls.append(a[0].shape), real(*a, **kw))[1])
+    rng = np.random.RandomState(2)
+    q, k = (rng.randn(1, 64, 1, dk).astype(np.float32) for _ in range(2))
+    v = rng.randn(1, 64, 2, 128).astype(np.float32)
+    g = -rng.uniform(0.01, 1.0, (1, 64, 2)).astype(np.float32)
+    beta = rng.uniform(0, 1, (1, 64, 2)).astype(np.float32)
+    arrays = (q, k, v, g, beta)
+    before = _rule_traces()
+    out = F.chunk_gated_delta_rule(*(paddle.to_tensor(a) for a in arrays),
+                                   chunk_size=chunk)
+    moved = tuple(b - a for a, b in zip(before, _rule_traces()))
+    assert moved == ((1, 0) if kernel else (0, 1))
+    assert bool(calls) is kernel
+    want = la._chunk_rule(*map(jnp.asarray, arrays), chunk=chunk,
+                          mm=jnp.float32)
+    np.testing.assert_allclose(np.asarray(out._data), want, atol=2e-6)
 
 
 # -------------------------------------------- the backward by block count

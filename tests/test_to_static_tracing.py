@@ -454,6 +454,8 @@ def test_record_event_begin_end_without_a_trace():
 
 # ------------------------------------------------------- the kernels' names
 _SITES = pallas_call_sites()
+# where the file is named after the rule and the wrapper after its form
+_WRAPPER = {"gated_delta_rule.py": "gdn_chunk_rule"}
 
 
 @pytest.mark.parametrize(
@@ -464,10 +466,10 @@ def test_every_pallas_call_is_named(site):
         f"{fname}:{line}: pallas_call without a name= string; the device "
         "trace would name the kernel after its enclosing Python function")
     # "<public wrapper>_<role>": the wrappers live in the file of their name
-    assert name.startswith(fname[:-3]), (fname, name)
+    assert name.startswith(_WRAPPER.get(fname, fname[:-3])), (fname, name)
 
 
 def test_pallas_call_names_are_unique():
     names = [name for _, _, name in _SITES]
-    assert len(names) == 17
+    assert len(names) == 18
     assert len(set(names)) == len(names), sorted(names)
