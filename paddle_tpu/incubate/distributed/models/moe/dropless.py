@@ -64,6 +64,19 @@ _LOW_BITS = 30
 _layers: list = []
 
 
+def add_counts(old, inc):
+    """``old`` [2, n] int32 (low words, high words) plus ``inc`` [n] int32:
+    counts kept on the device as state of a step, past 2**31."""
+    low = old[0] + inc
+    return jnp.stack([low & ((1 << _LOW_BITS) - 1),
+                      old[1] + (low >> _LOW_BITS)])
+
+
+def read_counts(raw):
+    """The Python ints of a fetched [2, n] array of ``add_counts``."""
+    return [int(lo) + (int(hi) << _LOW_BITS) for lo, hi in zip(raw[0], raw[1])]
+
+
 def _swiglu(x, w_gate_up, w_down, dot):
     """``(SiLU(x W_g) * (x W_u)) W_d`` with ``[W_g | W_u]`` fused, through
     ``dot`` (a plain or a grouped product that accumulates in float32)."""
@@ -148,10 +161,7 @@ class DroplessMoELayer(Layer):
                 group.astype(jnp.int32), counts.astype(jnp.int32))
 
     def _count(self, counts):
-        old = self.counts._data
-        low = old[0] + counts
-        self.counts._data = jnp.stack(
-            [low & ((1 << _LOW_BITS) - 1), old[1] + (low >> _LOW_BITS)])
+        self.counts._data = add_counts(self.counts._data, counts)
 
     def forward(self, x):
         b, s, d = x.shape
@@ -220,7 +230,7 @@ def routing_stats():
     out = {name: 0 for name in names}
     out["layers"] = []
     for layer, c in zip(layers, raw):
-        vals = [int(lo) + (int(hi) << _LOW_BITS) for lo, hi in zip(c[0], c[1])]
+        vals = read_counts(c)
         rec = dict(zip(names, vals))
         rec["experts_held"] = list(layer.experts_held)
         rec["rows_per_expert"] = vals[_PER_EXPERT:]

@@ -203,10 +203,12 @@ class Qwen3NextGatedDeltaNet(Layer):
         return self.out_proj(y)
 
 
-def _rope(x, theta, rotary_dim):
+def _rope(x, theta, rotary_dim, pos=None):
     """Rotate-half rotary embedding on the first ``rotary_dim`` of the head
-    dimension of ``x`` [B, S, H, D], angles in float32."""
-    pos = jnp.arange(x.shape[1], dtype=_F32)
+    dimension of ``x`` [B, S, H, D], angles in float32; at the positions
+    ``pos`` [S] (default: 0 .. S - 1)."""
+    pos = (jnp.arange(x.shape[1], dtype=_F32) if pos is None
+           else pos.astype(_F32))
     inv = theta ** (-jnp.arange(0, rotary_dim, 2, dtype=_F32) / rotary_dim)
     ang = pos[:, None] * inv[None, :]
     cos = jnp.concatenate([jnp.cos(ang)] * 2, -1)[None, :, None, :]

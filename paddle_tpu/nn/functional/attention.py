@@ -13,16 +13,20 @@ import jax
 import jax.numpy as jnp
 
 from ...core.rng import next_key
+from ...inference.telemetry import runtime_counter
 from ...tensor.tensor import Tensor, apply_op
 
 __all__ = ["scaled_dot_product_attention", "flash_attention",
            "flash_attn_unpadded", "sdp_kernel"]
 
 
-def _sdpa_ref(q, k, v, mask, dropout_p, causal, scale, dropout_key=None):
+def _sdpa_ref(q, k, v, mask, dropout_p, causal, scale, dropout_key=None,
+              structured_mask=None):
     """Composite attention: [B,S,H,D] layout; fp32 softmax for stability.
     Attention dropout (reference: dropout on the softmax probs, upscaled)
-    is applied when dropout_p > 0 and a key is supplied."""
+    is applied when dropout_p > 0 and a key is supplied. A structured mask
+    (``scaled_dot_product_attention``) becomes the dense boolean mask of
+    the rule the flash kernels apply."""
     qt = jnp.swapaxes(q, 1, 2)  # [B,H,S,D]
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)
@@ -36,6 +40,9 @@ def _sdpa_ref(q, k, v, mask, dropout_p, causal, scale, dropout_key=None):
         ql, kl = logits.shape[-2], logits.shape[-1]
         cm = jnp.tril(jnp.ones((ql, kl), bool), k=kl - ql)
         logits = jnp.where(cm, logits, -jnp.inf)
+    if structured_mask is not None:
+        from ...ops.pallas.flash_attention import dense_mask
+        logits = jnp.where(dense_mask(structured_mask), logits, -jnp.inf)
     if mask is not None:
         if mask.dtype == jnp.bool_:
             logits = jnp.where(mask, logits, -jnp.inf)
@@ -89,14 +96,25 @@ def _per_shard(q_shape, k_shape):
 
 def scaled_dot_product_attention(query, key, value, attn_mask=None,
                                  dropout_p=0.0, is_causal=False,
-                                 training=True, name=None):
+                                 training=True, name=None,
+                                 structured_mask=None):
+    """``structured_mask`` names a rule instead of an array
+    (``ops.pallas.flash_attention.block_diffusion_mask(L, b)``): the flash
+    kernels evaluate it on row and column ids and skip the tiles it
+    empties; the composite builds the dense mask from the same rule.
+    ``paddle_flash_mask_kernel_traces_total`` or
+    ``paddle_flash_mask_composite_traces_total`` counts each trace."""
     mask_arr = attn_mask._data if isinstance(attn_mask, Tensor) else attn_mask
     drop_p = float(dropout_p) if training else 0.0
+    if structured_mask is not None and (is_causal or mask_arr is not None):
+        raise ValueError("scaled_dot_product_attention: structured_mask "
+                         "comes alone, without is_causal or attn_mask")
 
     # the one question: does the flash kernel take these shapes, this
-    # dtype, this mask, under this mesh? Dropout does not enter: the kernel
-    # draws its mask in-kernel (no O(S^2) mask in HBM), so with dropout it
-    # is taken exactly when it would be without. No blanket except: an
+    # dtype, this mask, under this mesh? Dropout enters only under a
+    # structured mask (refused there): elsewhere the kernel draws its mask
+    # in-kernel (no O(S^2) mask in HBM), so with dropout it is taken
+    # exactly when it would be without. No blanket except: an
     # import or gate error must surface, not silently downgrade every
     # attention call to the O(S^2) composite.
     from ...ops import pallas
@@ -105,8 +123,13 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
     wrap = None
     if mask_arr is None and pallas._enabled() \
             and q_shape[2] % k_shape[2] == 0 \
-            and fa.is_supported(q_shape, query.dtype):
+            and fa.is_supported(q_shape, query.dtype, structured_mask,
+                                k_shape, drop_p):
         wrap = _per_shard(q_shape, k_shape)
+    if structured_mask is not None:
+        runtime_counter("paddle_flash_mask_kernel_traces_total"
+                        if wrap is not None else
+                        "paddle_flash_mask_composite_traces_total", 1)
     if wrap is not None:
         seed = jnp.zeros((), jnp.int32)
         if drop_p > 0.0:
@@ -116,7 +139,8 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
 
         def kern(q, k, v, s):
             return fa.flash_attention(q, k, v, causal=is_causal,
-                                      dropout_p=drop_p, dropout_seed=s)
+                                      dropout_p=drop_p, dropout_seed=s,
+                                      mask=structured_mask)
 
         def f(q, k, v):
             return wrap(kern)(q, k, v, seed)
@@ -126,7 +150,8 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
 
     def f(q, k, v, *m):
         return _sdpa_ref(q, k, v, m[0] if m else None, drop_p, is_causal,
-                         None, dropout_key=key_)
+                         None, dropout_key=key_,
+                         structured_mask=structured_mask)
     if attn_mask is not None:
         return apply_op(f, query, key, value, attn_mask)
     return apply_op(f, query, key, value)

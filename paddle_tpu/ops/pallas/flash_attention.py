@@ -19,6 +19,15 @@ fp32 accumulate + softmax), seq
 lengths not divisible by the block size (masked tail blocks).  Backward is
 the standard two-kernel split: dKV (grid over KV blocks, scan Q) and dQ
 (grid over Q blocks, scan KV), with delta = rowsum(dO * O) precomputed.
+
+Masks are STRUCTURED: a rule on an entry's row and column, never an array.
+Inside this file ``mask`` is ``None``, ``"causal"`` or
+``("block_diffusion", L, b)`` (``block_diffusion_mask``): the attention rule
+of block-diffusion training over ``[noisy ; clean]`` copies of an ``L``-token
+sequence cut into blocks of ``b`` (``_bd_allowed``). Every kernel skips a tile
+that holds no allowed entry, decided from the tile's first and last row and
+column (``_tile_runs``), and applies the rule per entry elsewhere
+(``_tile_mask``).
 """
 from __future__ import annotations
 
@@ -27,23 +36,52 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .. import pallas as _pallas
+from ...inference.telemetry import runtime_counter
 
-__all__ = ["flash_attention", "is_supported"]
+__all__ = ["flash_attention", "is_supported", "block_diffusion_mask",
+           "dense_mask"]
 
 NEG_INF = -1e30  # large-negative instead of -inf: keeps exp()/max() NaN-free
+_NEVER = 1 << 30    # a column code no row code reaches
 
 
-def is_supported(q_shape, dtype) -> bool:
-    """Wrapper-level gate: rank-4 [B,S,H,D], supported dtype, head_dim ≤ 256."""
+def block_diffusion_mask(seq_len, block_length):
+    """The structured mask of block-diffusion training: rows and columns are
+    positions of ``[noisy ; clean]``, two copies of a ``seq_len``-token
+    sequence cut into blocks of ``block_length`` (``beta(j) = (j mod L) //
+    b``; "noisy" is ``j < L``)::
+
+        noisy i -> noisy j : beta(j) == beta(i)
+        noisy i -> clean j : beta(j) <  beta(i)
+        clean i -> clean j : beta(j) <= beta(i)
+        clean i -> noisy j : never
+    """
+    seq_len, block_length = int(seq_len), int(block_length)
+    if seq_len < 1 or block_length < 1:
+        raise ValueError(f"block_diffusion_mask({seq_len}, {block_length})")
+    return ("block_diffusion", seq_len, block_length)
+
+
+def is_supported(q_shape, dtype, mask=None, k_shape=None,
+                 dropout_p=0.0) -> bool:
+    """Wrapper-level gate: rank-4 [B,S,H,D], supported dtype, head_dim ≤ 256;
+    under a structured ``mask`` also self-attention over its ``2 L``
+    positions and no dropout (the composite takes that combination)."""
     if len(q_shape) != 4:
         return False
     d = q_shape[-1]
     if d > 256:
         return False
+    if mask is not None:
+        if mask[0] != "block_diffusion" or dropout_p > 0.0 \
+                or q_shape[1] != 2 * mask[1] \
+                or (k_shape is not None and k_shape[1] != q_shape[1]):
+            return False
     return jnp.dtype(dtype) in (jnp.float32, jnp.bfloat16, jnp.float16)
 
 
@@ -72,6 +110,115 @@ def _block_sizes(sq: int, sk: int, d: int = 64):
 
 
 # ---------------------------------------------------------------------------
+# Structured masks: the rule per entry, and per tile
+# ---------------------------------------------------------------------------
+
+def _block_of(pos, b, xp):
+    """``pos // b`` for positions >= 0 (a shift where ``b`` is a power of
+    two): Python ints, numpy arrays or traced int32 alike."""
+    if b & (b - 1) == 0:
+        return pos >> (b.bit_length() - 1)
+    return jax.lax.div(pos, jnp.int32(b)) if xp is jnp else pos // b
+
+
+def _bd_allowed(rows, cols, seq, b):
+    """The block-diffusion rule on positions ``rows`` [n, 1] and ``cols``
+    [1, m] as two comparisons of integer codes: entry (i, j) is allowed iff
+    ``same_c[j] == same_r[i]`` (both noisy, one block) or ``past_c[j] <
+    past_r[i]`` (a clean column of an earlier block, for a clean row of its
+    own block too). Positions past ``2 seq`` (a padded tail) get codes that
+    allow nothing. Returns [n, m] bool."""
+    def half(pos):
+        noisy = pos < seq
+        return noisy, _block_of(jnp.where(noisy, pos, pos - seq), b, jnp)
+    r_noisy, r_blk = half(rows)
+    c_noisy, c_blk = half(cols)
+    same_r = jnp.where(r_noisy, r_blk, -2)
+    past_r = jnp.where(rows < 2 * seq, jnp.where(r_noisy, r_blk, r_blk + 1), 0)
+    same_c = jnp.where(c_noisy, c_blk, -1)
+    past_c = jnp.where(c_noisy | (cols >= 2 * seq), _NEVER, c_blk)
+    return (same_c == same_r) | (past_c < past_r)
+
+
+def dense_mask(mask):
+    """The structured ``mask`` as a boolean [S, S] array (True: allowed):
+    what the composite applies off the chip, and what the tests hold the
+    kernels to."""
+    _, seq, b = mask
+    pos = jnp.arange(2 * seq, dtype=jnp.int32)
+    return _bd_allowed(pos[:, None], pos[None, :], seq, b)
+
+
+def _tile_runs(mask, q_start, k_start, bq, bk, sq, sk, xp=jnp):
+    """Whether the tile of rows ``q_start .. q_start + bq - 1`` and columns
+    ``k_start .. k_start + bk - 1`` holds an allowed entry: a scalar, from
+    the tile's first and last row and column alone. Traced on the program
+    ids inside a kernel, and on numpy arrays of tile starts for the count
+    of visited tiles."""
+    if mask is None:
+        return True
+    if mask == "causal":
+        # bottom-right alignment (FA2 convention): row i attends key j iff
+        # j <= i + sk - sq; skip blocks strictly above that diagonal
+        return q_start + bq - 1 + (sk - sq) >= k_start
+    _, seq, b = mask
+    r0, r1 = q_start, xp.minimum(q_start + bq, 2 * seq) - 1
+    c0, c1 = k_start, xp.minimum(k_start + bk, 2 * seq) - 1
+
+    def blk(pos):
+        return _block_of(pos, b, xp)
+    # the noisy and the clean part of the rows and of the columns, each a
+    # range of blocks (used only where that part is not empty)
+    r_noisy, r_clean = r0 < seq, r1 >= seq
+    c_noisy, c_clean = c0 < seq, c1 >= seq
+    rn_lo, rn_hi = blk(r0), blk(xp.minimum(r1, seq - 1))
+    rc_hi = blk(r1 - seq)
+    cn_lo, cn_hi = blk(c0), blk(xp.minimum(c1, seq - 1))
+    cc_lo = blk(xp.maximum(c0, seq) - seq)
+    return ((r_noisy & c_noisy & (cn_lo <= rn_hi) & (rn_lo <= cn_hi))
+            | (r_noisy & c_clean & (cc_lo < rn_hi))
+            | (r_clean & c_clean & (cc_lo <= rc_hi)))
+
+
+def _tile_mask(mask, q_start, k_start, bq, bk, sq, sk, rows_too):
+    """The allowed entries of a tile, [bq, bk] bool: inside the sequence
+    (the key-padding tail; with ``rows_too`` the padded query rows as well)
+    and under ``mask``."""
+    single = isinstance(q_start, int)       # the one-tile kernel: starts 0
+    if mask is None or mask == "causal":
+        rows = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+        if not single:
+            rows = q_start + rows
+        cols = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+        if not single:
+            cols = k_start + cols
+        ok = cols < sk
+        if rows_too:
+            ok = ok & (rows < sq)
+        if mask == "causal":
+            ok = ok & (cols <= rows + (sk - sq))
+        return ok
+    _, seq, b = mask
+    rows = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
+    cols = k_start + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+    return _bd_allowed(rows, cols, seq, b)
+
+
+def _count_tiles(mask, planes, bq, bk, sq, sk):
+    """``paddle_flash_tiles_visited_total`` / ``paddle_flash_tiles_total``:
+    the tiles the kernels' grids compute and the tiles they have, counted
+    when the kernels are traced, from the static grid: ``planes`` (batch x
+    heads x kernels) times the tiles of one [sq, sk] plane."""
+    q0 = np.arange(0, sq, bq, dtype=np.int64)[:, None]
+    k0 = np.arange(0, sk, bk, dtype=np.int64)[None, :]
+    runs = np.broadcast_to(_tile_runs(mask, q0, k0, bq, bk, sq, sk, np),
+                           (q0.size, k0.size))
+    runtime_counter("paddle_flash_tiles_visited_total",
+                    planes * int(runs.sum()))
+    runtime_counter("paddle_flash_tiles_total", planes * runs.size)
+
+
+# ---------------------------------------------------------------------------
 # Forward
 # ---------------------------------------------------------------------------
 
@@ -91,7 +238,7 @@ def _drop_tile(seed_ref, bi, hi, qi, ki, bq, bk, dropout_p):
     return jnp.where(bits >= thresh, 1.0 / (1.0 - dropout_p), 0.0)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, sq, sk, bq, bk,
+def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, mask, sq, sk, bq, bk,
                 drop_mode=0, dropout_p=0.0):
     # drop_mode: 0 = no dropout, 1 = mask input (interpret), 2 = in-kernel
     # PRNG (TPU). Mode 1/2 append dmask / SMEM seed to the inputs.
@@ -104,9 +251,6 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, sq, sk, bq, bk,
     else:
         o_ref, lse_ref, acc_sc, m_sc, l_sc = rest
         dmask_ref = seed_ref = None
-    # Causal uses bottom-right alignment (FA2 convention): row i attends
-    # key j iff j <= i + sk - sq.
-    offset = sk - sq
     qi = pl.program_id(2)
     ki = pl.program_id(3)
     nk = pl.num_programs(3)
@@ -120,12 +264,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, sq, sk, bq, bk,
     q_start = qi * bq
     k_start = ki * bk
 
-    # Causal: skip blocks strictly above the (aligned) diagonal entirely.
-    run = True
-    if causal:
-        run = q_start + bq - 1 + offset >= k_start
-
-    @pl.when(run)
+    # skip a tile that holds no allowed entry (causal: strictly above the
+    # aligned diagonal) entirely
+    @pl.when(_tile_runs(mask, q_start, k_start, bq, bk, sq, sk))
     def _():
         # dots run in the input dtype (bf16 MXU full rate) with f32
         # accumulation; only the softmax math is f32
@@ -135,19 +276,15 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, sq, sk, bq, bk,
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale   # [bq, bk] f32
 
-        rows = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-        cols = k_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        mask = cols < sk                      # key-padding tail
-        if causal:
-            mask = mask & (cols <= rows + offset)
-        s = jnp.where(mask, s, NEG_INF)
+        ok = _tile_mask(mask, q_start, k_start, bq, bk, sq, sk, False)
+        s = jnp.where(ok, s, NEG_INF)
 
         m_prev = m_sc[:]                                   # [bq, 1]
         m_cur = jnp.max(s, axis=1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)                             # [bq, bk]
-        p = jnp.where(mask, p, 0.0)
+        p = jnp.where(ok, p, 0.0)
 
         l_sc[:] = l_sc[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
         m_sc[:] = m_new
@@ -172,7 +309,30 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, sq, sk, bq, bk,
         lse_ref[0, 0] = m_sc[:] + jnp.log(l_safe)      # [bq, 1]
 
 
-def _fwd(q, k, v, drop=None, *, causal, scale, bq, bk):
+def _scanned_spec(mask, rows, d, group, bq, bk, own_is_q):
+    """The BlockSpec of an operand whose tiles a kernel scans along its
+    last grid axis (``rows`` each; K and V have a head for every ``group``
+    query heads, the Q side has ``group`` None): tile ``j`` at step ``(b,
+    h, own, j)``. Under a structured mask a step that is skipped holds its
+    own tile's diagonal tile instead (position i always sees position i, so
+    that tile runs; rows and columns are one sequence there, so ``bq ==
+    bk``): consecutive skipped steps then name one tile and their copies
+    are elided, where ``j`` would fetch a tile nobody reads (forward +
+    backward of 16,384 positions: 60.5 ms without this, 50.8 with; chip run
+    of PR 32, PERF.md section 6)."""
+    def head(h_):
+        return h_ if group is None else h_ // group
+
+    def scanned(own, j):
+        if mask is None or mask == "causal":
+            return j
+        q0, k0 = (own * bq, j * bk) if own_is_q else (j * bq, own * bk)
+        return jnp.where(_tile_runs(mask, q0, k0, bq, bk, None, None), j, own)
+    return pl.BlockSpec((1, 1, rows, d), lambda b_, h_, i, j: (
+        b_, head(h_), scanned(i, j), 0))
+
+
+def _fwd(q, k, v, drop=None, *, mask, scale, bq, bk):
     """q,k,v: [B,H,S,D] (kv may have fewer heads for GQA). Returns (o, lse).
     drop: None, ('mask', dmask [B,H,Sq_p,Sk_p] f32) or ('prng', seed, p)."""
     b, h, sq, d = q.shape
@@ -190,15 +350,14 @@ def _fwd(q, k, v, drop=None, *, causal, scale, bq, bk):
     grid = (b, h, sq_p // bq, sk_p // bk)
     drop_mode = 0 if drop is None else (1 if drop[0] == "mask" else 2)
     kernel = functools.partial(
-        _fwd_kernel, scale=scale, causal=causal, sq=sq, sk=sk, bq=bq, bk=bk,
+        _fwd_kernel, scale=scale, mask=mask, sq=sq, sk=sk, bq=bq, bk=bk,
         drop_mode=drop_mode,
         dropout_p=drop[2] if drop_mode == 2 else 0.0)
+    _count_tiles(mask, b * h, bq, bk, sq, sk)
+    kvspec = _scanned_spec(mask, bk, d, group, bq, bk, True)
     in_specs = [
         pl.BlockSpec((1, 1, bq, d), lambda b_, h_, i, j: (b_, h_, i, 0)),
-        pl.BlockSpec((1, 1, bk, d),
-                     lambda b_, h_, i, j, g=group: (b_, h_ // g, j, 0)),
-        pl.BlockSpec((1, 1, bk, d),
-                     lambda b_, h_, i, j, g=group: (b_, h_ // g, j, 0)),
+        kvspec, kvspec,
     ]
     args = [q, k, v]
     if drop_mode == 1:
@@ -236,7 +395,7 @@ def _fwd(q, k, v, drop=None, *, causal, scale, bq, bk):
 # ---------------------------------------------------------------------------
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    *rest, scale, causal, sq, sk, bq, bk, drop_mode=0,
+                    *rest, scale, mask, sq, sk, bq, bk, drop_mode=0,
                     dropout_p=0.0):
     if drop_mode == 1:
         dmask_ref, dk_ref, dv_ref, dk_sc, dv_sc = rest
@@ -247,7 +406,6 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     else:
         dk_ref, dv_ref, dk_sc, dv_sc = rest
         dmask_ref = seed_ref = None
-    offset = sk - sq
     ki = pl.program_id(2)
     qi = pl.program_id(3)
     nq = pl.num_programs(3)
@@ -259,11 +417,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     q_start = qi * bq
     k_start = ki * bk
-    run = True
-    if causal:
-        run = q_start + bq - 1 + offset >= k_start
 
-    @pl.when(run)
+    @pl.when(_tile_runs(mask, q_start, k_start, bq, bk, sq, sk))
     def _():
         q = q_ref[0, 0]                                   # [bq, d]
         k = k_ref[0, 0]                                   # [bk, d]
@@ -274,12 +429,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        rows = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-        cols = k_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        mask = (cols < sk) & (rows < sq)
-        if causal:
-            mask = mask & (cols <= rows + offset)
-        p = jnp.where(mask, jnp.exp(s - lse), 0.0)        # [bq, bk] f32
+        ok = _tile_mask(mask, q_start, k_start, bq, bk, sq, sk, True)
+        p = jnp.where(ok, jnp.exp(s - lse), 0.0)          # [bq, bk] f32
 
         if dmask_ref is not None:
             dm = dmask_ref[0, 0]
@@ -313,7 +464,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   *rest, scale, causal, sq, sk, bq, bk, drop_mode=0,
+                   *rest, scale, mask, sq, sk, bq, bk, drop_mode=0,
                    dropout_p=0.0):
     if drop_mode == 1:
         dmask_ref, dq_ref, dq_sc = rest
@@ -324,7 +475,6 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     else:
         dq_ref, dq_sc = rest
         dmask_ref = seed_ref = None
-    offset = sk - sq
     qi = pl.program_id(2)
     ki = pl.program_id(3)
     nk = pl.num_programs(3)
@@ -335,11 +485,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     q_start = qi * bq
     k_start = ki * bk
-    run = True
-    if causal:
-        run = q_start + bq - 1 + offset >= k_start
 
-    @pl.when(run)
+    @pl.when(_tile_runs(mask, q_start, k_start, bq, bk, sq, sk))
     def _():
         q = q_ref[0, 0]
         k = k_ref[0, 0]
@@ -350,12 +497,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        rows = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-        cols = k_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        mask = (cols < sk) & (rows < sq)
-        if causal:
-            mask = mask & (cols <= rows + offset)
-        p = jnp.where(mask, jnp.exp(s - lse), 0.0)
+        ok = _tile_mask(mask, q_start, k_start, bq, bk, sq, sk, True)
+        p = jnp.where(ok, jnp.exp(s - lse), 0.0)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         if dmask_ref is not None:
@@ -374,7 +517,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                      *rest, scale, causal, sq, sk, drop_mode=0,
+                      *rest, scale, mask, sq, sk, drop_mode=0,
                       dropout_p=0.0):
     """Single-block backward: when the whole (b, h) slice fits one
     (bq, bk) tile (the common S <= 1024 training shape), dq, dk and dv
@@ -391,7 +534,6 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     else:
         dq_ref, dk_ref, dv_ref = rest
         dmask_ref = seed_ref = None
-    offset = sk - sq
     bq = q_ref.shape[2]
     bk = k_ref.shape[2]
 
@@ -404,12 +546,8 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
-    rows = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    mask = (cols < sk) & (rows < sq)
-    if causal:
-        mask = mask & (cols <= rows + offset)
-    p = jnp.where(mask, jnp.exp(s - lse), 0.0)        # [bq, bk] f32
+    ok = _tile_mask(mask, 0, 0, bq, bk, sq, sk, True)
+    p = jnp.where(ok, jnp.exp(s - lse), 0.0)          # [bq, bk] f32
 
     if dmask_ref is not None:
         dm = dmask_ref[0, 0]
@@ -437,7 +575,7 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _bwd_fused(q_, k_, v_, do_, lse_, delta_, drop, drop_arg, *,
-               causal, scale, sq, sk, group):
+               mask, scale, sq, sk, group):
     """Single-block fused backward dispatch; inputs are pre-padded to one
     (bq, bk) = (sq_p, sk_p) block. Returns (dq, dk_perq, dv_perq) with dk/dv
     still per-q-head (GQA segment-sum happens in the caller)."""
@@ -458,7 +596,7 @@ def _bwd_fused(q_, k_, v_, do_, lse_, delta_, drop, drop_arg, *,
         in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
         args.append(drop_arg())
     dq, dk, dv = pl.pallas_call(
-        functools.partial(_bwd_fused_kernel, scale=scale, causal=causal,
+        functools.partial(_bwd_fused_kernel, scale=scale, mask=mask,
                           sq=sq, sk=sk, drop_mode=drop_mode,
                           dropout_p=drop[2] if drop_mode == 2 else 0.0),
         grid=(b, h),
@@ -479,7 +617,7 @@ def _bwd_fused(q_, k_, v_, do_, lse_, delta_, drop, drop_arg, *,
     return dq, dk, dv
 
 
-def _bwd(q, k, v, o, lse, do, drop=None, *, causal, scale, bq, bk):
+def _bwd(q, k, v, o, lse, do, drop=None, *, mask, scale, bq, bk):
     b, h, sq, d = q.shape
     hk = k.shape[1]
     group = h // hk
@@ -515,7 +653,7 @@ def _bwd(q, k, v, o, lse, do, drop=None, *, causal, scale, bq, bk):
         # split dKV + dQ pair below
         dq, dk, dv = _bwd_fused(
             q_, k_, v_, do_, lse_, delta_, drop, drop_arg,
-            causal=causal, scale=scale, sq=sq, sk=sk, group=group)
+            mask=mask, scale=scale, sq=sq, sk=sk, group=group)
         dq = dq[:, :, :sq]
         dk = dk[:, :, :sk]
         dv = dv[:, :, :sk]
@@ -524,10 +662,12 @@ def _bwd(q, k, v, o, lse, do, drop=None, *, causal, scale, bq, bk):
             dv = dv.reshape(b, hk, group, sk, d).sum(axis=2)
         return dq, dk.astype(k.dtype), dv.astype(v.dtype)
 
-    qspec = pl.BlockSpec((1, 1, bq, d), lambda b_, h_, j, i: (b_, h_, i, 0))
+    _count_tiles(mask, 2 * b * h, bq, bk, sq, sk)    # dK/dV and dQ
+    # grid (b, h, K tile j, Q tile i): the Q side is scanned
+    qspec = _scanned_spec(mask, bq, d, None, bq, bk, False)
     kspec = pl.BlockSpec((1, 1, bk, d),
                          lambda b_, h_, j, i, g=group: (b_, h_ // g, j, 0))
-    rowspec = pl.BlockSpec((1, 1, bq, 1), lambda b_, h_, j, i: (b_, h_, i, 0))
+    rowspec = _scanned_spec(mask, bq, 1, None, bq, bk, False)
 
     # dK/dV: one [bk,d] accumulator pair per KV block; Q scanned innermost.
     # GQA: compute per-Q-head dk/dv (shape [B,H,...]) and segment-sum to
@@ -542,7 +682,7 @@ def _bwd(q, k, v, o, lse, do, drop=None, *, causal, scale, bq, bk):
         dkv_in.append(pl.BlockSpec(memory_space=pltpu.SMEM))
         dkv_args.append(drop_arg())
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
+        functools.partial(_bwd_dkv_kernel, scale=scale, mask=mask,
                           sq=sq, sk=sk, bq=bq, bk=bk, drop_mode=drop_mode,
                           dropout_p=drop_p),
         grid=(b, h, sk_p // bk, sq_p // bq),
@@ -564,8 +704,7 @@ def _bwd(q, k, v, o, lse, do, drop=None, *, causal, scale, bq, bk):
     )(*dkv_args)
 
     qspec2 = pl.BlockSpec((1, 1, bq, d), lambda b_, h_, i, j: (b_, h_, i, 0))
-    kspec2 = pl.BlockSpec((1, 1, bk, d),
-                          lambda b_, h_, i, j, g=group: (b_, h_ // g, j, 0))
+    kspec2 = _scanned_spec(mask, bk, d, group, bq, bk, True)
     rowspec2 = pl.BlockSpec((1, 1, bq, 1), lambda b_, h_, i, j: (b_, h_, i, 0))
     dq_in = [qspec2, kspec2, kspec2, qspec2, rowspec2, rowspec2]
     dq_args = [q_, k_, v_, do_, lse_, delta_]
@@ -577,7 +716,7 @@ def _bwd(q, k, v, o, lse, do, drop=None, *, causal, scale, bq, bk):
         dq_in.append(pl.BlockSpec(memory_space=pltpu.SMEM))
         dq_args.append(drop_arg())
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
+        functools.partial(_bwd_dq_kernel, scale=scale, mask=mask,
                           sq=sq, sk=sk, bq=bq, bk=bk, drop_mode=drop_mode,
                           dropout_p=drop_p),
         grid=(b, h, sq_p // bq, sk_p // bk),
@@ -619,8 +758,8 @@ def _padded_sizes(sq, sk, d=64):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _flash(q, k, v, seed, causal, scale, dropout_p):
-    o, _ = _core_fwd(q, k, v, seed, causal, scale, dropout_p)
+def _flash(q, k, v, seed, mask, scale, dropout_p):
+    o, _ = _core_fwd(q, k, v, seed, mask, scale, dropout_p)
     return o
 
 
@@ -637,22 +776,22 @@ def _make_drop(q, k, seed, dropout_p):
                           dropout_p))
 
 
-def _core_fwd(q, k, v, seed, causal, scale, dropout_p):
+def _core_fwd(q, k, v, seed, mask, scale, dropout_p):
     bq, bk, _, _ = _padded_sizes(q.shape[2], k.shape[2], q.shape[3])
     drop = _make_drop(q, k, seed, dropout_p)
-    return _fwd(q, k, v, drop, causal=causal, scale=scale, bq=bq, bk=bk)
+    return _fwd(q, k, v, drop, mask=mask, scale=scale, bq=bq, bk=bk)
 
 
-def _flash_fwd(q, k, v, seed, causal, scale, dropout_p):
-    o, lse = _core_fwd(q, k, v, seed, causal, scale, dropout_p)
+def _flash_fwd(q, k, v, seed, mask, scale, dropout_p):
+    o, lse = _core_fwd(q, k, v, seed, mask, scale, dropout_p)
     return o, (q, k, v, o, lse, seed)
 
 
-def _flash_bwd(causal, scale, dropout_p, res, g):
+def _flash_bwd(mask, scale, dropout_p, res, g):
     q, k, v, o, lse, seed = res
     bq, bk, _, _ = _padded_sizes(q.shape[2], k.shape[2], q.shape[3])
     drop = _make_drop(q, k, seed, dropout_p)
-    dq, dk, dv = _bwd(q, k, v, o, lse, g, drop, causal=causal, scale=scale,
+    dq, dk, dv = _bwd(q, k, v, o, lse, g, drop, mask=mask, scale=scale,
                       bq=bq, bk=bk)
     return dq, dk, dv, None
 
@@ -661,14 +800,22 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 def flash_attention(q, k, v, causal=False, scale=None, dropout_p=0.0,
-                    dropout_seed=None):
+                    dropout_seed=None, mask=None):
     """q,k,v: [batch, seq, heads, head_dim] (kv heads may divide q heads).
 
     Returns [batch, seq, heads, head_dim]; differentiable (custom VJP with
     flash backward kernels). dropout_p > 0 applies attention-prob dropout
     (upscaled) with a seed-regenerated mask — pass dropout_seed (int32
-    scalar, traced ok) for reproducibility.
+    scalar, traced ok) for reproducibility. ``mask`` is a structured mask
+    (``block_diffusion_mask(L, b)``, over ``2 L`` positions of q and k
+    alike; not with ``causal``, not with dropout), never an array.
     """
+    if mask is not None and (causal or not is_supported(
+            q.shape, q.dtype, mask, k.shape, dropout_p)):
+        raise ValueError(
+            f"flash_attention: mask {mask!r} with q {q.shape}, k {k.shape}, "
+            f"causal={causal}, dropout_p={dropout_p}: a structured mask "
+            "covers its own 2 L positions, alone")
     if scale is None:
         scale = q.shape[-1] ** -0.5
     if q.shape[2] % k.shape[2] != 0:
@@ -680,6 +827,6 @@ def flash_attention(q, k, v, causal=False, scale=None, dropout_p=0.0,
     vt = jnp.swapaxes(v, 1, 2)
     if dropout_seed is None:
         dropout_seed = jnp.zeros((), jnp.int32)
-    o = _flash(qt, kt, vt, dropout_seed, bool(causal), float(scale),
-               float(dropout_p))
+    o = _flash(qt, kt, vt, dropout_seed, "causal" if causal else mask,
+               float(scale), float(dropout_p))
     return jnp.swapaxes(o, 1, 2)

@@ -203,14 +203,24 @@ def _dense_decode():
 _QKV = (B, 1024, H, D)     # the gpt2_124m train step: 8 x 1024 x 12 x 64
 
 
-def _flash(grad, dropout_p=0.0, qkv=_QKV, kv_heads=None):
-    from paddle_tpu.ops.pallas.flash_attention import flash_attention
-    fn = functools.partial(flash_attention, causal=True,
-                           dropout_p=dropout_p)
+def _flash(grad, dropout_p=0.0, qkv=_QKV, kv_heads=None, block=None):
+    """``block``: under the block-diffusion mask over ``qkv``'s positions,
+    in blocks of that many tokens, instead of the causal one. The kernels
+    keep their names: which mask an event ran under is the program's to
+    know (``paddle_flash_mask_kernel_traces_total``), not the trace's."""
+    from paddle_tpu.ops.pallas.flash_attention import (block_diffusion_mask,
+                                                       flash_attention)
+    fn = functools.partial(flash_attention, causal=block is None,
+                           dropout_p=dropout_p,
+                           mask=block and block_diffusion_mask(
+                               qkv[1] // 2, block))
     if grad:
         fn = _sum_grad(fn, (0, 1, 2))
     kv = qkv if kv_heads is None else qkv[:2] + (kv_heads,) + qkv[3:]
-    return fn, (_one(qkv, BF), _one(kv, BF), _one(kv, BF))
+    names = ("flash_attention_fwd",) + (
+        ("flash_attention_bwd_dkv", "flash_attention_bwd_dq")
+        if grad and block else ())
+    return (fn, (_one(qkv, BF), _one(kv, BF), _one(kv, BF))) + names
 
 
 def _ring_chunk(grad):
@@ -370,6 +380,16 @@ _CASES = {
         False, qkv=(2, 8192, 16, 256), kv_heads=2),
     "flash_fwd_bwd_gqa_d256_seq8192": lambda mp: _flash(
         True, qkv=(2, 8192, 16, 256), kv_heads=2),
+    # SDAR's attention at the benchmark's 1 x 8192 data tokens: 16,384
+    # positions of [noisy ; clean] under the block-diffusion mask, blocks
+    # of 4, 32 query heads of 128 on 4 KV heads; and blocks that are no
+    # power of two (a division where the others shift) on a ragged length
+    "flash_fwd_blockdiff_d128_seq16384": lambda mp: _flash(
+        False, qkv=(1, 16384, 32, 128), kv_heads=4, block=4),
+    "flash_fwd_bwd_blockdiff_d128_seq16384": lambda mp: _flash(
+        True, qkv=(1, 16384, 32, 128), kv_heads=4, block=4),
+    "flash_fwd_bwd_blockdiff_block12_seq3000": lambda mp: _flash(
+        True, qkv=(2, 3000, 8, 64), kv_heads=1, block=12),
     # Qwen3-Next's gated delta rule at the benchmark's 2 x 8192
     "gdn_rule_fwd_seq8192": lambda mp: _gdn_rule(False),
     "gdn_rule_fwd_bwd_seq8192": lambda mp: _gdn_rule(True),
@@ -478,6 +498,57 @@ def test_gated_deltanet_mixer_keeps_one_layout(topo, monkeypatch):
     assert "gdn_chunk_rule_fwd" in text
     assert moved / gib <= 1.6, moved / gib
     assert compiled.memory_analysis().temp_size_in_bytes / gib <= 3.2
+
+
+def test_sdar_layer_compiles_with_the_masked_kernels(topo, monkeypatch):
+    """One ``SDARDecoderLayer`` at the published widths with 16 of 128
+    experts held, on the benchmark's 1 x 8192 data tokens (16,384 positions),
+    bf16, in train mode: forward, the replay under ``recompute`` and the
+    backward pass in one program, as the cell's step holds six of. The
+    three flash kernels are in it under the structured mask (no dense
+    [16384, 16384] mask is: the composite's scores alone would be 34 GB),
+    and its temporaries (1.90 GiB) leave the step room beside its state. BYTES
+    of a compile, not times."""
+    import paddle_tpu as paddle
+    import paddle_tpu.ops.pallas as pallas
+    from paddle_tpu.inference import telemetry
+    from paddle_tpu.models.sdar import SDARConfig, SDARDecoderLayer
+    from paddle_tpu.tensor.tensor import Tensor
+    monkeypatch.setattr(pallas, "_enabled", lambda: True)   # as on the chip
+    paddle.seed(0)
+    layer = SDARDecoderLayer(SDARConfig(experts_held=list(range(16)),
+                                        recompute=True))
+    layer.bfloat16()
+    layer.train()
+    params = list(layer.parameters())
+    held = [p._data for p in params]
+    counts = layer.mlp.counts._data
+    kernel = telemetry.runtime_counter("paddle_flash_mask_kernel_traces_total")
+
+    def step(arrays, x, pos):
+        for p, a in zip(params, arrays):
+            p._data, p.grad = a, None
+        x = Tensor(x, stop_gradient=False)
+        y = layer(x, Tensor(pos))
+        (y.astype("float32") ** 2).sum().backward()
+        return [p.grad._data for p in params], x.grad._data
+
+    try:
+        compiled = jax.jit(step).lower(
+            [_one(a.shape, a.dtype) for a in held],
+            _one((1, 16384, 2048), BF), _one((16384,), I32)).compile()
+    finally:
+        for p, a in zip(params, held):
+            p._data, p.grad = a, None
+        layer.mlp.counts._data = counts
+    kernels = _KERNEL_INSTR.findall(compiled.as_text())
+    for name in ("flash_attention_fwd", "flash_attention_bwd_dkv",
+                 "flash_attention_bwd_dq"):
+        assert any(name in k for k in kernels), (name, kernels)
+    # the first forward and its replay each traced the dispatch once
+    assert telemetry.runtime_counter(
+        "paddle_flash_mask_kernel_traces_total") == kernel + 2
+    assert compiled.memory_analysis().temp_size_in_bytes / (1 << 30) <= 2.4
 
 
 @pytest.mark.parametrize("m,k,o,says", [
