@@ -10,6 +10,29 @@ replay is exact because the global PRNG key is snapshotted and restored
 (explicit keys — stronger than the reference's CUDA RNG state juggling).
 Under paddle.jit.to_static the replay sits behind an optimization barrier,
 so that XLA recomputes and does not merge it with the first forward.
+
+What is kept and what is replayed. Each call of ``recompute`` is a region
+with a store of its own (``tensor.KeptRegion``). An operation whose forward
+is a Pallas kernel keeps the kernel's outputs there in the first forward
+and gets them back in the replay (``tensor.kept_over_replay``): flash
+attention its ``o`` and ``lse`` (``ops/pallas/flash_attention.py``), the
+gated delta rule its ``o`` and block-start ``states``
+(``nn/functional/linear_attention.py``). Their backward kernels read ``q``,
+``k``, ``v``, which are cheap to replay, and these outputs, so the replay
+runs everything in ``function`` BUT those kernels, each of which runs once
+a step. The kept arrays stay alive from the forward to the backward pass
+and do not pass the barrier; the replay's inputs do. An inner region whose
+first forward runs in an outer region's replay takes from the outer store.
+A replay that meets other kernel calls than the first forward kept
+(``function`` branched) raises ``tensor.RecomputeKeepError``, which names
+the region and the entry. The store does not engage, and the replay
+computes as before, where nothing in the region is such a kernel (the
+composites ``_sdpa_ref`` and ``_chunk_rule`` keep nothing) and on a mesh of
+several devices, where the flash kernel runs inside ``shard_map`` and a
+value made in one such body cannot be handed to another: the code sees both
+for itself, and nothing switches it. ``paddle_recompute_kept_total`` counts
+the kernel forwards a traced replay took from its store,
+``paddle_recompute_replayed_total`` those it computed again.
 """
 from __future__ import annotations
 
@@ -18,8 +41,8 @@ from typing import Callable
 import jax
 
 from ....core.rng import get_rng_state, get_rng_tensor, set_rng_state
-from ....tensor.tensor import (Parameter, Tensor, _TapeNode, _tape,
-                               enable_grad, is_grad_enabled, no_grad,
+from ....tensor.tensor import (KeptRegion, Parameter, Tensor, _TapeNode,
+                               _tape, enable_grad, is_grad_enabled, no_grad,
                                persistent_tensors)
 from ....autograd.backward_engine import run_backward
 
@@ -36,8 +59,10 @@ def recompute(function: Callable, *args, **kwargs):
     tensor_positions = [i for i, a in enumerate(args) if isinstance(a, Tensor)]
     tensor_inputs = [args[i] for i in tensor_positions]
     rng_snapshot = get_rng_state() if preserve_rng_state else None
+    kept = KeptRegion(getattr(function, "__qualname__",
+                              type(function).__name__))
 
-    with no_grad():
+    with no_grad(), kept.forward():
         out = function(*args, **kwargs)
     multi = isinstance(out, (tuple, list))
     outs_raw = tuple(out) if multi else (out,)
@@ -55,7 +80,11 @@ def recompute(function: Callable, *args, **kwargs):
         # again, and common-subexpression elimination would merge the two
         # and keep every activation alive after all. Behind the barrier the
         # replay's inputs are new values that exist only once the cotangents
-        # do (jax.checkpoint's own device).
+        # do (jax.checkpoint's own device). What the region kept does not
+        # pass it: XLA may then also merge what the replay computes from a
+        # kept array and parameters alone (the projection after attention)
+        # with the first forward's, and keep that too (PERF.md section 6,
+        # PR 33).
         held, cots = jax.lax.optimization_barrier(
             ([t._data for t in tensor_inputs], list(cots)))
         for i, t, arr in zip(tensor_positions, tensor_inputs, held):
@@ -70,7 +99,7 @@ def recompute(function: Callable, *args, **kwargs):
         key = get_rng_tensor()
         moved = [(t, t._data) for t in persistent_tensors()
                  if t is not key and not isinstance(t, Parameter)]
-        with enable_grad():
+        with enable_grad(), kept.replay():
             out2 = function(*rebuilt, **kwargs)
         for t, data in moved:
             t._data = data

@@ -67,7 +67,7 @@ import jax.numpy as jnp
 from ...inference.telemetry import runtime_counter
 from ...ops.pallas import gated_delta_rule
 from ...parallel import current_mesh
-from ...tensor.tensor import apply_op
+from ...tensor.tensor import apply_op, kept_over_replay, kept_region_open
 
 __all__ = ["causal_conv1d", "chunk_gated_delta_rule"]
 
@@ -285,9 +285,13 @@ def _kernel_rule(q, k, v, g, beta, mm):
     return _kernel_rule_fwd(q, k, v, g, beta, mm)[0]
 
 
-def _kernel_rule_fwd(q, k, v, g, beta, mm):
-    o, states = gated_delta_rule.gdn_chunk_rule_fwd(
+def _rule_kernel(q, k, v, g, beta, mm):
+    return gated_delta_rule.gdn_chunk_rule_fwd(
         q, k, v, g, beta, mm=mm, block_chunks=_BLOCK_CHUNKS)
+
+
+def _kernel_rule_fwd(q, k, v, g, beta, mm):
+    o, states = _rule_kernel(q, k, v, g, beta, mm)
     return o, (q, k, v, g, beta, states)
 
 
@@ -311,6 +315,36 @@ def _kernel_rule_bwd(mm, res, do):
 
 
 _kernel_rule.defvjp(_kernel_rule_fwd, _kernel_rule_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
+def _kernel_rule_kept(q, k, v, g, beta, o, states, mm):
+    """``_kernel_rule`` whose forward kernel has run: ``o`` and ``states``
+    are what it wrote. The backward is ``_kernel_rule_bwd`` on them."""
+    return o
+
+
+def _kernel_rule_kept_fwd(q, k, v, g, beta, o, states, mm):
+    return o, (q, k, v, g, beta, states)
+
+
+def _kernel_rule_kept_bwd(mm, res, do):
+    return _kernel_rule_bwd(mm, res, do) + (None, None)
+
+
+_kernel_rule_kept.defvjp(_kernel_rule_kept_fwd, _kernel_rule_kept_bwd)
+
+
+def _kernel_rule_in_region(q, k, v, g, beta, mm):
+    """``_kernel_rule`` inside a ``fleet.utils.recompute`` region: the
+    kernel runs in the region's first forward alone, and the replay gets its
+    ``o`` and ``states`` back (``tensor.kept_over_replay``)."""
+    arrays = (q, k, v, g, beta)
+    o, states = kept_over_replay(
+        "gdn_chunk_rule_fwd",
+        tuple((x.shape, x.dtype) for x in arrays) + (mm,),
+        lambda: _rule_kernel(*arrays, mm))
+    return _kernel_rule_kept(*arrays, o, states, mm)
 
 
 def chunk_gated_delta_rule(q, k, v, g, beta, chunk_size=64,
@@ -350,6 +384,8 @@ def chunk_gated_delta_rule(q, k, v, g, beta, chunk_size=64,
                         else "paddle_gdn_rule_composite_traces_total", 1)
         with jax.named_scope("gdn.chunk_rule"):
             if kernel:
-                return _kernel_rule(*arrays, mm)
+                rule = (_kernel_rule_in_region if kept_region_open()
+                        else _kernel_rule)
+                return rule(*arrays, mm)
             return _chunk_rule(*arrays, chunk=int(chunk_size), mm=mm)
     return apply_op(f, q, k, v, g, beta)

@@ -42,6 +42,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .. import pallas as _pallas
 from ...inference.telemetry import runtime_counter
+from ...tensor.tensor import (computed_in_replay, kept_over_replay,
+                              kept_region_open)
 
 __all__ = ["flash_attention", "is_supported", "block_diffusion_mask",
            "dense_mask"]
@@ -799,6 +801,40 @@ def _flash_bwd(mask, scale, dropout_p, res, g):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+def _flash_kept(q, k, v, seed, o, lse, mask, scale, dropout_p):
+    """``_flash`` whose forward kernel has run: ``o`` and ``lse`` [B, H, S]
+    are what it wrote. The backward is ``_flash_bwd`` on them."""
+    return o
+
+
+def _flash_kept_fwd(q, k, v, seed, o, lse, mask, scale, dropout_p):
+    return o, (q, k, v, o, lse, seed)
+
+
+def _flash_kept_bwd(mask, scale, dropout_p, res, g):
+    q, k, v, o, lse, seed = res
+    return _flash_bwd(mask, scale, dropout_p,
+                      (q, k, v, o, lse[..., None], seed), g) + (None, None)
+
+
+_flash_kept.defvjp(_flash_kept_fwd, _flash_kept_bwd)
+
+
+def _flash_in_region(q, k, v, seed, mask, scale, dropout_p):
+    """``_flash`` inside a ``fleet.utils.recompute`` region: the forward
+    kernel runs in the region's first forward alone, and the replay gets its
+    ``o`` and ``lse`` back (``tensor.kept_over_replay``). ``lse`` is kept
+    without the kernel's last axis of 1, which HBM pads to 128 lanes."""
+    def first():
+        o, lse = _core_fwd(q, k, v, seed, mask, scale, dropout_p)
+        return o, lse[..., 0]
+    call = tuple((x.shape, x.dtype) for x in (q, k, v)) + (
+        mask, scale, dropout_p)
+    o, lse = kept_over_replay("flash_attention_fwd", call, first)
+    return _flash_kept(q, k, v, seed, o, lse, mask, scale, dropout_p)
+
+
 def flash_attention(q, k, v, causal=False, scale=None, dropout_p=0.0,
                     dropout_seed=None, mask=None):
     """q,k,v: [batch, seq, heads, head_dim] (kv heads may divide q heads).
@@ -809,6 +845,12 @@ def flash_attention(q, k, v, causal=False, scale=None, dropout_p=0.0,
     scalar, traced ok) for reproducibility. ``mask`` is a structured mask
     (``block_diffusion_mask(L, b)``, over ``2 L`` positions of q and k
     alike; not with ``causal``, not with dropout), never an array.
+    Inside a ``fleet.utils.recompute`` region the forward kernel runs once:
+    the region keeps its outputs for the replay (``_flash_in_region``). Not
+    on a mesh of several devices, where this call is the body of a
+    ``shard_map`` (``nn.functional.attention._per_shard``) and a value made
+    in one such body cannot be handed to another: there the replay computes
+    them again.
     """
     if mask is not None and (causal or not is_supported(
             q.shape, q.dtype, mask, k.shape, dropout_p)):
@@ -827,6 +869,13 @@ def flash_attention(q, k, v, causal=False, scale=None, dropout_p=0.0,
     vt = jnp.swapaxes(v, 1, 2)
     if dropout_seed is None:
         dropout_seed = jnp.zeros((), jnp.int32)
-    o = _flash(qt, kt, vt, dropout_seed, "causal" if causal else mask,
-               float(scale), float(dropout_p))
+    from ...parallel import current_mesh
+    flash = _flash
+    mesh = current_mesh()
+    if mesh is not None and mesh.devices.size > 1:
+        computed_in_replay()
+    elif kept_region_open():
+        flash = _flash_in_region
+    o = flash(qt, kt, vt, dropout_seed, "causal" if causal else mask,
+              float(scale), float(dropout_p))
     return jnp.swapaxes(o, 1, 2)

@@ -38,6 +38,11 @@ __all__ = [
     "unregister_persistent",
     "persistent_tensors",
     "clear_tape",
+    "KeptRegion",
+    "RecomputeKeepError",
+    "kept_region_open",
+    "kept_over_replay",
+    "computed_in_replay",
 ]
 
 _uid = itertools.count()
@@ -47,6 +52,8 @@ class _TapeState(threading.local):
     def __init__(self):
         self.nodes: list[_TapeNode] = []
         self.grad_enabled: bool = True
+        # the recompute regions being run, innermost last (KeptRegion)
+        self.kept: list[KeptRegion] = []
 
 
 _tape = _TapeState()
@@ -95,6 +102,115 @@ def enable_grad():
 
 def clear_tape() -> None:
     _tape.nodes.clear()
+
+
+# ------------------------------------------------- kept over a replay
+class RecomputeKeepError(RuntimeError):
+    """A recompute region's replay did not meet the kept values its first
+    forward left: ``function`` took another path the second time."""
+
+
+class KeptRegion:
+    """What one call of ``fleet.utils.recompute`` keeps from its first
+    forward for its replay: the outputs of operations whose forward is not
+    worth running twice (a Pallas kernel's), in the order the forward met
+    them. ``recompute`` opens the region around each of the two passes
+    (``forward()``, ``replay()``); the operations reach it through
+    ``kept_over_replay``. The arrays are whatever the forward computed:
+    tracers under ``to_static``, where the replay is part of the same trace,
+    and device arrays in eager mode."""
+
+    __slots__ = ("name", "entries", "cursor")
+
+    def __init__(self, name):
+        self.name = name
+        self.entries = []       # [(label, call, arrays)]
+        self.cursor = None      # None in the forward; the replay's position
+
+    def forward(self):
+        """Around the first forward, whose operations keep here."""
+        return self._open()
+
+    @contextlib.contextmanager
+    def replay(self):
+        """Around the replay, which takes the entries in order, and all
+        of them."""
+        self.cursor = 0
+        with self._open():
+            yield
+        if self.cursor != len(self.entries):
+            label = self.entries[self.cursor][0]
+            raise RecomputeKeepError(
+                f"recompute({self.name}): the replay ended with entry "
+                f"{self.cursor} of {len(self.entries)} ({label!r}) not "
+                "taken: the function ran another path than in its first "
+                "forward")
+
+    @contextlib.contextmanager
+    def _open(self):
+        _tape.kept.append(self)
+        try:
+            yield
+        finally:
+            _tape.kept.pop()
+
+    def _take(self, label, call):
+        at = self.cursor
+        if at >= len(self.entries):
+            raise RecomputeKeepError(
+                f"recompute({self.name}): the replay asks for entry {at} "
+                f"({label!r}) and the first forward kept {at}: the function "
+                "ran another path than in its first forward")
+        kept_label, kept_call, arrays = self.entries[at]
+        if (kept_label, kept_call) != (label, call):
+            raise RecomputeKeepError(
+                f"recompute({self.name}): entry {at} was kept by "
+                f"{kept_label!r} called with {kept_call} and the replay "
+                f"asks for it from {label!r} called with {call}: the "
+                "function ran another path than in its first forward")
+        self.cursor = at + 1
+        return arrays
+
+
+def kept_region_open() -> bool:
+    """Whether this code runs inside a ``fleet.utils.recompute`` region."""
+    return bool(_tape.kept)
+
+
+def kept_over_replay(label, call, compute):
+    """``compute()``'s tuple of arrays, computed once for a recompute
+    region's two passes. In the first forward it is computed and kept; in
+    the replay the same call, met in the same order, gets the kept arrays
+    back and ``compute`` does not run (``paddle_recompute_kept_total``
+    counts that, once a trace). ``label`` names the operation and ``call``
+    is everything that decides what it computes besides its arrays' values
+    (their shapes and dtypes, its static arguments), comparable with
+    ``==``: the replay gets an entry only for the call that kept it, so the
+    arrays have the shapes and dtypes they were stored with. A first
+    forward that itself runs in an outer region's replay takes from that
+    region. Outside any region ``compute`` runs."""
+    fresh, arrays = [], None
+    for region in reversed(_tape.kept):
+        if region.cursor is not None:
+            arrays = region._take(label, call)
+            from ..inference.telemetry import runtime_counter
+            runtime_counter("paddle_recompute_kept_total", 1)
+            break
+        fresh.append(region)
+    if arrays is None:
+        arrays = tuple(compute())
+    for region in fresh:
+        region.entries.append((label, call, arrays))
+    return arrays
+
+
+def computed_in_replay() -> None:
+    """For an operation that could keep its forward and here cannot (its
+    kernel runs inside ``shard_map``): counts the forward that a replay
+    computes again, ``paddle_recompute_replayed_total``."""
+    if any(region.cursor is not None for region in _tape.kept):
+        from ..inference.telemetry import runtime_counter
+        runtime_counter("paddle_recompute_replayed_total", 1)
 
 
 # Persistent-state registry: Parameters and optimizer accumulators register here

@@ -441,7 +441,9 @@ def test_gated_deltanet_mixer_keeps_one_layout(topo, monkeypatch):
     copy into another tiling: held here by what the ENTRY computation's
     plain data-movement instructions (fusions not counted) write, by the
     absence of a (2, 128) tile among them (a size-2 axis in the sublanes),
-    and by the program's temporaries. These are BYTES of a compile, not
+    by ONE call of the rule's kernel (the region keeps what it wrote; the
+    replay does not run it again) and by the program's temporaries. These
+    are BYTES of a compile, not
     times: PERF.md section 6 (PR 29) says which of them turned into time on
     the chip. Before PR 29: 8.19 GiB, nine such tiles, 5.02 GiB."""
     import paddle_tpu as paddle
@@ -494,8 +496,14 @@ def test_gated_deltanet_mixer_keeps_one_layout(topo, monkeypatch):
     # forward passes move nothing; what is left, 1.42 GiB, is the composite
     # BACKWARD's: v and o's cotangent into its blocks (256 + 128 MiB), the
     # gradients of q, k and v out of them (128 + 128 + 256) and the reverse
-    # scan's zero-filled results (the same again). Temporaries 2.84 GiB.
-    assert "gdn_chunk_rule_fwd" in text
+    # scan's zero-filled results (the same again). Since PR 33 the region
+    # keeps the kernel's ``o`` and ``states`` for its replay: ONE call of
+    # the kernel in forward + replay + backward, 1.41 GiB moved, and
+    # temporaries 2.99 GiB (2.84 when the replay ran the kernel again and
+    # nothing of it outlived the forward).
+    rule = [k for k in _KERNEL_INSTR.findall(text)
+            if "gdn_chunk_rule_fwd" in k]
+    assert len(rule) == 1, rule
     assert moved / gib <= 1.6, moved / gib
     assert compiled.memory_analysis().temp_size_in_bytes / gib <= 3.2
 
@@ -507,8 +515,10 @@ def test_sdar_layer_compiles_with_the_masked_kernels(topo, monkeypatch):
     backward pass in one program, as the cell's step holds six of. The
     three flash kernels are in it under the structured mask (no dense
     [16384, 16384] mask is: the composite's scores alone would be 34 GB),
-    and its temporaries (1.90 GiB) leave the step room beside its state. BYTES
-    of a compile, not times."""
+    each ONCE: the region keeps the forward kernel's ``o`` and ``lse`` and
+    the replay does not run it again (twice before PR 33). Its temporaries
+    (1.77 GiB; 1.90 with the replayed forward) leave the step room beside
+    its state. BYTES of a compile, not times."""
     import paddle_tpu as paddle
     import paddle_tpu.ops.pallas as pallas
     from paddle_tpu.inference import telemetry
@@ -544,11 +554,11 @@ def test_sdar_layer_compiles_with_the_masked_kernels(topo, monkeypatch):
     kernels = _KERNEL_INSTR.findall(compiled.as_text())
     for name in ("flash_attention_fwd", "flash_attention_bwd_dkv",
                  "flash_attention_bwd_dq"):
-        assert any(name in k for k in kernels), (name, kernels)
+        assert sum(name in k for k in kernels) == 1, (name, kernels)
     # the first forward and its replay each traced the dispatch once
     assert telemetry.runtime_counter(
         "paddle_flash_mask_kernel_traces_total") == kernel + 2
-    assert compiled.memory_analysis().temp_size_in_bytes / (1 << 30) <= 2.4
+    assert compiled.memory_analysis().temp_size_in_bytes / (1 << 30) <= 2.1
 
 
 @pytest.mark.parametrize("m,k,o,says", [
