@@ -3,6 +3,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ...tensor.tensor import Tensor, apply_op
 
@@ -136,8 +137,10 @@ def _adaptive(x, output_size, n, op):
         # decompose into per-axis adaptive windows
         for i, (dim, osz) in enumerate(zip(spatial, out_sz)):
             ax = 2 + i
-            starts = (jnp.arange(osz) * dim) // osz
-            ends = ((jnp.arange(osz) + 1) * dim + osz - 1) // osz
+            # static window bounds: NumPy, so that they stay numbers when
+            # the caller is traced
+            starts = (np.arange(osz) * dim) // osz
+            ends = ((np.arange(osz) + 1) * dim + osz - 1) // osz
             segs = []
             for j in range(osz):
                 sl = jax.lax.slice_in_dim(res, int(starts[j]), int(ends[j]),

@@ -6,8 +6,40 @@ Tests never touch a chip (a chip belongs to one process at a time, and the
 suite starts children): XLA_FLAGS is set before first backend init and
 jax_platforms is forced to cpu via jax.config, which wins over the env. The
 chip is exercised by chip_smoke.py only.
+
+For whoever writes the next model's tests:
+
+**Every test has a limit.** ``LIMIT`` seconds after a test's set-up starts, a
+watchdog thread in C (``faulthandler.dump_traceback_later(exit=True)``) prints
+every thread's stack to the real stderr and ends the process, whether the
+main thread is in Python or waits inside XLA, a lock or a child's pipe. Under
+xdist the worker shows as ``node down``, the test it ran is failed by name
+and a new worker takes the rest of the queue; without xdist the run ends
+there. The end of a session has ``END_LIMIT`` the same way. There is no
+option to raise it: a test that needs minutes is marked ``slow``. A test
+that starts a child uses ``paddle_tpu.testing.child`` (``Child``,
+``run_child``, ``run_launch``): its waits have deadlines below ``LIMIT`` and
+it kills the child's process group when the ``with`` block ends. Send
+``SIGUSR1`` to a controller or a ``gw`` worker to read where it stands.
+
+**Build the tiny model once a module, trace once a shape.** ``--dist
+loadfile`` makes each file one serial chain on one worker, so the run is never
+shorter than its longest file, and a model file's time is its traces and
+compiles, not its arithmetic: eager, a whole model is some hundreds of
+one-operation compiles. Run its forward and backward as ONE compiled program
+(``paddle.jit.to_static``, the oracle under ``jax.jit``), run each compiled
+training step once in a module-scoped fixture that returns numbers and arrays,
+and let the tests read them (``tests/test_qwen3_next_model.py``). A new
+model's file has 60 s as a chain under ``-n 6`` and no test over 40 s. Nothing
+keeps the model past the module (a global, a cache, a thread):
+``pytest_runtest_setup`` below fails the next file's first test when a
+parameter outlives its file, because a compiled step's state is every live
+parameter of the process (ROADMAP.md, Design, D15).
 """
+import faulthandler
+import gc
 import os
+import signal
 
 flags = os.environ.get("XLA_FLAGS", "")
 if "host_platform_device_count" not in flags:
@@ -21,6 +53,144 @@ assert jax.devices()[0].platform == "cpu"
 
 import numpy as np
 import pytest
+
+# Seconds one test may take, set-up, teardown and the finalizers of the
+# worker's last test included: about six times the slowest test under -n 6.
+LIMIT = 240.0
+# Seconds the end of a session may take, in a worker and in the controller.
+END_LIMIT = 60.0
+
+_real_stderr = [None]
+
+
+def pytest_configure(config):
+    # fd 2 is the real stderr here (capture is suspended) and a capture
+    # file while a test runs, where stacks would be lost with the process
+    _real_stderr[0] = os.dup(2)
+    faulthandler.register(signal.SIGUSR1, file=_real_stderr[0],
+                          all_threads=True)
+
+
+def _arm(seconds):
+    faulthandler.dump_traceback_later(seconds, exit=True,
+                                      file=_real_stderr[0])
+
+
+@pytest.hookimpl(hookwrapper=True, tryfirst=True)
+def pytest_runtest_protocol(item, nextitem):
+    _arm(LIMIT)
+    try:
+        yield
+    finally:
+        faulthandler.cancel_dump_traceback_later()
+
+
+@pytest.hookimpl(tryfirst=True)
+def pytest_sessionfinish(session):
+    _arm(END_LIMIT)
+
+
+@pytest.hookimpl(tryfirst=True)
+def pytest_unconfigure(config):
+    if hasattr(config, "workerinput"):
+        # a worker that is done idles until the controller ends the run,
+        # and is killed by it 10 s after that
+        faulthandler.cancel_dump_traceback_later()
+    else:
+        # left armed: the interpreter's own exit (the threads it joins,
+        # atexit) is part of the end
+        _arm(END_LIMIT)
+
+
+@pytest.hookimpl(optionalhook=True)
+def pytest_xdist_make_scheduler(config, log):
+    """The driver's ``--dist loadfile`` with what a worker's death needs:
+    xdist 3.8's scope scheduler puts ALL of a dead worker's files back in the
+    queue, the finished ones too and the one with the test that killed it.
+    The replacement is then handed a finished file, reports nothing, is never
+    looked at again, and the controller waits for good (or it is handed the
+    fatal test once more). And a replacement that has not reported its
+    collection yet is a ``KeyError`` whenever another worker goes down
+    meanwhile. And a replacement is handed ONE file; if that has one test,
+    the worker holds it until it hears what follows, which is nothing. Here
+    only unfinished files go back, the fatal test counts as run (the
+    controller fails it by name), a node gets work once its collection has
+    arrived, and then until it has three tests pending or the queue is
+    empty."""
+    if config.getvalue("dist") != "loadfile":
+        return None
+    from xdist.scheduler import LoadFileScheduling
+
+    class AfterADeath(LoadFileScheduling):
+        def _reschedule(self, node):
+            # until nothing more is handed out: enough pending, or none left
+            # and the node told to finish (a worker runs a test only once it
+            # knows what follows it)
+            handed = node in self.registered_collections
+            while handed:
+                before = len(self.workqueue)
+                super()._reschedule(node)
+                handed = len(self.workqueue) < before
+
+        def remove_node(self, node):
+            fatal = None
+            for scope, tests in self.assigned_work.pop(node).items():
+                left = [name for name, done in tests.items() if not done]
+                if left and fatal is None:
+                    fatal = left.pop(0)
+                    tests[fatal] = True
+                if left:
+                    self.workqueue[scope] = tests
+            for other in self.assigned_work:
+                self._reschedule(other)
+            return fatal
+    return AfterADeath(config, log)
+
+
+# uid of a persistent tensor that a test file's import left alive -> the file
+_made_at_import = {}
+_file = [None]
+
+
+def pytest_collectreport(report):
+    if report.nodeid.endswith(".py"):
+        from paddle_tpu.tensor.tensor import persistent_tensors
+        for t in persistent_tensors():
+            _made_at_import.setdefault(t._uid, report.nodeid)
+
+
+@pytest.hookimpl(tryfirst=True)
+def pytest_runtest_setup(item):
+    """At a test file's first test: no compiled step of this file may see
+    another file's parameters (``jit.to_static`` takes every live persistent
+    tensor of the process as its state). What the file before left alive is
+    named, fails THIS test, and leaves the registry, so that it costs one
+    failure and not one in every file that follows on this worker. Process-
+    wide by design, and allowed: this thread's global RNG key
+    (``core/rng.py``). A tensor that a test file's import made stays, and
+    fails every file's first test until the file builds it in a fixture."""
+    if item.path == _file[0]:
+        return
+    before, _file[0] = _file[0], item.path
+    from paddle_tpu.core.rng import _rng
+    from paddle_tpu.tensor.tensor import (persistent_tensors,
+                                          unregister_persistent_many)
+    gc.collect()
+    strays = [t for t in persistent_tensors() if t is not _rng.key_tensor]
+    if not strays:
+        return
+    t = strays[0]
+    where = _made_at_import.get(t._uid)
+    unregister_persistent_many(
+        [s for s in strays if s._uid not in _made_at_import])
+    pytest.fail(
+        f"{len(strays)} persistent tensors outlive their test file; the "
+        f"first has shape {tuple(t.shape)} {t.dtype} and was made "
+        + (f"by the import of {where}" if where else
+           f"in {before.name if before else 'no test file'} or a file "
+           "before it on this worker")
+        + ": release the module global, cache or thread that holds it",
+        pytrace=False)
 
 
 @pytest.fixture(autouse=True)

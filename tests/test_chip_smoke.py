@@ -5,14 +5,14 @@ width (on-chip-measurement guide, section 2.1/2.2) — with the platform
 check patched HERE, not through a flag of the script."""
 import json
 import os
-import subprocess
 import sys
 
 import pytest
 
 import jax
 
-REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from paddle_tpu.testing.child import REPO_ROOT, cpu_env, run_child
+
 sys.path.insert(0, REPO_ROOT)
 
 import chip_smoke
@@ -50,9 +50,8 @@ def test_compile_cache_defaults_to_one_path_in_the_checkout(monkeypatch):
 # ------------------------------------------------------------ chip_smoke.py
 def test_chip_smoke_fails_without_a_chip():
     """As the driver's sandbox runs it: non-zero exit, no result line."""
-    r = subprocess.run([sys.executable, "chip_smoke.py"], cwd=REPO_ROOT,
-                       env=dict(os.environ, JAX_PLATFORMS="cpu"),
-                       capture_output=True, text=True, timeout=300)
+    r = run_child([sys.executable, "chip_smoke.py"], cwd=REPO_ROOT,
+                  env=cpu_env())
     assert r.returncode != 0
     assert '"ok"' not in r.stdout
     assert "no CPU fallback" in r.stderr

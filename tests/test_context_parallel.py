@@ -158,8 +158,9 @@ class TestLlamaContextParallel:
             x = paddle.to_tensor(np.random.RandomState(0).randint(
                 0, 256, (2, 32)).astype(np.int32))
             dense.eval(); ring.eval()
-            out_d = dense(x)
-            out_r = ring(x)
+            with paddle.no_grad():      # each forward one compiled program
+                out_d = paddle.jit.to_static(dense)(x)
+                out_r = paddle.jit.to_static(ring)(x)
             np.testing.assert_allclose(np.asarray(out_r._data),
                                        np.asarray(out_d._data),
                                        atol=3e-5, rtol=3e-5)
@@ -296,7 +297,9 @@ class TestModelUlyssesOption:
             np.int32)
         x, y = paddle.to_tensor(ids), paddle.to_tensor(ids)
         ref = build(False)
-        loss_ref = float(np.asarray(ref(x, labels=y)._data))
+        with paddle.no_grad():          # each forward one compiled program
+            loss_ref = float(np.asarray(
+                paddle.jit.to_static(ref)(x, labels=y)._data))
 
         strategy = fleet.DistributedStrategy()
         strategy.hybrid_configs = {"dp_degree": 2, "mp_degree": 1,
@@ -304,7 +307,9 @@ class TestModelUlyssesOption:
                                    "sep_degree": 4}
         fleet.init(is_collective=True, strategy=strategy)
         m = build("ulysses")
-        loss_u = float(np.asarray(m(x, labels=y)._data))
+        with paddle.no_grad():
+            loss_u = float(np.asarray(
+                paddle.jit.to_static(m)(x, labels=y)._data))
         np.testing.assert_allclose(loss_u, loss_ref, rtol=2e-5)
         # and the scheme actually selected ulysses
         attn = m.llama.layers[0].self_attn

@@ -29,12 +29,12 @@ import pytest
 
 import paddle_tpu as paddle
 from paddle_tpu.incubate.nn import FusedMultiTransformer
-from paddle_tpu.inference.generation import FusedDecoder
 from paddle_tpu.inference.serving import AdmissionFull, ServingEngine
 from paddle_tpu.nn.layer.common import Embedding, Linear
 from paddle_tpu.serving_cluster import (Autoscaler, LocalReplica,
                                         NoReplicaError, Router)
 from paddle_tpu.testing import fault
+from paddle_tpu.testing.oracle import sequential_tokens
 
 V, E, H, FF, L = 97, 32, 4, 64, 2
 WAIT_S = 120                              # bound on every drive loop
@@ -58,11 +58,8 @@ def _engine(fmt, embed, head, **kw):
 
 
 def _oracle(fmt, embed, head, prompt, max_new):
-    dec = FusedDecoder(fmt, embed, head, max_seq_len=128)
-    out = dec.generate(
-        paddle.to_tensor(np.asarray(prompt, np.int32)[None]),
-        max_new_tokens=max_new)
-    return [int(t) for t in np.asarray(out._data)[0, len(prompt):]]
+    return sequential_tokens(fmt, embed, head, prompt,
+                             max_new_tokens=max_new).tolist()
 
 
 def _prompt(n=10, seed=3):
@@ -855,7 +852,7 @@ class TestRetryAfter:
 # =====================================================================
 # migration across the rpc boundary
 # =====================================================================
-def test_rpc_migration_state_round_trip():
+def test_rpc_migration_state_round_trip(monkeypatch):
     """The migration payload (numpy KV blocks + the contract) must
     pickle through the rpc transport intact: export over rpc from the
     served engine, import into a local engine, finish with oracle
@@ -864,7 +861,9 @@ def test_rpc_migration_state_round_trip():
     if load_native() is None:
         pytest.skip("native runtime unavailable")
     from paddle_tpu.distributed import rpc
-    from paddle_tpu.serving_cluster import RpcReplica, serve_engine
+    from paddle_tpu.serving_cluster import RpcReplica, replica, serve_engine
+    # the served replica is the PROCESS's: this one's goes with the test
+    monkeypatch.setattr(replica, "_WORKER", [None])
 
     fmt, embed, head = _model()
     rpc.init_rpc("elastic_worker0", rank=0, world_size=1,

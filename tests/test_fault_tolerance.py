@@ -9,7 +9,6 @@ this file's own monkeypatched process — see the conftest leak guard).
 """
 import os
 import socket
-import subprocess
 import sys
 import threading
 import time
@@ -25,27 +24,12 @@ from paddle_tpu.distributed.checkpoint import (latest_step, load_latest,
 from paddle_tpu.distributed.resilience import (PeerFailureError, Watchdog,
                                                WATCHDOG_EXIT_CODE)
 from paddle_tpu.testing import FI_ENV_VARS, fault
+from paddle_tpu.testing.child import (REPO_ROOT, cpu_env, run_child,
+                                      run_launch)
 from paddle_tpu.tensor.tensor import Tensor
 
 needs_native = pytest.mark.skipif(load_native() is None,
                                   reason="native runtime unavailable")
-
-REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _run_launch(tmp_path, script_body, extra_args, script_args,
-                timeout=240):
-    script = tmp_path / "companion.py"
-    script.write_text(script_body)
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    env["PYTHONPATH"] = REPO_ROOT + os.pathsep + env.get("PYTHONPATH", "")
-    return subprocess.run(
-        [sys.executable, "-m", "paddle_tpu.distributed.launch",
-         "--log_dir", str(tmp_path / "log")] + extra_args +
-        [str(script)] + script_args,
-        env=env, cwd=REPO_ROOT, capture_output=True, text=True,
-        timeout=timeout)
 
 
 # =====================================================================
@@ -448,15 +432,10 @@ class TestFaultHarness:
                 "for i in range(5):\n"
                 "    fault.inject('step')\n"
                 "raise SystemExit(0)\n")
-        env = dict(os.environ,
-                   JAX_PLATFORMS="cpu",
-                   PYTHONPATH=REPO_ROOT + os.pathsep +
-                   os.environ.get("PYTHONPATH", ""),
-                   PADDLE_TRAINER_ID="0", PADDLE_FI_KILL_RANK="0",
-                   PADDLE_FI_AT_STEP="2")
-        r = subprocess.run([sys.executable, "-c", code], env=env,
-                           cwd=REPO_ROOT, capture_output=True, text=True,
-                           timeout=120)
+        env = cpu_env(PADDLE_TRAINER_ID="0", PADDLE_FI_KILL_RANK="0",
+                      PADDLE_FI_AT_STEP="2")
+        r = run_child([sys.executable, "-c", code], env=env, cwd=REPO_ROOT,
+                      timeout=120)
         assert r.returncode == fault.FI_EXIT_CODE, (r.stdout, r.stderr)
         assert "KILLED at step" in r.stdout
 
@@ -464,14 +443,9 @@ class TestFaultHarness:
         code = ("from paddle_tpu.testing import fault\n"
                 "fault.inject('init')\n"
                 "raise SystemExit(0)\n")
-        env = dict(os.environ,
-                   JAX_PLATFORMS="cpu",
-                   PYTHONPATH=REPO_ROOT + os.pathsep +
-                   os.environ.get("PYTHONPATH", ""),
-                   PADDLE_TRAINER_ID="3", PADDLE_FI_KILL_RANK="3")
-        r = subprocess.run([sys.executable, "-c", code], env=env,
-                           cwd=REPO_ROOT, capture_output=True, text=True,
-                           timeout=120)
+        env = cpu_env(PADDLE_TRAINER_ID="3", PADDLE_FI_KILL_RANK="3")
+        r = run_child([sys.executable, "-c", code], env=env, cwd=REPO_ROOT,
+                      timeout=120)
         assert r.returncode == fault.FI_EXIT_CODE, (r.stdout, r.stderr)
 
 
@@ -683,13 +657,9 @@ class TestWatchdogEscalation:
     def test_wedged_rank_hard_exits_with_watchdog_code(self, tmp_path):
         script = tmp_path / "wedged.py"
         script.write_text(WEDGED)
-        env = dict(os.environ, JAX_PLATFORMS="cpu",
-                   PYTHONPATH=REPO_ROOT + os.pathsep +
-                   os.environ.get("PYTHONPATH", ""))
         t0 = time.monotonic()
-        r = subprocess.run([sys.executable, str(script)], env=env,
-                           cwd=REPO_ROOT, capture_output=True, text=True,
-                           timeout=120)
+        r = run_child([sys.executable, str(script)], env=cpu_env(),
+                      cwd=REPO_ROOT, timeout=120)
         assert r.returncode == WATCHDOG_EXIT_CODE, (r.stdout, r.stderr)
         assert "no heartbeat from rank 1" in r.stdout
         assert time.monotonic() - t0 < 60.0
@@ -721,7 +691,7 @@ class TestGangSupervisor:
         Supervisor: first bad exit tears the gang down in seconds and
         prints an attributable per-rank report with the log tail."""
         t0 = time.monotonic()
-        r = _run_launch(tmp_path, SLOW_SURVIVOR,
+        r = run_launch(tmp_path, SLOW_SURVIVOR,
                         ["--nproc_per_node", "2"], [], timeout=90)
         assert r.returncode == 7, (r.stdout, r.stderr)
         assert time.monotonic() - t0 < 60.0
@@ -730,7 +700,7 @@ class TestGangSupervisor:
         assert "rank 1 failing now" in r.stderr     # workerlog tail
 
     def test_workerlog_rotates_per_generation(self, tmp_path):
-        r = _run_launch(tmp_path, GEN_LOGGER,
+        r = run_launch(tmp_path, GEN_LOGGER,
                         ["--nproc_per_node", "2", "--max_restart", "1",
                          "--restart_backoff", "0.1"], [])
         assert r.returncode == 0, (r.stdout, r.stderr)
@@ -789,6 +759,11 @@ resumed = dist.load_latest(sd, root)     # both ranks read the shared dir
 if resumed is not None:
     start = resumed
     open(f"{workdir}/resumed_from.{gen}.{rank_s}", "w").write(str(resumed))
+# no rank saves before both have looked: on a loaded host rank 1 came here
+# seconds after rank 0, resumed generation 0 from rank 0's step 5, never
+# reached the loop's step 2 that silences its heartbeat, and hung with it
+# beating; rank 0 finished its steps and sat out jax's shutdown barrier
+dist.barrier()
 
 rng = np.random.RandomState(0)
 xs = rng.randn(steps, 8, 4).astype(np.float32)
@@ -835,10 +810,10 @@ class TestFaultToleranceEndToEnd:
         a bumped PADDLE_RESTART_COUNT -> generation 1 resumes from
         load_latest() and completes. Entire test bounded by the
         subprocess timeout."""
-        r = _run_launch(tmp_path, FT_E2E,
+        r = run_launch(tmp_path, FT_E2E,
                         ["--nproc_per_node", "2", "--max_restart", "2",
                          "--restart_backoff", "0.2"],
-                        [str(tmp_path)], timeout=200)
+                        [str(tmp_path)])
         assert r.returncode == 0, (r.stdout, r.stderr)
         # generation 0 ran both ranks; generation 1 proves the restart
         # and the PADDLE_RESTART_COUNT env contract
@@ -891,10 +866,10 @@ open(f"{workdir}/done.{gen}", "w").write(str(start))
 
 class TestFaultInjectionKillResume:
     def test_kill_restart_resumes_from_latest(self, tmp_path):
-        r = _run_launch(tmp_path, FI_KILL,
+        r = run_launch(tmp_path, FI_KILL,
                         ["--nproc_per_node", "1", "--max_restart", "1",
                          "--restart_backoff", "0.1"],
-                        [str(tmp_path)], timeout=150)
+                        [str(tmp_path)])
         assert r.returncode == 0, (r.stdout, r.stderr)
         assert f"exit {fault.FI_EXIT_CODE}" in r.stderr  # attributed
         done = tmp_path / "done.1"

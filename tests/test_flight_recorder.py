@@ -31,8 +31,6 @@ import importlib.util
 import io
 import json
 import os
-import subprocess
-import sys
 import time
 import types
 
@@ -44,11 +42,10 @@ import paddle_tpu.distributed as dist
 from paddle_tpu.core.native import TCPStore, TCPStoreServer, load_native
 from paddle_tpu.distributed.resilience import flight_recorder as fr
 from paddle_tpu.testing import FI_ENV_VARS, FR_ENV_VARS, fault
+from paddle_tpu.testing.child import REPO_ROOT, run_launch
 
 needs_native = pytest.mark.skipif(load_native() is None,
                                   reason="native runtime unavailable")
-
-REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture
@@ -782,27 +779,13 @@ print("completed all collectives", flush=True)   # must never print
 
 @needs_native
 class TestDesyncEndToEnd:
-    def _run_launch(self, tmp_path, extra_args, timeout=240):
-        script = tmp_path / "companion.py"
-        script.write_text(DESYNC_E2E)
-        env = dict(os.environ)
-        env["JAX_PLATFORMS"] = "cpu"
-        env["PYTHONPATH"] = REPO_ROOT + os.pathsep + \
-            env.get("PYTHONPATH", "")
-        return subprocess.run(
-            [sys.executable, "-m", "paddle_tpu.distributed.launch",
-             "--log_dir", str(tmp_path / "log")] + extra_args +
-            [str(script)],
-            env=env, cwd=REPO_ROOT, capture_output=True, text=True,
-            timeout=timeout)
-
     def test_hang_produces_dumps_and_named_straggler(self, tmp_path):
         """Acceptance: a fault-injected hang in one rank produces
         per-rank flightdump files and a supervisor report naming the
         desynced collective (op + seq + group), the stuck rank, and its
         in-collective stack — all bounded, no sleeps-as-sync."""
         from paddle_tpu.distributed.resilience import WATCHDOG_EXIT_CODE
-        r = self._run_launch(tmp_path, ["--nproc_per_node", "2"])
+        r = run_launch(tmp_path, DESYNC_E2E, ["--nproc_per_node", "2"])
         # rank 1 (wedged INSIDE the collective) escalates via the
         # watchdog once rank 0's heartbeats never arrive
         assert r.returncode == WATCHDOG_EXIT_CODE, (r.stdout, r.stderr)

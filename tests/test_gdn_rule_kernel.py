@@ -60,25 +60,37 @@ def test_kernel_against_the_rule(hk, hv, seq, mm, monkeypatch):
     monkeypatch.setattr(pallas, "_enabled", lambda: True)
     arrays, cot = _inputs(seq, hk, hv)
     before = runtime_counter("paddle_gdn_rule_kernel_traces_total")
-    ts = [paddle.to_tensor(a) for a in arrays]
-    for t in ts:
-        t.stop_gradient = False
-    got = F.chunk_gated_delta_rule(*ts, chunk_size=CHUNK, matmul_dtype=mm)
-    (got * paddle.to_tensor(cot)).sum().backward()
+
+    # each side is one compiled program: a case is its traces and compiles,
+    # not its arithmetic (64 tokens took 4 s eager, 1100 took 7)
+    @paddle.jit.to_static
+    def through_the_kernel(cot, *ts):
+        for t in ts:
+            t.stop_gradient = False
+        out = F.chunk_gated_delta_rule(*ts, chunk_size=CHUNK,
+                                       matmul_dtype=mm)
+        (out * cot).sum().backward()
+        return out, [t.grad for t in ts]
+    got, got_grads = through_the_kernel(*map(paddle.to_tensor,
+                                             (cot,) + arrays))
     assert runtime_counter("paddle_gdn_rule_kernel_traces_total") > before
 
     # the composite, and what JAX derives from it
-    want, vjp = jax.vjp(lambda *a: la._chunk_rule(
-        *a, chunk=CHUNK, mm=jnp.dtype(mm)), *map(jnp.asarray, arrays))
+    @jax.jit
+    def composite(cot, *a):
+        out, vjp = jax.vjp(lambda *a: la._chunk_rule(
+            *a, chunk=CHUNK, mm=jnp.dtype(mm)), *a)
+        return out, vjp(cot)
+    want, want_grads = composite(cot, *arrays)
     exact = mm == "float32"
     np.testing.assert_allclose(np.asarray(got._data), want,
                                atol=2e-5 if exact else 1e-5)
-    for t, wg in zip(ts, vjp(jnp.asarray(cot))):
-        np.testing.assert_allclose(np.asarray(t.grad._data), wg,
+    for t, wg in zip(got_grads, want_grads):
+        np.testing.assert_allclose(np.asarray(t._data), wg,
                                    atol=2e-5 * max(1.0, np.abs(wg).max()))
     # the recurrence: float32 at the chunked rule's own tolerance, bf16
     # products within bf16's 2^-8 of the largest output a few times over
-    rec = np.asarray(_recurrence(*map(jnp.asarray, arrays)))
+    rec = np.asarray(jax.jit(_recurrence)(*arrays))
     np.testing.assert_allclose(
         np.asarray(got._data), rec,
         atol=2e-5 if exact else 2e-2 * np.abs(rec).max())

@@ -65,8 +65,15 @@ class TestPredictor:
 
 
 class TestGeneration:
-    def test_greedy_deterministic(self):
-        model = small_lm()
+    @pytest.fixture(scope="class")
+    def model(self):
+        """``small_lm()`` once for the class (every test built the same
+        seeded weights), its forward compiled: ``generate`` re-runs it on
+        every prefix length, one program a length where eager is some sixty
+        one-operation compiles a length, in every test again."""
+        return paddle.jit.to_static(small_lm())
+
+    def test_greedy_deterministic(self, model):
         x = np.random.RandomState(3).randint(0, 97, (2, 4)).astype(np.int32)
         out1 = generate(model, paddle.to_tensor(x), max_new_tokens=5)
         out2 = generate(model, paddle.to_tensor(x), max_new_tokens=5)
@@ -76,8 +83,7 @@ class TestGeneration:
         # prefix preserved
         np.testing.assert_array_equal(np.asarray(out1._data)[:, :4], x)
 
-    def test_sampling_topk(self):
-        model = small_lm()
+    def test_sampling_topk(self, model):
         paddle.seed(11)
         x = np.zeros((1, 2), np.int32)
         out = generate(model, paddle.to_tensor(x), max_new_tokens=4,
@@ -85,8 +91,7 @@ class TestGeneration:
         assert out.shape == [1, 6]
         assert np.asarray(out._data).max() < 97
 
-    def test_eos_early_stop(self):
-        model = small_lm()
+    def test_eos_early_stop(self, model):
         x = np.zeros((1, 2), np.int32)
         # whatever token greedy picks first, treat as eos -> stops at len 3
         first = generate(model, paddle.to_tensor(x), max_new_tokens=1)
@@ -95,13 +100,12 @@ class TestGeneration:
                        eos_token_id=eos)
         assert out.shape[1] <= 4
 
-    def test_beam_search_beats_or_ties_greedy_logprob(self):
+    def test_beam_search_beats_or_ties_greedy_logprob(self, model):
         """num_beams>1: the returned sequence's total log-prob must be >=
         greedy's (beam search explores a superset); num_beams=1-equivalent
         check: beams are deterministic and keep the prefix."""
         import jax
         import jax.numpy as jnp
-        model = small_lm()
         x = np.random.RandomState(9).randint(0, 97, (2, 3)).astype(np.int32)
         g = generate(model, paddle.to_tensor(x), max_new_tokens=5)
         bm = generate(model, paddle.to_tensor(x), max_new_tokens=5,
@@ -127,8 +131,7 @@ class TestGeneration:
         np.testing.assert_array_equal(np.asarray(bm._data),
                                       np.asarray(bm2._data))
 
-    def test_beam_search_eos_freezes_finished(self):
-        model = small_lm()
+    def test_beam_search_eos_freezes_finished(self, model):
         x = np.zeros((1, 2), np.int32)
         first = generate(model, paddle.to_tensor(x), max_new_tokens=1)
         eos = int(np.asarray(first._data)[0, -1])

@@ -71,18 +71,20 @@ def _rule_traces():
                  for which in ("kernel", "composite"))
 
 
-@pytest.mark.parametrize("build,rule", [
-    (_gpt2_tiny, (False, False)), (_qwen3next_tiny, (False, True)),
-    (_qwen3next_heads_of_128, (True, False))],
+@pytest.mark.parametrize("build,rule,calls", [
+    (_gpt2_tiny, (False, False), 3), (_qwen3next_tiny, (False, True), 3),
+    (_qwen3next_heads_of_128, (True, False), 1)],
     ids=["gpt2_tiny", "qwen3next_tiny", "qwen3next_rule_kernel"])
-def test_training_trace_reads_no_kernel_env(build, rule, monkeypatch,
+def test_training_trace_reads_no_kernel_env(build, rule, calls, monkeypatch,
                                             flash_calls):
     """Both cells' families, the step under ``to_static`` as the benchmark
     runs it: while it is traced (twice: the optimizer's slots appear in the
     first call) no ``PADDLE_TPU_*`` variable is read but the two named
     debts (ROADMAP.md D11). ``rule``: whether the traces took the delta
     rule's kernel, and its composite (heads of 16 in chunks of 16 are not
-    the kernel's; GPT-2 has no such layer)."""
+    the kernel's; GPT-2 has no such layer). The third case differs from the
+    second in the rule's sizes alone, which the first trace shows: it is
+    not traced again with the optimizer's slots."""
     before = _rule_traces()
     paddle.seed(5)
     model, ids = build()
@@ -108,7 +110,7 @@ def test_training_trace_reads_no_kernel_env(build, rule, monkeypatch,
         spy.setattr(environ, "__getitem__", lambda self, key: (
             read.append(key), getitem(self, key))[1])
         losses = [float(np.asarray(step(x, y)._data, np.float32))
-                  for _ in range(3)]
+                  for _ in range(calls)]
 
     assert all(math.isfinite(v) for v in losses)
     assert flash_calls, "the flash kernel was not in the traced step"

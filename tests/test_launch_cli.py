@@ -1,10 +1,8 @@
 """paddle_tpu.distributed.launch CLI: env contract, logs, restart
 (SURVEY §2.5 Launcher, §5.3 failure detection)."""
-import os
-import subprocess
-import sys
-
 import numpy as np
+
+from paddle_tpu.testing.child import run_launch
 
 COMPANION = """
 import os, sys
@@ -26,21 +24,6 @@ sys.exit(0 if n >= 1 else 1)      # fail on first attempt, pass on second
 """
 
 
-def _run_launch(tmp_path, script_body, extra_args, script_args):
-    script = tmp_path / "companion.py"
-    script.write_text(script_body)
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env["PYTHONPATH"] = repo_root + os.pathsep + env.get("PYTHONPATH", "")
-    return subprocess.run(
-        [sys.executable, "-m", "paddle_tpu.distributed.launch",
-         "--log_dir", str(tmp_path / "log")] + extra_args +
-        [str(script)] + script_args,
-        env=env, cwd=os.path.dirname(os.path.dirname(__file__)),
-        capture_output=True, text=True, timeout=240)
-
-
 def test_refuses_several_children_on_a_tpu_host(monkeypatch):
     """A chip belongs to one process at a time and the launcher does not
     partition chips: more than one child per TPU host is refused with a
@@ -58,7 +41,7 @@ def test_refuses_several_children_on_a_tpu_host(monkeypatch):
 
 class TestLaunchCLI:
     def test_two_proc_env_contract_and_logs(self, tmp_path):
-        r = _run_launch(tmp_path, COMPANION, ["--nproc_per_node", "2"],
+        r = run_launch(tmp_path, COMPANION, ["--nproc_per_node", "2"],
                         [str(tmp_path)])
         assert r.returncode == 0, r.stderr
         assert (tmp_path / "done.0").exists()
@@ -67,14 +50,14 @@ class TestLaunchCLI:
         assert "ok" in (tmp_path / "log" / "workerlog.1").read_text()
 
     def test_max_restart_retries_failed_pod(self, tmp_path):
-        r = _run_launch(tmp_path, FLAKY,
+        r = run_launch(tmp_path, FLAKY,
                         ["--nproc_per_node", "1", "--max_restart", "2"],
                         [str(tmp_path)])
         assert r.returncode == 0, r.stderr
         assert (tmp_path / "attempts").read_text() == "2"
 
     def test_failure_propagates_exit_code(self, tmp_path):
-        r = _run_launch(tmp_path, "import sys; sys.exit(3)\n",
+        r = run_launch(tmp_path, "import sys; sys.exit(3)\n",
                         ["--nproc_per_node", "1"], [])
         assert r.returncode == 3
 
@@ -146,14 +129,14 @@ class TestFaultToleranceResume:
         ref_dir = tmp_path / "oracle"
         int_dir.mkdir(), ref_dir.mkdir()
 
-        r = _run_launch(tmp_path, FT_TRAIN,
+        r = run_launch(tmp_path, FT_TRAIN,
                         ["--nproc_per_node", "1", "--max_restart", "1"],
                         [str(int_dir), "4"])
         assert r.returncode == 0, r.stderr
         assert (int_dir / "died").exists()          # it really crashed
         assert "restarting" in r.stderr             # launcher relaunched it
 
-        r2 = _run_launch(tmp_path, FT_TRAIN,
+        r2 = run_launch(tmp_path, FT_TRAIN,
                          ["--nproc_per_node", "1"], [str(ref_dir), "-1"])
         assert r2.returncode == 0, r2.stderr
 
@@ -165,7 +148,7 @@ class TestFaultToleranceResume:
     def test_no_restart_budget_fails(self, tmp_path):
         d = tmp_path / "nobudget"
         d.mkdir()
-        r = _run_launch(tmp_path, FT_TRAIN, ["--nproc_per_node", "1"],
+        r = run_launch(tmp_path, FT_TRAIN, ["--nproc_per_node", "1"],
                         [str(d), "2"])
         assert r.returncode == 17                   # crash surfaces
 
@@ -231,7 +214,7 @@ print("rank", rank, "collectives ok")
 
 class TestMultiProcessCollectives:
     def test_world2_eager_collectives(self, tmp_path):
-        r = _run_launch(tmp_path, MP_COLLECTIVES,
+        r = run_launch(tmp_path, MP_COLLECTIVES,
                         ["--nproc_per_node", "2"], [str(tmp_path)])
         assert r.returncode == 0, (r.stdout, r.stderr)
         assert (tmp_path / "ok.0").exists() and (tmp_path / "ok.1").exists()
@@ -283,7 +266,7 @@ print("elastic scale-down observed")
 
 class TestElasticEndToEnd:
     def test_scale_down_triggers_restart(self, tmp_path):
-        r = _run_launch(tmp_path, ELASTIC_WORKER,
+        r = run_launch(tmp_path, ELASTIC_WORKER,
                         ["--nproc_per_node", "2"], [str(tmp_path)])
         assert r.returncode == 0, (r.stdout, r.stderr)
         assert (tmp_path / "left.1").exists()
@@ -371,7 +354,7 @@ class TestWorld4LaunchTrainResume:
         and match the single-process full-batch oracle exactly."""
         d = tmp_path / "dp4"
         d.mkdir()
-        r = _run_launch(tmp_path, DP4_TRAIN,
+        r = run_launch(tmp_path, DP4_TRAIN,
                         ["--nproc_per_node", "4", "--max_restart", "1"],
                         [str(d), "3"])
         assert r.returncode == 0, (r.stdout, r.stderr)
@@ -458,7 +441,7 @@ print("elastic 4-worker scale-down observed")
 
 class TestElastic4:
     def test_four_worker_scale_down(self, tmp_path):
-        r = _run_launch(tmp_path, ELASTIC4_WORKER,
+        r = run_launch(tmp_path, ELASTIC4_WORKER,
                         ["--nproc_per_node", "4"], [str(tmp_path)])
         assert r.returncode == 0, (r.stdout, r.stderr)
         assert (tmp_path / "left.3").exists()

@@ -39,7 +39,6 @@ import pytest
 
 import paddle_tpu as paddle
 from paddle_tpu.incubate.nn import FusedMultiTransformer
-from paddle_tpu.inference.generation import FusedDecoder
 from paddle_tpu.inference.serving import AdmissionFull, ServingEngine
 from paddle_tpu.inference.telemetry import (DEFAULT_QOS_SHARES,
                                             QOS_CLASSES, QOS_DEFAULT)
@@ -47,6 +46,7 @@ from paddle_tpu.nn.layer.common import Embedding, Linear
 from paddle_tpu.serving_cluster import Gateway, LocalReplica, Router
 from paddle_tpu.testing import fault
 from paddle_tpu.testing.fault import FaultInjected
+from paddle_tpu.testing.oracle import sequential_tokens
 
 V, E, H, FF, L = 97, 32, 4, 64, 2
 WAIT_S = 120                              # bound on every drive loop
@@ -70,11 +70,8 @@ def _engine(fmt, embed, head, **kw):
 
 
 def _oracle(fmt, embed, head, prompt, max_new):
-    dec = FusedDecoder(fmt, embed, head, max_seq_len=128)
-    out = dec.generate(
-        paddle.to_tensor(np.asarray(prompt, np.int32)[None]),
-        max_new_tokens=max_new)
-    return [int(t) for t in np.asarray(out._data)[0, len(prompt):]]
+    return sequential_tokens(fmt, embed, head, prompt,
+                             max_new_tokens=max_new).tolist()
 
 
 def _prompt(n=10, seed=3):

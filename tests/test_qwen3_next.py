@@ -1,10 +1,10 @@
-"""Qwen3-Next on the CPU at a small size (hidden 64, one period of three
-Gated DeltaNet layers and one gated attention layer, 8 experts top-2,
-vocabulary 256): the program against the plain float32 reference
-(``benchmark/reference/qwen3_next.py``, which shares no code with it) on
-seeded weights; the chunked delta rule against the token-by-token
-recurrence; the expert layer's shares against the uncut layer; the compiled
-training step."""
+"""Qwen3-Next's parts on the CPU at a small size: the chunked delta rule
+against the token-by-token recurrence of the plain float32 reference
+(``benchmark/reference/qwen3_next.py``, which shares no code with the
+program); the causal convolution; the mixer against its former layout; the
+expert layer's shares against the uncut layer. The whole model and its
+compiled step are ``tests/test_qwen3_next_model.py``'s, so that two workers
+share what was one chain."""
 import os
 import sys
 
@@ -19,102 +19,11 @@ if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
 import paddle_tpu as paddle                                     # noqa: E402
-from benchmark.models import qwen3_next_train as family         # noqa: E402
 from benchmark.reference import qwen3_next as ref               # noqa: E402
 from paddle_tpu.incubate.distributed.models.moe import (        # noqa: E402
     DroplessMoELayer, routing_stats)
 from paddle_tpu.models.qwen3_next import qwen3_next_tiny        # noqa: E402
 from paddle_tpu.nn import functional as F                       # noqa: E402
-
-VOCAB, BATCH, SEQ = 256, 2, 40      # 40 tokens: two and a half chunks of 16
-
-
-def _tokens(seed=0):
-    ids = np.random.default_rng(seed).integers(
-        0, VOCAB, (BATCH, SEQ + 1), dtype=np.int32)
-    return ids[:, :-1], ids[:, 1:]
-
-
-def _reference(model, x, y):
-    """(logits [B, T, V], mean loss, gradients as the reference's tree)."""
-    w = family.reference_weights(model)
-    leaves, tree = jax.tree_util.tree_flatten(w)
-    real = [i for i, a in enumerate(leaves)
-            if hasattr(a, "dtype") and jnp.issubdtype(a.dtype, jnp.floating)]
-
-    def loss(values):
-        full = list(leaves)
-        for i, v in zip(real, values):
-            full[i] = v
-        w_ = jax.tree_util.tree_unflatten(tree, full)
-        return jnp.mean(jnp.stack([ref.loss(w_, x[i], y[i])
-                                   for i in range(x.shape[0])]))
-    values = [jnp.asarray(leaves[i], jnp.float32) for i in real]
-    value, grads = jax.value_and_grad(loss)(values)
-    full = [None] * len(leaves)
-    for i, g in zip(real, grads):
-        full[i] = g
-    logits = np.stack([np.asarray(ref.logits(w, x[i]))
-                       for i in range(x.shape[0])])
-    return logits, float(value), jax.tree_util.tree_unflatten(tree, full)
-
-
-def _program_grads(model):
-    """The program's parameter gradients, arranged like the reference's
-    weights: ``reference_weights`` is a linear rearrangement, so it maps
-    gradients as it maps weights."""
-    saved = [(p, p._data) for p in model.parameters()]
-    for p, _ in saved:
-        p._data = p.grad._data
-    try:
-        return family.reference_weights(model)
-    finally:
-        for p, a in saved:
-            p._data = a
-
-
-# float32 against float32 differs by summation order alone (the chunked rule
-# against the recurrence, one fused projection against three, a grouped
-# product against a masked loop): 1e-4 of the largest value is 100 x what
-# those leave at this size, and a wrong term (a missing decay, a wrong head
-# mapping, an unnormalised weight) misses it by orders of magnitude.
-# bf16 rounds every activation at 2**-9, and the benchmark's runner uses the
-# same 0.05 of the largest logit on the chip. A bf16 router also FLIPS a
-# token's last choice where two experts' probabilities are closer than the
-# rounding, and that token's gradient then goes to another expert: bf16
-# gradients are held to 0.3 of their tensor's norm (measured: up to 0.14 on
-# the experts, 0.24 on a router, 0.03 elsewhere), float32 ones to 1e-4 of
-# their tensor's largest entry.
-@pytest.mark.parametrize("dtype,tol,grad_tol", [("float32", 1e-4, 1e-4),
-                                                ("bfloat16", 0.05, 0.3)])
-def test_logits_loss_and_gradients_match_the_reference(dtype, tol, grad_tol):
-    paddle.seed(11)
-    model = qwen3_next_tiny(vocab_size=VOCAB, experts_held=[0, 1, 2, 5])
-    if dtype == "bfloat16":
-        model.bfloat16()
-    x, y = _tokens()
-    want_logits, want_loss, want_grads = _reference(model, x, y)
-    loss = model(paddle.to_tensor(x), labels=paddle.to_tensor(y))
-    loss.backward()
-    got_grads = _program_grads(model)
-    model.eval()
-    with paddle.no_grad():
-        got_logits = np.asarray(model(paddle.to_tensor(x))._data, np.float32)
-    scale = np.abs(want_logits).max()
-    assert np.abs(got_logits - want_logits).max() <= tol * scale
-    assert abs(float(loss._data) - want_loss) <= tol * want_loss
-
-    flat_got = dict(jax.tree_util.tree_leaves_with_path(got_grads))
-    checked = 0
-    for path, want in jax.tree_util.tree_leaves_with_path(want_grads):
-        diff = np.asarray(flat_got[path], np.float32) - np.asarray(want)
-        size = np.abs if dtype == "float32" else np.linalg.norm
-        assert np.max(size(diff)) <= grad_tol * max(
-            np.max(size(np.asarray(want))), 1e-6), jax.tree_util.keystr(path)
-        checked += 1
-    # every parameter, the fused ones in their parts: [q|k|v|z] and [b|a] of
-    # three layers, [query|gate] of one, [gate|up] twice in each of four
-    assert checked == len(list(model.parameters())) + 3 * 4 + 1 + 4 * 2
 
 
 def _recurrence(q, k, v, g, beta):
@@ -146,16 +55,23 @@ def test_chunked_delta_rule_matches_the_recurrence(seq, chunk):
         kn = jnp.repeat(ref.l2norm(k), hv // hk, axis=2)
         return _recurrence(qn, kn, v, g, beta)
 
-    want, vjp = jax.vjp(recurrent, q, k, v, g, beta)
-    want_grads = vjp(jnp.asarray(cot))
-    ts = [paddle.to_tensor(a) for a in (q, k, v, g, beta)]
-    for t in ts:
-        t.stop_gradient = False
-    got = F.chunk_gated_delta_rule(*ts, chunk_size=chunk)
-    (got * paddle.to_tensor(cot)).sum().backward()
+    @jax.jit        # each side one compiled program: a case is its compiles
+    def reference(cot, *a):
+        out, vjp = jax.vjp(recurrent, *a)
+        return out, vjp(cot)
+    want, want_grads = reference(cot, q, k, v, g, beta)
+
+    @paddle.jit.to_static
+    def chunked(cot, *ts):
+        for t in ts:
+            t.stop_gradient = False
+        out = F.chunk_gated_delta_rule(*ts, chunk_size=chunk)
+        (out * cot).sum().backward()
+        return out, [t.grad for t in ts]
+    got, got_grads = chunked(*map(paddle.to_tensor, (cot, q, k, v, g, beta)))
     np.testing.assert_allclose(np.asarray(got._data), want, atol=2e-5)
-    for t, wg in zip(ts, want_grads):
-        np.testing.assert_allclose(np.asarray(t.grad._data), wg,
+    for t, wg in zip(got_grads, want_grads):
+        np.testing.assert_allclose(np.asarray(t._data), wg,
                                    atol=2e-5 * max(1.0, np.abs(wg).max()))
 
 
@@ -301,16 +217,21 @@ def _former_mixer(m, x, w_qkvz, w_ba, conv_w, a_log, dt_bias, norm_w, w_out):
     return (h * norm_w * jax.nn.silu(z)).reshape(b, s, value) @ w_out
 
 
+@pytest.fixture(scope="module")
+def mixer():
+    paddle.seed(9)
+    return qwen3_next_tiny(num_layers=1).model.layers[0].mixer
+
+
 @pytest.mark.parametrize("seq", [300, 296])
-def test_the_mixer_agrees_with_its_former_layout(seq):
+def test_the_mixer_agrees_with_its_former_layout(seq, mixer):
     """The mixer's result and every gradient on the layout it keeps now
     (projections a consumer, heads moved as whole tiles, the norms inside
     the blocks) against the former path, in float32, where each key head
     serves two value heads and the sequence fills one block of 16 chunks
     of 16 and part of a second; 300 is no multiple of 8 either, so the
-    gated norm takes its one-row view, 296 the tiles'."""
-    paddle.seed(9)
-    mixer = qwen3_next_tiny(num_layers=1).model.layers[0].mixer
+    gated norm takes its one-row view, 296 the tiles'. Each side is one
+    compiled program: eager, the two were 12 of this test's 14 s."""
     assert mixer.hv // mixer.hk == 2
     rng = np.random.default_rng(seq)
     x = rng.standard_normal((2, seq, 64)).astype(np.float32)
@@ -318,17 +239,24 @@ def test_the_mixer_agrees_with_its_former_layout(seq):
     params = [mixer.in_proj_qkvz.weight, mixer.in_proj_ba.weight,
               mixer.conv_weight, mixer.A_log, mixer.dt_bias,
               mixer.norm_weight, mixer.out_proj.weight]
-    want, vjp = jax.vjp(lambda x_, *ws: _former_mixer(mixer, x_, *ws),
-                        jnp.asarray(x), *[p._data for p in params])
-    want_grads = vjp(jnp.asarray(cot))
-    xt = paddle.to_tensor(x)
-    xt.stop_gradient = False
-    got = mixer(xt)
-    (got * paddle.to_tensor(cot)).sum().backward()
+
+    @jax.jit
+    def former(x_, cot_, *ws):
+        out, vjp = jax.vjp(lambda *a: _former_mixer(mixer, *a), x_, *ws)
+        return out, vjp(cot_)
+    want, want_grads = former(x, cot, *[p._data for p in params])
+
+    @paddle.jit.to_static
+    def now(xt, cot_):
+        xt.stop_gradient = False
+        out = mixer(xt)
+        (out * cot_).sum().backward()
+        return out, [xt.grad] + [p.grad for p in params]
+    got, got_grads = now(paddle.to_tensor(x), paddle.to_tensor(cot))
     np.testing.assert_allclose(np.asarray(got._data), want,
                                atol=1e-5 * np.abs(want).max())
-    for t, g in zip([xt] + params, want_grads):
-        np.testing.assert_allclose(np.asarray(t.grad._data), g,
+    for t, g in zip(got_grads, want_grads):
+        np.testing.assert_allclose(np.asarray(t._data), g,
                                    atol=2e-5 * max(np.abs(g).max(), 1e-6))
 
 
@@ -506,80 +434,3 @@ def test_routing_counts_reach_the_runtime_exposition():
                       ("paddle_moe_pairs_dropped_total", "pairs_dropped")):
         assert f"{name} {stats[key]}" in text
     assert stats["pairs"] >= 8 * K
-
-
-# ---------------------------------------------------------- compiled step
-@pytest.mark.parametrize("recompute", [False, True])
-def test_to_static_step_trains_donates_and_does_not_retrace(recompute):
-    from paddle_tpu.inference import telemetry
-    paddle.seed(21)
-    model = qwen3_next_tiny(vocab_size=VOCAB, experts_held=[0, 1, 2, 3],
-                            recompute=recompute)
-    model.bfloat16()
-    opt = paddle.optimizer.AdamW(learning_rate=3e-3,
-                                 parameters=model.parameters(),
-                                 multi_precision=True)
-
-    def step(x, y):
-        loss = model(x, labels=y)
-        loss.backward()
-        opt.step()
-        opt.clear_grad()
-        return loss
-    step = paddle.jit.to_static(step)
-    x, y = (paddle.to_tensor(a) for a in _tokens(5))
-    compiles = telemetry.runtime_counter("paddle_to_static_compiles_total")
-    def counts():      # of this model's four layers, the newest alive
-        mine = routing_stats()["layers"][-4:]
-        return {k: sum(r[k] for r in mine) for k in ("pairs",
-                                                     "pairs_dropped")}
-    before = counts()
-    losses = [float(np.asarray(step(x, y)._data, np.float32))
-              for _ in range(5)]
-    assert losses[-1] < losses[2] < losses[0]           # the same batch
-    # two traces (the optimizer's slots appear in the first), then none
-    assert telemetry.runtime_counter(
-        "paddle_to_static_compiles_total") - compiles == 2
-    steady = paddle.jit.call_timeline()[-3:]
-    assert all(not r["fresh"] and r["kept"] == 0 and r["donated"] > 0
-               for r in steady)
-    # the counters are state of the step: updated on the device, once a
-    # step whether or not its forward is replayed by recompute
-    after = counts()
-    assert after["pairs"] - before["pairs"] == \
-        5 * 4 * BATCH * SEQ * model.config.num_experts_per_tok
-    assert after["pairs_dropped"] == before["pairs_dropped"]
-
-
-def test_recompute_under_to_static_leaves_the_update_as_it_was():
-    """The compiled step replays every layer behind an optimization barrier
-    (``fleet.utils.recompute``); the runner's comparison sees the eval-mode
-    forward only, so HERE the replayed step is held to the plain one: the
-    same float32 weights and batch give the same losses and, after three
-    AdamW steps, the same parameters."""
-    runs = []
-    for recompute in (False, True):
-        paddle.seed(33)
-        model = qwen3_next_tiny(vocab_size=VOCAB, experts_held=[0, 1, 2, 3],
-                                recompute=recompute)
-        opt = paddle.optimizer.AdamW(learning_rate=1e-3,
-                                     parameters=model.parameters())
-
-        def step(x, y, model=model, opt=opt):
-            loss = model(x, labels=y)
-            loss.backward()
-            opt.step()
-            opt.clear_grad()
-            return loss
-        step = paddle.jit.to_static(step)
-        x, y = (paddle.to_tensor(a) for a in _tokens(6))
-        losses = [float(np.asarray(step(x, y)._data)) for _ in range(3)]
-        runs.append((losses, [np.asarray(p._data)
-                              for p in model.parameters()]))
-    (plain_losses, plain), (losses, replayed) = runs
-    np.testing.assert_allclose(losses, plain_losses, rtol=1e-6)
-    # AdamW moves a parameter by about the learning rate a step whatever
-    # its gradient's size, so where a gradient is near 0 its last bits show:
-    # 5e-5 of the 3e-3 a wrong gradient's sign would make
-    for a, b in zip(replayed, plain):
-        np.testing.assert_allclose(a, b, atol=5e-5)

@@ -2,7 +2,6 @@
 fleet.utils.fs parity tests.
 Reference: python/paddle/distributed/rpc/, fleet/utils/fs.py."""
 import os
-import subprocess
 import sys
 import textwrap
 
@@ -12,6 +11,7 @@ import pytest
 import paddle_tpu as paddle
 from paddle_tpu.distributed.fleet.utils.fs import (ExecuteError, HDFSClient,
                                                    LocalFS)
+from paddle_tpu.testing.child import REPO_ROOT, Child, cpu_env
 
 _RPC_COMPANION = textwrap.dedent("""
     import os, sys, time
@@ -68,22 +68,20 @@ def _free_port():
 
 
 def test_rpc_two_process_roundtrip(tmp_path):
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     script = tmp_path / "rpc_worker.py"
-    script.write_text(_RPC_COMPANION.format(repo=repo))
-    port = _free_port()
-    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
-    p0 = subprocess.Popen([sys.executable, str(script), "0", str(port)],
-                          stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
-                          text=True, env=env)
-    p1 = subprocess.Popen([sys.executable, str(script), "1", str(port)],
-                          stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
-                          text=True, env=env)
-    out1, _ = p1.communicate(timeout=120)
-    out0, _ = p0.communicate(timeout=120)
-    assert p1.returncode == 0, f"client failed:\n{out1}\nserver:\n{out0}"
+    script.write_text(_RPC_COMPANION.format(repo=REPO_ROOT))
+    port = str(_free_port())
+    with Child([sys.executable, str(script), "0", port],
+               env=cpu_env()) as server, \
+            Child([sys.executable, str(script), "1", port],
+                  env=cpu_env()) as client:
+        client_code = client.wait(120)
+        server_code = server.wait(120)
+        out0 = server.stdout + server.stderr
+        out1 = client.stdout + client.stderr
+    assert client_code == 0, f"client failed:\n{out1}\nserver:\n{out0}"
     assert "RPC_OK" in out1
-    assert p0.returncode == 0, f"server failed:\n{out0}"
+    assert server_code == 0, f"server failed:\n{out0}"
 
 
 def test_localfs_contract(tmp_path):

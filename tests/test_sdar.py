@@ -62,17 +62,33 @@ def _reference(model, x, masked, t):
     return logits, float(value), jax.tree_util.tree_unflatten(tree, full)
 
 
-def _program_grads(model):
-    """The program's parameter gradients, arranged like the reference's
-    weights (``reference_weights`` is a linear rearrangement)."""
-    saved = [(p, p._data) for p in model.parameters()]
-    for p, _ in saved:
-        p._data = p.grad._data
+def _program(model, x, masked, t):
+    """(loss, gradients arranged like the reference's weights, eval-mode
+    logits of the noisy half): forward and backward as ONE compiled program,
+    as the benchmark's step has them, and not some hundreds of
+    one-operation compiles. ``reference_weights`` is a linear rearrangement,
+    so it maps gradients as it maps weights."""
+    params = list(model.parameters())
+
+    def loss_and_grads(ids):
+        loss = model(ids, masked, t, labels=ids)
+        loss.backward()
+        return loss, [p.grad for p in params]
+    ids = paddle.to_tensor(x)
+    loss, grads = paddle.jit.to_static(loss_and_grads)(ids)
+    saved = [p._data for p in params]
+    for p, g in zip(params, grads):
+        p._data = g._data
     try:
-        return family.reference_weights(model)
+        arranged = family.reference_weights(model)
     finally:
-        for p, a in saved:
+        for p, a in zip(params, saved):
             p._data = a
+    model.eval()
+    with paddle.no_grad():
+        logits = paddle.jit.to_static(lambda ids: model(ids, masked, t))(ids)
+    return (float(loss._data), arranged,
+            np.asarray(logits._data, np.float32))
 
 
 # The tolerances and their reasons are ``tests/test_qwen3_next.py``'s: float32
@@ -91,17 +107,11 @@ def test_logits_loss_and_gradients_match_the_reference(dtype, tol, grad_tol):
     x = _tokens()
     masked, t = train_blockdiff.noise(CFG, 5, BATCH, SEQ)
     want_logits, want_loss, want_grads = _reference(model, x, masked, t)
-    ids = paddle.to_tensor(x)
-    loss = model(ids, masked, t, labels=ids)
-    loss.backward()
-    got_grads = _program_grads(model)
-    model.eval()
-    with paddle.no_grad():
-        got_logits = np.asarray(model(ids, masked, t)._data, np.float32)
+    got_loss, got_grads, got_logits = _program(model, x, masked, t)
     assert got_logits.shape == (BATCH, SEQ, VOCAB)      # the noisy half only
     scale = np.abs(want_logits).max()
     assert np.abs(got_logits - want_logits).max() <= tol * scale
-    assert abs(float(loss._data) - want_loss) <= tol * want_loss
+    assert abs(got_loss - want_loss) <= tol * want_loss
 
     flat_got = dict(jax.tree_util.tree_leaves_with_path(got_grads))
     checked = 0
@@ -121,9 +131,12 @@ def test_losses_reports_the_bound_and_the_masked_mean():
     x = _tokens(3)
     masked, t = train_blockdiff.noise(CFG, 9, BATCH, SEQ)
     ids = paddle.to_tensor(x)
-    bound, plain = model.losses(ids, masked, t)
-    assert float(bound._data) == pytest.approx(
-        float(model(ids, masked, t, labels=ids)._data))
+    with paddle.no_grad():      # each a compiled program, not eager
+        bound, plain = paddle.jit.to_static(
+            lambda ids: model.losses(ids, masked, t))(ids)
+        again = paddle.jit.to_static(
+            lambda ids: model(ids, masked, t, labels=ids))(ids)
+    assert float(bound._data) == pytest.approx(float(again._data))
     w = family.reference_weights(model)
     ce = []
     for i in range(BATCH):
@@ -180,9 +193,10 @@ def test_the_rule_leaks_nothing_forward():
     t = np.full((1, SEQ // BLOCK), 0.5, np.float32)
     other = x.copy()
     other[0, i] = (x[0, i] + 7) % (VOCAB - 1)
-    with paddle.no_grad():
-        a = np.asarray(model(paddle.to_tensor(x), masked, t)._data)
-        b = np.asarray(model(paddle.to_tensor(other), masked, t)._data)
+    forward = paddle.jit.to_static(lambda ids: model(ids, masked, t))
+    with paddle.no_grad():      # one compiled program, called twice
+        a = np.asarray(forward(paddle.to_tensor(x))._data)
+        b = np.asarray(forward(paddle.to_tensor(other))._data)
     upto = (k + 1) * BLOCK
     np.testing.assert_array_equal(a[0, :upto], b[0, :upto])
     later = np.abs(a[0, upto:upto + BLOCK] - b[0, upto:upto + BLOCK]).max()
@@ -205,11 +219,14 @@ def test_the_first_loss_is_ln_vocabulary_within_its_spread():
     model.eval()
     rng = np.random.default_rng(0)
     bounds, plains = [], []
+    # one compiled program, called 20 times: the key is state of the step,
+    # so every call draws its own noise from next_key()
+    losses = paddle.jit.to_static(model.losses)
     with paddle.no_grad():
         for _ in range(20):
             ids = paddle.to_tensor(rng.integers(0, VOCAB, (rows, seq),
                                                 dtype=np.int32))
-            bound, plain = model.losses(ids)        # noise from next_key()
+            bound, plain = losses(ids)
             bounds.append(float(bound._data))
             plains.append(float(plain._data))
     centre = math.log(VOCAB) + 0.5 * 64 * 0.02 ** 2

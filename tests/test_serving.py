@@ -18,9 +18,9 @@ import paddle_tpu as paddle
 import jax.numpy as jnp
 
 from paddle_tpu.incubate.nn import FusedMultiTransformer
-from paddle_tpu.inference.generation import FusedDecoder
 from paddle_tpu.inference.serving import ServingEngine
 from paddle_tpu.nn.layer.common import Embedding, Linear
+from paddle_tpu.testing.oracle import sequential_tokens
 
 V, E, H, FF, L = 97, 32, 4, 64, 2
 
@@ -37,13 +37,6 @@ def _model(seed=3):
 
 def _prompt(rng, n):
     return rng.randint(1, V, (n,)).astype(np.int32)
-
-
-def _oracle(fmt, embed, head, prompt, use_rotary=False, **kw):
-    dec = FusedDecoder(fmt, embed, head, max_seq_len=128,
-                       use_rotary=use_rotary)
-    out = dec.generate(paddle.to_tensor(prompt[None]), **kw)
-    return np.asarray(out._data)[0, prompt.size:]
 
 
 class TestServingParity:
@@ -67,7 +60,7 @@ class TestServingParity:
         rids = [eng.submit(p, max_new_tokens=m) for p, m in reqs]
         eng.run()
         for (p, m), rid in zip(reqs, rids):
-            want = _oracle(fmt, embed, head, p, use_rotary=rotary,
+            want = sequential_tokens(fmt, embed, head, p, use_rotary=rotary,
                            max_new_tokens=m)
             np.testing.assert_array_equal(
                 eng.results[rid]["tokens"], want)
@@ -92,7 +85,7 @@ class TestServingParity:
         rids = [eng.submit(p, **kw) for p, kw in reqs]
         eng.run()
         for (p, kw), rid in zip(reqs, rids):
-            want = _oracle(fmt, embed, head, p, **kw)
+            want = sequential_tokens(fmt, embed, head, p, **kw)
             np.testing.assert_array_equal(
                 eng.results[rid]["tokens"], want)
 
@@ -106,7 +99,7 @@ class TestServingParity:
         rids = [eng.submit(p, max_new_tokens=m) for p, m in reqs]
         eng.run()
         for (p, m), rid in zip(reqs, rids):
-            want = _oracle(fmt, embed, head, p, max_new_tokens=m)
+            want = sequential_tokens(fmt, embed, head, p, max_new_tokens=m)
             np.testing.assert_array_equal(
                 eng.results[rid]["tokens"], want)
 

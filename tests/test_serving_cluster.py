@@ -33,6 +33,7 @@ from paddle_tpu.nn.layer.common import Embedding, Linear
 from paddle_tpu.serving_cluster import (Gateway, HashRing, LocalReplica,
                                         NoReplicaError, Router)
 from paddle_tpu.serving_cluster.replica import ReplicaError
+from paddle_tpu.testing.oracle import sequential_tokens
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 V, E, H, FF, L = 97, 32, 4, 64, 2
@@ -57,10 +58,8 @@ def _engine(fmt, embed, head, **kw):
 
 
 def _oracle(fmt, embed, head, prompt, max_new):
-    dec = FusedDecoder(fmt, embed, head, max_seq_len=128)
-    out = dec.generate(paddle.to_tensor(np.asarray(prompt, np.int32)[None]),
-                       max_new_tokens=max_new)
-    return [int(t) for t in np.asarray(out._data)[0, len(prompt):]]
+    return sequential_tokens(fmt, embed, head, prompt,
+                             max_new_tokens=max_new).tolist()
 
 
 # =====================================================================
@@ -1113,12 +1112,15 @@ class TestRoleAutoscaler:
 # RpcReplica: the same interface across a process boundary
 # =====================================================================
 class TestRpcReplica:
-    def test_rpc_replica_parity_and_backpressure(self):
+    def test_rpc_replica_parity_and_backpressure(self, monkeypatch):
         from paddle_tpu.core.native import load_native
         if load_native() is None:
             pytest.skip("native runtime unavailable")
         from paddle_tpu.distributed import rpc
-        from paddle_tpu.serving_cluster import RpcReplica, serve_engine
+        from paddle_tpu.serving_cluster import (RpcReplica, replica,
+                                                serve_engine)
+        # the served replica is the PROCESS's: this one's goes with the test
+        monkeypatch.setattr(replica, "_WORKER", [None])
 
         fmt, embed, head = _model()
         rpc.init_rpc("cluster_worker0", rank=0, world_size=1,
@@ -1182,32 +1184,20 @@ def test_supervised_worker_gang_e2e(tmp_path):
     worker process, rendezvouses it over rpc, fronts it with an
     RpcReplica, and serves a completion through the gateway — the
     promoted replacement for hand-rolled init_rpc glue."""
-    import re
     import signal
-    import subprocess
     import sys
     import urllib.request
 
     from paddle_tpu.core.native import load_native
+    from paddle_tpu.testing.child import Child, cpu_env
     if load_native() is None:
         pytest.skip("native runtime unavailable")
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    p = subprocess.Popen(
-        [sys.executable, "-m", "paddle_tpu.serving_cluster",
-         "--workers", "1", "--port", "0",
-         "--log-dir", str(tmp_path / "log")],
-        env=env, stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
-        text=True)
-    try:
-        port = None
-        deadline = time.monotonic() + WAIT_S
-        for line in p.stdout:
-            m = re.search(r"http://127\.0\.0\.1:(\d+)", line)
-            if m:
-                port = int(m.group(1))
-                break
-            assert time.monotonic() < deadline, "supervisor never ready"
-        assert port is not None
+    with Child([sys.executable, "-m", "paddle_tpu.serving_cluster",
+                "--workers", "1", "--port", "0",
+                "--log-dir", str(tmp_path / "log")],
+               env=cpu_env()) as supervisor:
+        port = int(supervisor.wait_for(r"http://127\.0\.0\.1:(\d+)",
+                                       timeout=WAIT_S).group(1))
         req = urllib.request.Request(
             f"http://127.0.0.1:{port}/v1/completions",
             data=json.dumps({"prompt": [5, 9, 2, 41],
@@ -1231,14 +1221,7 @@ def test_supervised_worker_gang_e2e(tmp_path):
             max_new_tokens=8)
         want = [int(t) for t in np.asarray(out._data)[0, 4:]]
         assert toks == want
-    finally:
-        p.send_signal(signal.SIGINT)
-        try:
-            rc = p.wait(timeout=30)
-        except subprocess.TimeoutExpired:
-            p.kill()
-            rc = p.wait()
-    assert rc == 0
+        assert supervisor.stop(signal.SIGINT, timeout=30) == 0
 
 
 # =====================================================================
