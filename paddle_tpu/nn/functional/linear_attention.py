@@ -25,17 +25,17 @@ operands in ``matmul_dtype`` (the dtype of ``v`` unless stated; bf16 in a
 bf16 model) and accumulate in float32. The convolution's backward pass is
 written out (``_causal_conv_bwd``).
 
-Two forms of one arithmetic, chosen per call from backend, shapes, dtypes
-and mesh by ``ops/pallas/gated_delta_rule.is_supported`` (on a TPU, chunks
-of 64, heads of a multiple of 128, no multi-device mesh): the KERNEL
-``gdn_chunk_rule_fwd``, which keeps a chunk's matrices and the state in
-VMEM, and the COMPOSITE ``_chunk_rule`` below, batched XLA products over
-blocks of 16 chunks. The composite's backward pass is JAX's own through its
-checkpointed scan. The kernel's is that same program (``_kernel_rule_bwd``):
-the kernel saves the state at each block's start, and a reverse scan
-replays each block through the composite's ``block_of_chunks`` and carries
-``dS``. So ``block_of_chunks`` is the one definition of the rule outside
-the kernel, and every gradient is the composite's.
+Two forms of one arithmetic, chosen per call and for BOTH passes from
+backend, shapes, dtypes and mesh by
+``ops/pallas/gated_delta_rule.is_supported`` (on a TPU, chunks of 64, heads
+of a multiple of 128, no multi-device mesh): the KERNELS
+``gdn_chunk_rule_fwd`` and ``gdn_chunk_rule_bwd``, which keep a chunk's
+matrices, the state and its gradient in VMEM (the forward saves the state
+at each tile's start; the backward walks the tiles from the last, prepares
+a tile's chunks again and carries ``dS``), and the COMPOSITE ``_chunk_rule``
+below, batched XLA products over blocks of 16 chunks, whose backward pass is
+JAX's own through its checkpointed scan. ``block_of_chunks`` is the one
+definition of the rule outside the kernels.
 
 Layout. The TPU keeps the last two axes of an array in (8, 128) tiles: 8
 rows in the sublanes, 128 columns in the lanes. A ``[B, T, H * 128]``
@@ -54,8 +54,8 @@ all. ``_chunk_rule`` forms its blocks that way and returns its result by
 the mirrored path; a caller that works on heads (the layer's gated norm)
 does the same with ``TILE_ROWS``. The kernel reads the same layout in
 place, through ``BlockSpec``s of ``(1, 16 chunks, heads * 128)``, and writes
-its result the same way: only the composite, and so the backward pass,
-still moves tiles.
+its result the same way, as the backward kernel does with the gradients:
+only the composite still moves tiles.
 """
 from __future__ import annotations
 
@@ -178,12 +178,12 @@ def _inverse_unit_lower(a):
 
 
 def _composite(q_shape, v_shape, out, chunk, mm):
-    """The rule's composite for these shapes, in the three pieces its two
-    users share: ``to_blocks(q, k, v, g, beta)`` (the operands a block of
-    ``nb`` chunks, [G, B, ...]), ``block_of_chunks(s, xs)`` (one block from
-    its start state ``s`` [B,hk,r,dk,dv]: the one definition of the rule
-    outside the kernel) and ``from_blocks(o)`` ([G,nb,B,hk,r,C,dv] ->
-    [B,T,hv,dv] in ``out``); then the shapes of ``s`` and of that ``o``."""
+    """The rule's composite for these shapes, in three pieces:
+    ``to_blocks(q, k, v, g, beta)`` (the operands a block of ``nb`` chunks,
+    [G, B, ...]), ``block_of_chunks(s, xs)`` (one block from its start state
+    ``s`` [B,hk,r,dk,dv]: the one definition of the rule outside the
+    kernels) and ``from_blocks(o)`` ([G,nb,B,hk,r,C,dv] -> [B,T,hv,dv] in
+    ``out``); then the shape of ``s``."""
     b, t, hk, dk = q_shape
     hv, dv = v_shape[2], v_shape[3]
     r = hv // hk                    # each key head serves r value heads
@@ -259,8 +259,7 @@ def _composite(q_shape, v_shape, out, chunk, mm):
         o = o.transpose(2, 0, 1, 4, 5, 3, 6).reshape(b, t + pad, hv, dv)
         return o[:, :t].astype(out)
 
-    return (to_blocks, block_of_chunks, from_blocks, (b, hk, r, dk, dv),
-            (n_blocks, nb, b, hk, r, chunk, dv))
+    return to_blocks, block_of_chunks, from_blocks, (b, hk, r, dk, dv)
 
 
 def _chunk_rule(q, k, v, g, beta, *, chunk, mm):
@@ -268,7 +267,7 @@ def _chunk_rule(q, k, v, g, beta, *, chunk, mm):
     backward pass keeps the state at each block's start and recomputes the
     block, so that the rule's working set is one block's and not the
     sequence's."""
-    to_blocks, block_of_chunks, from_blocks, state, _ = _composite(
+    to_blocks, block_of_chunks, from_blocks, state = _composite(
         q.shape, v.shape, v.dtype, chunk, mm)
     _, o = jax.lax.scan(jax.checkpoint(block_of_chunks),
                         jnp.zeros(state, _F32), to_blocks(q, k, v, g, beta))
@@ -277,11 +276,9 @@ def _chunk_rule(q, k, v, g, beta, *, chunk, mm):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
 def _kernel_rule(q, k, v, g, beta, mm):
-    """The rule with its forward as ``ops/pallas/gated_delta_rule``'s
-    kernel (chunks of 64). Its backward is the composite's own, as JAX
-    derives it from ``_chunk_rule``'s checkpointed scan: a reverse scan
-    over the blocks that replays each from its start state, which the
-    kernel saved, and carries ``dS``."""
+    """The rule as ``ops/pallas/gated_delta_rule``'s two kernels (chunks of
+    64): the forward saves the state at each tile's start, the backward
+    takes the five inputs, those states and ``o``'s cotangent."""
     return _kernel_rule_fwd(q, k, v, g, beta, mm)[0]
 
 
@@ -296,22 +293,9 @@ def _kernel_rule_fwd(q, k, v, g, beta, mm):
 
 
 def _kernel_rule_bwd(mm, res, do):
-    *inputs, states = res
-    q, v = inputs[0], inputs[2]
-    to_blocks, block_of_chunks, from_blocks, state, o_blocks = _composite(
-        q.shape, v.shape, v.dtype, gated_delta_rule.CHUNK, mm)
-    xs, blocks_vjp = jax.vjp(to_blocks, *inputs)
-    do, = jax.linear_transpose(
-        from_blocks, jax.ShapeDtypeStruct(o_blocks, _F32))(do)
-
-    def block(ds, saved):
-        s, x, do_ = saved
-        ds, dx = jax.vjp(block_of_chunks, s, x)[1]((ds, do_))
-        return ds, dx
-    _, dxs = jax.lax.scan(
-        block, jnp.zeros(state, _F32),
-        (states.reshape(states.shape[:1] + state), xs, do), reverse=True)
-    return blocks_vjp(dxs)
+    runtime_counter("paddle_gdn_rule_bwd_kernel_traces_total", 1)
+    return gated_delta_rule.gdn_chunk_rule_bwd(
+        *res, do, mm=mm, block_chunks=_BLOCK_CHUNKS)
 
 
 _kernel_rule.defvjp(_kernel_rule_fwd, _kernel_rule_bwd)
@@ -360,11 +344,13 @@ def chunk_gated_delta_rule(q, k, v, g, beta, chunk_size=64,
     uses it. The matrix products take their operands in
     ``matmul_dtype`` (default: ``v``'s dtype) and accumulate in float32; the
     result comes in ``v``'s dtype. 16 chunks at a time are prepared
-    together and recomputed in the backward pass: the working set is one
+    together and prepared again in the backward pass: the working set is one
     block's whatever ``T``. Takes the Pallas kernel where
     ``gated_delta_rule.is_supported`` says so and the composite elsewhere
     (the module docstring); ``paddle_gdn_rule_kernel_traces_total`` or
-    ``paddle_gdn_rule_composite_traces_total`` counts each trace."""
+    ``paddle_gdn_rule_composite_traces_total`` counts each trace, and
+    ``paddle_gdn_rule_bwd_kernel_traces_total`` each trace of the kernels'
+    backward."""
     if chunk_size < 8 or chunk_size & (chunk_size - 1):
         raise ValueError(f"chunk_gated_delta_rule: chunk_size {chunk_size} "
                          "is not a power of two >= 8")

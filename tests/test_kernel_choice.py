@@ -283,17 +283,23 @@ def test_delta_rule_dispatch_moves_its_counter(on, dk, chunk, mesh, kernel,
                                                monkeypatch):
     """``chunk_gated_delta_rule`` asks ``is_supported`` and nothing else;
     whichever it takes, the result is the rule's and one of the two
-    counters moves."""
+    counters moves. The one decision covers both passes: under a gradient
+    the kernel's shapes trace the backward kernel and move its counter,
+    the composite's shapes leave both alone."""
+    from paddle_tpu.inference.telemetry import runtime_counter
     from paddle_tpu.nn.functional import linear_attention as la
     from paddle_tpu.ops.pallas import gated_delta_rule as gdr
     monkeypatch.setattr(pallas, "_enabled", lambda: on)
     if mesh is not None:
         held = mesh()
         monkeypatch.setattr(la, "current_mesh", lambda: held)
-    calls = []
-    real = gdr.gdn_chunk_rule_fwd
-    monkeypatch.setattr(gdr, "gdn_chunk_rule_fwd", lambda *a, **kw: (
-        calls.append(a[0].shape), real(*a, **kw))[1])
+    calls = {"fwd": [], "bwd": []}
+    for which in calls:
+        real = getattr(gdr, f"gdn_chunk_rule_{which}")
+        monkeypatch.setattr(
+            gdr, f"gdn_chunk_rule_{which}",
+            lambda *a, _real=real, _seen=calls[which], **kw: (
+                _seen.append(a[0].shape), _real(*a, **kw))[1])
     rng = np.random.RandomState(2)
     q, k = (rng.randn(1, 64, 1, dk).astype(np.float32) for _ in range(2))
     v = rng.randn(1, 64, 2, 128).astype(np.float32)
@@ -301,14 +307,24 @@ def test_delta_rule_dispatch_moves_its_counter(on, dk, chunk, mesh, kernel,
     beta = rng.uniform(0, 1, (1, 64, 2)).astype(np.float32)
     arrays = (q, k, v, g, beta)
     before = _rule_traces()
-    out = F.chunk_gated_delta_rule(*(paddle.to_tensor(a) for a in arrays),
-                                   chunk_size=chunk)
+    backward = runtime_counter("paddle_gdn_rule_bwd_kernel_traces_total")
+    ts = [paddle.to_tensor(a) for a in arrays]
+    for t in ts:
+        t.stop_gradient = False
+    out = F.chunk_gated_delta_rule(*ts, chunk_size=chunk)
     moved = tuple(b - a for a, b in zip(before, _rule_traces()))
     assert moved == ((1, 0) if kernel else (0, 1))
-    assert bool(calls) is kernel
-    want = la._chunk_rule(*map(jnp.asarray, arrays), chunk=chunk,
-                          mm=jnp.float32)
+    assert bool(calls["fwd"]) is kernel
+    want, vjp = jax.vjp(lambda *a: la._chunk_rule(
+        *a, chunk=chunk, mm=jnp.float32), *map(jnp.asarray, arrays))
     np.testing.assert_allclose(np.asarray(out._data), want, atol=2e-6)
+    out.sum().backward()
+    assert (runtime_counter("paddle_gdn_rule_bwd_kernel_traces_total")
+            - backward) == int(kernel)
+    assert bool(calls["bwd"]) is kernel
+    for t, wg in zip(ts, vjp(jnp.ones_like(want))):
+        np.testing.assert_allclose(np.asarray(t.grad._data), wg,
+                                   atol=2e-5 * max(1.0, np.abs(wg).max()))
 
 
 # -------------------------------------------- the backward by block count
