@@ -53,8 +53,11 @@ def _param_sig(layer):
     by construction)."""
     params = tuple((tuple(p.shape), str(p.dtype))
                    for p in layer.parameters())
+    # ``_scope`` is the name the layer has in its parent (its index in the
+    # stack): where it sits, not what it computes
     cfg = tuple(sorted((k, str(v)) for k, v in vars(layer).items()
-                       if isinstance(v, (int, float, bool, str))))
+                       if isinstance(v, (int, float, bool, str))
+                       and k != "_scope"))
     fn = getattr(layer, "_fn", None)
     # cfg applies to PARAM-BEARING layers too: same class + same shapes but
     # a different behavior flag (e.g. act='relu' vs 'gelu') must not match,

@@ -41,9 +41,9 @@ from typing import Callable
 import jax
 
 from ....core.rng import get_rng_state, get_rng_tensor, set_rng_state
-from ....tensor.tensor import (KeptRegion, Parameter, Tensor, _TapeNode,
-                               _tape, enable_grad, is_grad_enabled, no_grad,
-                               persistent_tensors)
+from ....tensor.tensor import (PASS_REPLAY, KeptRegion, Parameter, Tensor,
+                               _TapeNode, _tape, enable_grad,
+                               is_grad_enabled, no_grad, persistent_tensors)
 from ....autograd.backward_engine import run_backward
 
 __all__ = ["recompute", "recompute_sequential", "RecomputeFunction"]
@@ -99,7 +99,9 @@ def recompute(function: Callable, *args, **kwargs):
         key = get_rng_tensor()
         moved = [(t, t._data) for t in persistent_tensors()
                  if t is not key and not isinstance(t, Parameter)]
-        with enable_grad(), kept.replay():
+        # the pass marker of every operation the replay traces (the rule of
+        # precedence among the markers: tensor.py, "stamps")
+        with enable_grad(), kept.replay(), jax.named_scope(PASS_REPLAY):
             out2 = function(*rebuilt, **kwargs)
         for t, data in moved:
             t._data = data

@@ -61,6 +61,7 @@ from __future__ import annotations
 import json
 import math
 import os
+import threading
 import time
 from collections import deque
 
@@ -536,6 +537,94 @@ def runtime_counter(name, inc=0):
     return _runtime_counters[name]
 
 
+# ------------------------------------------------ what the set-up compiles
+# JAX reports each phase of every compile it makes (a ``to_static`` step's
+# two, an eager operation's first use) to ``jax.monitoring``; the listeners
+# below, registered once when this module is imported, sum them into the
+# registry. They run when something compiles and never in a steady step.
+#   paddle_compile_seconds_total{phase="trace"}       Python -> jaxpr
+#   paddle_compile_seconds_total{phase="lower"}       jaxpr -> MLIR module
+#   paddle_compile_seconds_total{phase="backend"}     XLA's compile
+#   paddle_compile_seconds_total{phase="cache_load"}  an executable read back
+#                                  from the persistent compilation cache
+#   paddle_compile_cache_hits_total / ..._misses_total  of that cache (a miss
+#                                  is counted when the new entry is written)
+# No second is counted twice: JAX's ``backend_compile_duration`` spans
+# ``compiler.compile_or_get_cached`` and so CONTAINS the cache retrieval,
+# which is taken out of it here; a jitted function traced while another is
+# being traced (``jnp.where`` inside a step) reports a duration of its own
+# inside the outer one, and only the outermost of a thread is summed (JAX
+# records a scalar as a phase begins and the duration as it ends).
+_COMPILE_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load",
+}
+_COMPILE_CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "paddle_compile_cache_hits_total",
+    "/jax/compilation_cache/cache_misses":
+        "paddle_compile_cache_misses_total",
+}
+
+
+def compile_seconds_counter(phase):
+    """The registry's name of one phase's seconds."""
+    return f'paddle_compile_seconds_total{{phase="{phase}"}}'
+
+
+class _Compiling(threading.local):
+    def __init__(self):
+        self.open = {}          # phase -> how many of it are running
+        self.cache_load = 0.0   # retrieval seconds the open backend holds
+
+
+_compiling = _Compiling()
+
+
+def _compile_phase_begins(event, value, **kwargs):
+    phase = _COMPILE_PHASES.get(event)
+    if phase is not None:
+        _compiling.open[phase] = _compiling.open.get(phase, 0) + 1
+
+
+def _compile_phase_ends(event, seconds, **kwargs):
+    phase = _COMPILE_PHASES.get(event)
+    if phase is None:
+        return
+    if phase == "cache_load":       # reported inside the backend phase
+        _compiling.cache_load += seconds
+    else:
+        still = _compiling.open[phase] = max(
+            _compiling.open.get(phase, 1) - 1, 0)
+        if still:                   # nested: the outermost holds it
+            return
+        if phase == "backend":
+            seconds = max(seconds - _compiling.cache_load, 0.0)
+            _compiling.cache_load = 0.0
+    runtime_counter(compile_seconds_counter(phase), seconds)
+
+
+def _compile_cache_event(event, **kwargs):
+    name = _COMPILE_CACHE_EVENTS.get(event)
+    if name is not None:
+        runtime_counter(name, 1)
+
+
+def _count_compiles():
+    import jax.monitoring as monitoring
+    for phase in _COMPILE_PHASES.values():
+        runtime_counter(compile_seconds_counter(phase), 0.0)
+    for name in _COMPILE_CACHE_EVENTS.values():
+        runtime_counter(name, 0)
+    monitoring.register_scalar_listener(_compile_phase_begins)
+    monitoring.register_event_duration_secs_listener(_compile_phase_ends)
+    monitoring.register_event_listener(_compile_cache_event)
+
+
+_count_compiles()
+
+
 def runtime_collector(fn):
     """Register ``fn() -> {counter name: value}``, read at every
     exposition: for counts that live somewhere a scrape has to fetch them
@@ -595,9 +684,12 @@ def runtime_prometheus():
     counters = dict(_runtime_counters)
     for collect in _runtime_collectors:
         counters.update(collect())
+    family = None
     for name in sorted(counters):
-        lines.append(f"# HELP {name} {name}")
-        lines.append(f"# TYPE {name} counter")
+        if name.partition("{")[0] != family:    # one header a labelled family
+            family = name.partition("{")[0]
+            lines.append(f"# HELP {family} {family}")
+            lines.append(f"# TYPE {family} counter")
         lines.append(f"{name} {counters[name]}")
     for name in sorted(_runtime_hists):
         lines.extend(_runtime_hists[name].prometheus_lines(name))

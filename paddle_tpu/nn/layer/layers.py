@@ -9,6 +9,7 @@ from __future__ import annotations
 import collections
 from typing import Callable, Iterator, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -47,7 +48,21 @@ class HookRemoveHelper:
 
 
 class Layer:
-    """Base class for all network layers (paddle.nn.Layer parity)."""
+    """Base class for all network layers (paddle.nn.Layer parity).
+
+    ``forward`` runs under a ``jax.named_scope`` of the name the layer has in
+    its parent (the attribute it was assigned to, its key in a container), so
+    that JAX's name stack composes to the layer's path and every operation
+    of a compiled step names its layer in the HLO
+    (``GPTForCausalLM/gpt/h/7/attn/...``; the reference wraps ``forward`` in
+    a ``RecordEvent``: ``Layer._dygraph_call_func``). A layer that no parent
+    registered, the root of a model, runs under its class's name. A
+    container that is never called itself (``LayerList``, ``LayerDict``)
+    hands its own name on in front of its children's keys."""
+
+    # whether the layer is only a holder of sublayers that are called one by
+    # one by its parent's forward: its name goes in front of theirs
+    _holds_only = False
 
     def __init__(self, name_scope: Optional[str] = None, dtype="float32"):
         self._parameters: "collections.OrderedDict[str, Parameter]" = collections.OrderedDict()
@@ -75,6 +90,7 @@ class Layer:
                 raise RuntimeError("call Layer.__init__ before assigning layers")
             layers[name] = value
             self.__dict__.pop(name, None)
+            self._name_sublayer(name, value)
         else:
             if params is not None and name in params:
                 if value is None:
@@ -119,7 +135,23 @@ class Layer:
 
     def add_sublayer(self, name: str, sublayer: "Layer"):
         self._sub_layers[str(name)] = sublayer
+        if sublayer is not None:
+            self._name_sublayer(str(name), sublayer)
         return sublayer
+
+    def _name_sublayer(self, key: str, sublayer: "Layer"):
+        own = self.__dict__.get("_scope")
+        sublayer._set_scope(
+            f"{own}/{key}" if self._holds_only and own else key)
+
+    def _set_scope(self, scope: str):
+        """``scope`` is the name this layer has in its parent: what its
+        ``forward`` runs under."""
+        self.__dict__["_scope"] = scope
+        if self._holds_only:
+            for key, sub in self._sub_layers.items():
+                if sub is not None:
+                    sub._set_scope(f"{scope}/{key}")
 
     def register_buffer(self, name: str, tensor: Optional[Tensor],
                         persistable: bool = True):
@@ -229,7 +261,9 @@ class Layer:
             out = hook(self, inputs)
             if out is not None:
                 inputs = out if isinstance(out, tuple) else (out,)
-        outputs = self.forward(*inputs, **kwargs)
+        with jax.named_scope(self.__dict__.get("_scope")
+                             or type(self).__name__):
+            outputs = self.forward(*inputs, **kwargs)
         for hook in self._forward_post_hooks.values():
             res = hook(self, inputs, outputs)
             if res is not None:
@@ -319,6 +353,14 @@ class Layer:
         return "\n".join(lines) if len(lines) > 2 else lines[0] + ")"
 
 
+def _view(container, layers):
+    """``container`` over ``layers`` that another container holds: a slice
+    does not rename them."""
+    for i, layer in enumerate(layers):
+        container._sub_layers[str(i)] = layer
+    return container
+
+
 class Sequential(Layer):
     def __init__(self, *layers):
         super().__init__()
@@ -335,7 +377,7 @@ class Sequential(Layer):
 
     def __getitem__(self, idx):
         if isinstance(idx, slice):
-            return Sequential(*list(self._sub_layers.values())[idx])
+            return _view(Sequential(), list(self._sub_layers.values())[idx])
         return list(self._sub_layers.values())[idx]
 
     def __len__(self):
@@ -348,6 +390,8 @@ class Sequential(Layer):
 
 
 class LayerList(Layer):
+    _holds_only = True
+
     def __init__(self, sublayers=None):
         super().__init__()
         if sublayers is not None:
@@ -356,11 +400,11 @@ class LayerList(Layer):
 
     def __getitem__(self, idx):
         if isinstance(idx, slice):
-            return LayerList(list(self._sub_layers.values())[idx])
+            return _view(LayerList(), list(self._sub_layers.values())[idx])
         return list(self._sub_layers.values())[idx]
 
     def __setitem__(self, idx, layer):
-        self._sub_layers[str(idx)] = layer
+        self.add_sublayer(str(idx), layer)
 
     def __len__(self):
         return len(self._sub_layers)
@@ -377,7 +421,7 @@ class LayerList(Layer):
         layers.insert(index, layer)
         self._sub_layers.clear()
         for i, l in enumerate(layers):
-            self._sub_layers[str(i)] = l
+            self.add_sublayer(str(i), l)
 
     def extend(self, layers):
         for l in layers:
@@ -390,6 +434,8 @@ class LayerList(Layer):
 
 class LayerDict(Layer):
     """Ordered dict of sublayers (reference: nn.LayerDict)."""
+
+    _holds_only = True
 
     def __init__(self, sublayers=None):
         super().__init__()

@@ -11,9 +11,11 @@ from __future__ import annotations
 
 from typing import Iterable, Optional
 
+import jax
 import jax.numpy as jnp
 
-from ..tensor.tensor import Parameter, Tensor, no_grad, register_persistent
+from ..tensor.tensor import (PASS_OPTIMIZER, Parameter, Tensor, no_grad,
+                             register_persistent)
 from .lr import LRScheduler
 
 __all__ = ["Optimizer", "SGD", "Momentum", "Adam", "AdamW", "Adafactor",
@@ -169,13 +171,18 @@ class Optimizer:
             del self._master_weights[k]
 
     def _step_core(self, params_grads, lr):
-        if self._grad_clip is not None:
-            params_grads = self._grad_clip(params_grads)
-        for p, g in params_grads:
-            # per-param lr scaling from ParamAttr(learning_rate=...)
-            scale = getattr(p, "optimize_attr", None)
-            p_lr = lr * scale["learning_rate"] if scale else lr
-            self._update_param(p, g, p_lr)
+        # the pass marker of a compiled step's optimizer operations (the
+        # clip, the update, the master-weight cast), then which of them
+        with jax.named_scope(PASS_OPTIMIZER):
+            if self._grad_clip is not None:
+                with jax.named_scope("clip"):
+                    params_grads = self._grad_clip(params_grads)
+            with jax.named_scope(type(self).__name__.lower()):
+                for p, g in params_grads:
+                    # per-param lr scaling from ParamAttr(learning_rate=...)
+                    scale = getattr(p, "optimize_attr", None)
+                    p_lr = lr * scale["learning_rate"] if scale else lr
+                    self._update_param(p, g, p_lr)
 
     def _should_fuse(self, params_grads) -> bool:
         """Fuse the EAGER step into one compiled program (the reference's

@@ -25,6 +25,11 @@ from typing import Any, Callable, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+# the ONE place this package reads and sets JAX's name stack as a whole
+# (``jax.named_scope`` can only extend it): a tape node remembers the scopes
+# it was recorded under and the backward walk re-enters them
+from jax._src.source_info_util import (NameStack, Scope, current_name_stack,
+                                       set_name_stack)
 
 __all__ = [
     "Tensor",
@@ -62,7 +67,7 @@ _tape = _TapeState()
 class _TapeNode:
     """One recorded op: output ids <- vjp_fn <- input tensors."""
 
-    __slots__ = ("inputs", "output_ids", "vjp_fn", "outputs_meta",
+    __slots__ = ("inputs", "output_ids", "vjp_fn", "outputs_meta", "scope",
                  "__weakref__")
 
     def __init__(self, inputs, output_ids, vjp_fn, outputs_meta):
@@ -70,6 +75,50 @@ class _TapeNode:
         self.output_ids = output_ids    # list[int] tensor uids
         self.vjp_fn = vjp_fn            # cotangents -> input cotangents
         self.outputs_meta = outputs_meta  # list[(shape, dtype)] for zero-filling
+        # the name stack open when the op was recorded (the layers' path and
+        # the hand-placed scopes): ``run_backward`` calls ``vjp_fn`` long
+        # after they closed and opens them again around it (``scope_of_pass``)
+        self.scope = current_name_stack()
+
+
+# ------------------------------------------------ stamps of the compiled step
+# Every operation of a compiled step says where it came from in its HLO
+# ``op_name``, which is JAX's name stack when the operation was traced:
+#
+#   * the LAYER's path (``Layer.__call__`` runs ``forward`` under the name the
+#     layer has in its parent: ``GPTForCausalLM/gpt/h/7/attn``) and the
+#     hand-placed ``jax.named_scope``s inside a forward (``moe.experts``);
+#   * the PASS: ``bwd`` in front of what ``run_backward`` runs, ``replay``
+#     around the forward that ``fleet.utils.recompute`` runs again, ``opt``
+#     around ``Optimizer.step``.
+#
+# A replay runs INSIDE the backward walk and its own nested walk runs what
+# the replay recorded, so a path can carry several markers. The one rule of
+# precedence, for every reader (``paddle.profiler`` holds it as
+# ``pass_of``): a path with ``opt`` is the optimizer's; else one with
+# ``transpose(`` (JAX's own mark on a transposed equation) is backward, the
+# replayed layers' backward included; else one with ``replay`` is the
+# replay; else one with ``bwd`` is backward (the walk's own sums of
+# cotangents, a PyLayer's backward); else it is the first forward. The
+# stamps are metadata: no value, shape or order of the program changes.
+PASS_BACKWARD, PASS_REPLAY, PASS_OPTIMIZER = "bwd", "replay", "opt"
+
+
+def scope_of_pass(base: NameStack, marker: str, scope: NameStack) -> NameStack:
+    """The name stack to run a recorded node's backward under: ``base`` (the
+    stack open where the walk started), ``marker`` unless ``base`` already
+    carries it (a nested walk inside a replay), then the part of ``scope``
+    (the stack the node was recorded under) that ``base`` does not already
+    hold open."""
+    held = base.stack
+    k = 0
+    for a, b in zip(held, scope.stack):
+        if a != b:
+            break
+        k += 1
+    if not any(isinstance(e, Scope) and e.name == marker for e in held):
+        held = held + (Scope(marker),)
+    return NameStack(held + scope.stack[k:])
 
 
 def is_grad_enabled() -> bool:
