@@ -16,9 +16,14 @@ works on (seq, head_dim) tiles (last dim = lanes).
 Supports: causal masking, GQA/MQA (kv_heads divides q_heads; realized in the
 BlockSpec index_map — zero-copy), bf16/f32 inputs (dots in input dtype,
 fp32 accumulate + softmax), seq
-lengths not divisible by the block size (masked tail blocks).  Backward is
-the standard two-kernel split: dKV (grid over KV blocks, scan Q) and dQ
-(grid over Q blocks, scan KV), with delta = rowsum(dO * O) precomputed.
+lengths not divisible by the block size (masked tail blocks).  Backward,
+with delta = rowsum(dO * O) precomputed, is ONE kernel wherever a (batch,
+head) plane's float32 dQ fits VMEM (``_bwd``): a single tile a plane gives
+dq, dk and dv at once (``_bwd_fused``); several tiles run the dK/dV grid (KV
+blocks, Q scanned) with dQ summed in a plane-sized VMEM accumulator beside
+dK and dV (``_bwd_onepass``). Only a plane too long for that takes the
+standard two-kernel split, dKV then dQ (grid over Q blocks, scan KV), which
+computes S, P, dP and dS twice (``_bwd_pair``).
 
 Masks are STRUCTURED: a rule on an entry's row and column, never an array.
 Inside this file ``mask`` is ``None``, ``"causal"`` or
@@ -224,6 +229,13 @@ def _count_tiles(mask, planes, bq, bk, sq, sk):
 # Forward
 # ---------------------------------------------------------------------------
 
+def _drop_mode(drop):
+    """How a kernel gets its dropout: 0 none, 1 a mask array (``('mask',
+    dmask)``, interpret mode), 2 drawn in the kernel (``('prng', seed,
+    p)``)."""
+    return 0 if drop is None else (1 if drop[0] == "mask" else 2)
+
+
 def _drop_tile(seed_ref, bi, hi, qi, ki, bq, bk, dropout_p):
     """Scaled keep multiplier generated in-kernel (TPU hardware PRNG, zero
     HBM traffic); seeded per (call, batch, head, q-block, k-block) so the
@@ -350,7 +362,7 @@ def _fwd(q, k, v, drop=None, *, mask, scale, bq, bk):
         v = jnp.pad(v, ((0, 0), (0, 0), (0, sk_p - sk), (0, 0)))
 
     grid = (b, h, sq_p // bq, sk_p // bk)
-    drop_mode = 0 if drop is None else (1 if drop[0] == "mask" else 2)
+    drop_mode = _drop_mode(drop)
     kernel = functools.partial(
         _fwd_kernel, scale=scale, mask=mask, sq=sq, sk=sk, bq=bq, bk=bk,
         drop_mode=drop_mode,
@@ -398,16 +410,22 @@ def _fwd(q, k, v, drop=None, *, mask, scale, bq, bk):
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     *rest, scale, mask, sq, sk, bq, bk, drop_mode=0,
-                    dropout_p=0.0):
+                    dropout_p=0.0, with_dq=False):
+    """dK and dV of K tile ``ki``, summed over the Q tiles scanned innermost.
+    ``with_dq`` (``_bwd_onepass``): dQ too, from the same S, P, dP and dS:
+    ``dq_sc`` holds the whole plane's dQ ``[sq_p, d]`` in float32, row tile
+    ``qi`` of it zeroed at the first K tile, summed over the K tiles in
+    ascending order as ``_bwd_dq_kernel`` sums them, and written to the
+    plane's output block at the last."""
+    dmask_ref = seed_ref = dq_ref = dq_sc = None
     if drop_mode == 1:
-        dmask_ref, dk_ref, dv_ref, dk_sc, dv_sc = rest
-        seed_ref = None
+        dmask_ref, *rest = rest
     elif drop_mode == 2:
-        seed_ref, dk_ref, dv_ref, dk_sc, dv_sc = rest
-        dmask_ref = None
+        seed_ref, *rest = rest
+    if with_dq:
+        dk_ref, dv_ref, dq_ref, dk_sc, dv_sc, dq_sc = rest
     else:
         dk_ref, dv_ref, dk_sc, dv_sc = rest
-        dmask_ref = seed_ref = None
     ki = pl.program_id(2)
     qi = pl.program_id(3)
     nq = pl.num_programs(3)
@@ -419,6 +437,12 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     q_start = qi * bq
     k_start = ki * bk
+    if with_dq:
+        rows = pl.ds(pl.multiple_of(q_start, bq), bq)
+
+        @pl.when(ki == 0)
+        def _():
+            dq_sc[rows, :] = jnp.zeros((bq, dq_sc.shape[1]), jnp.float32)
 
     @pl.when(_tile_runs(mask, q_start, k_start, bq, bk, sq, sk))
     def _():
@@ -455,14 +479,25 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             dp = dp * dm
         ds = p * (dp - delta) * scale
         # dk += dS^T Q
+        ds = ds.astype(q.dtype)
         dk_sc[:] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+        if with_dq:
+            # dq[rows] += dS K: the fifth product of the tile
+            dq_sc[rows, :] += jax.lax.dot_general(
+                ds, k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
     @pl.when(qi == nq - 1)
     def _():
         dk_ref[0, 0] = dk_sc[:].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_sc[:].astype(dv_ref.dtype)
+
+    if with_dq:
+        @pl.when(ki == pl.num_programs(2) - 1)
+        def _():
+            dq_ref[0, 0, rows, :] = dq_sc[rows, :].astype(dq_ref.dtype)
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -583,7 +618,7 @@ def _bwd_fused(q_, k_, v_, do_, lse_, delta_, drop, drop_arg, *,
     still per-q-head (GQA segment-sum happens in the caller)."""
     b, h, sq_p, d = q_.shape
     sk_p = k_.shape[2]
-    drop_mode = 0 if drop is None else (1 if drop[0] == "mask" else 2)
+    drop_mode = _drop_mode(drop)
     qspec = pl.BlockSpec((1, 1, sq_p, d), lambda b_, h_: (b_, h_, 0, 0))
     kspec = pl.BlockSpec((1, 1, sk_p, d),
                          lambda b_, h_, g=group: (b_, h_ // g, 0, 0))
@@ -619,15 +654,167 @@ def _bwd_fused(q_, k_, v_, do_, lse_, delta_, drop, drop_arg, *,
     return dq, dk, dv
 
 
+# The one-pass backward keeps a plane's dQ [sq_p, d] in float32 for the whole
+# plane: 8 MiB at 16,384 positions of head 128 and at 8,192 of head 256. The
+# v5e has 128 MiB of VMEM a core (the 16 MiB a kernel gets by default are a
+# scoped limit, which ``vmem_limit_bytes`` lifts); an accumulator of up to an
+# eighth of that leaves its output block's two buffers (as much again in
+# float32) and the tiles well inside it: 63.1 MiB at the most, float32 at
+# 32,768 positions of head 128 (sandbox AOT, PR 38). A longer plane takes
+# the pair.
+_ONEPASS_DQ_BYTES = 16 << 20
+# what the compiler may keep beside the buffers ``_onepass_vmem_bytes``
+# counts; the limit is a ceiling, not an allocation
+_ONEPASS_HEADROOM = 8 << 20
+
+
+def _lanes(d):
+    return -(-d // 128) * 128       # a row of d features in VMEM
+
+
+def _onepass_vmem_bytes(sq_p, bq, bk, d, itemsize):
+    """What ``flash_attention_bwd_onepass`` holds in VMEM: the plane's dQ
+    accumulator and its output block (two buffers); the tiles of q, do, k,
+    v, dk, dv (two buffers each) and of lse and delta (a lane of 128 each);
+    the dK and dV scratch; and a tile's [bq, bk] temporaries, S / P, dP and
+    dS in float32 and P and dS in the input dtype. The v5e's compiler
+    allocates 27.6 MiB at SDAR's shape and 23.1 at Qwen3-Next's where this
+    counts 39 and 26 (sandbox AOT, PR 38: it keeps fewer temporaries
+    alive)."""
+    row = _lanes(d)
+    plane = sq_p * row * (4 + 2 * itemsize)
+    tiles = 2 * (2 * bq * row * itemsize + 2 * bk * row * itemsize
+                 + 2 * bk * row * 4 + 2 * bq * 128 * 4)
+    return (plane + tiles + 2 * bk * row * 4
+            + bq * bk * (3 * 4 + 2 * itemsize))
+
+
+def _dkv_call(q_, k_, v_, do_, lse_, delta_, drop, drop_arg, *,
+              mask, scale, sq, sk, bq, bk, group, with_dq):
+    """What the dK/dV kernel's two ``pallas_call``s share: grid (b, h, K
+    tile j, Q tile i), the Q side scanned; with ``with_dq`` the one-pass
+    kernel, which keeps the plane's dQ in VMEM as well. Returns the kernel,
+    the call's keywords but its name, and the operands; the call gives (dk,
+    dv) or (dk, dv, dq), dk and dv float32 and a query head's own."""
+    b, h, sq_p, d = q_.shape
+    sk_p = k_.shape[2]
+    drop_mode = _drop_mode(drop)
+    qspec = _scanned_spec(mask, bq, d, None, bq, bk, False)
+    kspec = pl.BlockSpec((1, 1, bk, d),
+                         lambda b_, h_, j, i, g=group: (b_, h_ // g, j, 0))
+    rowspec = _scanned_spec(mask, bq, 1, None, bq, bk, False)
+    in_specs = [qspec, kspec, kspec, qspec, rowspec, rowspec]
+    args = [q_, k_, v_, do_, lse_, delta_]
+    if drop_mode == 1:
+        in_specs.append(pl.BlockSpec((1, 1, bq, bk),
+                                     lambda b_, h_, j, i: (b_, h_, i, j)))
+        args.append(drop_arg())
+    elif drop_mode == 2:
+        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+        args.append(drop_arg())
+    kv_out = pl.BlockSpec((1, 1, bk, d), lambda b_, h_, j, i: (b_, h_, j, 0))
+    out_specs = [kv_out, kv_out]
+    out_shape = [jax.ShapeDtypeStruct((b, h, sk_p, d), jnp.float32)] * 2
+    scratch = [pltpu.VMEM((bk, d), jnp.float32)] * 2
+    compiler_params = None
+    if with_dq:
+        # the plane's block: its index moves with (b, h) alone, so it is
+        # written back once a plane
+        out_specs.append(pl.BlockSpec((1, 1, sq_p, d),
+                                      lambda b_, h_, j, i: (b_, h_, 0, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((b, h, sq_p, d), q_.dtype))
+        scratch.append(pltpu.VMEM((sq_p, d), jnp.float32))
+        compiler_params = pltpu.CompilerParams(
+            vmem_limit_bytes=_onepass_vmem_bytes(
+                sq_p, bq, bk, d, q_.dtype.itemsize) + _ONEPASS_HEADROOM)
+    kernel = functools.partial(
+        _bwd_dkv_kernel, scale=scale, mask=mask, sq=sq, sk=sk, bq=bq, bk=bk,
+        drop_mode=drop_mode, dropout_p=drop[2] if drop_mode == 2 else 0.0,
+        with_dq=with_dq)
+    return kernel, dict(
+        grid=(b, h, sk_p // bk, sq_p // bq), in_specs=in_specs,
+        out_specs=out_specs, out_shape=out_shape, scratch_shapes=scratch,
+        compiler_params=compiler_params,
+        interpret=_pallas._interpret()), args
+
+
+def _bwd_onepass(q_, k_, v_, do_, lse_, delta_, drop, drop_arg, *,
+                 mask, scale, sq, sk, bq, bk, group):
+    """Several tiles a plane, ONE kernel: S, P, dP and dS once a visited
+    tile, five products (dV, dP, dK, dQ and S itself) where the pair runs
+    seven. Inputs pre-padded to whole tiles; returns (dq, dk, dv), dk and dv
+    still a query head's own, as ``_bwd_fused`` and ``_bwd_pair`` do."""
+    b, h = q_.shape[:2]
+    _count_tiles(mask, b * h, bq, bk, sq, sk)
+    kernel, call, args = _dkv_call(
+        q_, k_, v_, do_, lse_, delta_, drop, drop_arg, mask=mask,
+        scale=scale, sq=sq, sk=sk, bq=bq, bk=bk, group=group, with_dq=True)
+    dk, dv, dq = pl.pallas_call(
+        kernel, name="flash_attention_bwd_onepass", **call)(*args)
+    return dq, dk, dv
+
+
+def _bwd_pair(q_, k_, v_, do_, lse_, delta_, drop, drop_arg, *,
+              mask, scale, sq, sk, bq, bk, group):
+    """Several tiles a plane whose dQ outgrows ``_ONEPASS_DQ_BYTES``: the
+    standard split, dK/dV (grid over KV blocks, Q scanned) then dQ (grid
+    over Q blocks, KV scanned, a tile-sized accumulator), each computing S,
+    P, dP and dS of every visited tile. Same arguments and results as
+    ``_bwd_onepass``."""
+    b, h, sq_p, d = q_.shape
+    sk_p = k_.shape[2]
+    drop_mode = _drop_mode(drop)
+    _count_tiles(mask, 2 * b * h, bq, bk, sq, sk)    # dK/dV and dQ
+    # GQA: per-Q-head dk/dv (shape [B,H,...]), segment-summed to [B,Hk,...]
+    # by the caller — XLA turns that into a cheap reshape-sum.
+    kernel, call, args = _dkv_call(
+        q_, k_, v_, do_, lse_, delta_, drop, drop_arg, mask=mask,
+        scale=scale, sq=sq, sk=sk, bq=bq, bk=bk, group=group, with_dq=False)
+    dk, dv = pl.pallas_call(
+        kernel, name="flash_attention_bwd_dkv", **call)(*args)
+
+    qspec2 = pl.BlockSpec((1, 1, bq, d), lambda b_, h_, i, j: (b_, h_, i, 0))
+    kspec2 = _scanned_spec(mask, bk, d, group, bq, bk, True)
+    rowspec2 = pl.BlockSpec((1, 1, bq, 1), lambda b_, h_, i, j: (b_, h_, i, 0))
+    dq_in = [qspec2, kspec2, kspec2, qspec2, rowspec2, rowspec2]
+    dq_args = [q_, k_, v_, do_, lse_, delta_]
+    if drop_mode == 1:
+        dq_in.append(pl.BlockSpec((1, 1, bq, bk),
+                                  lambda b_, h_, i, j: (b_, h_, i, j)))
+        dq_args.append(drop_arg())
+    elif drop_mode == 2:
+        dq_in.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+        dq_args.append(drop_arg())
+    dq = pl.pallas_call(
+        functools.partial(_bwd_dq_kernel, scale=scale, mask=mask,
+                          sq=sq, sk=sk, bq=bq, bk=bk, drop_mode=drop_mode,
+                          dropout_p=drop[2] if drop_mode == 2 else 0.0),
+        grid=(b, h, sq_p // bq, sk_p // bk),
+        in_specs=dq_in,
+        out_specs=pl.BlockSpec((1, 1, bq, d),
+                               lambda b_, h_, i, j: (b_, h_, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, h, sq_p, d), q_.dtype),
+        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+        name="flash_attention_bwd_dq",
+        interpret=_pallas._interpret(),
+    )(*dq_args)
+    return dq, dk, dv
+
+
 def _bwd(q, k, v, o, lse, do, drop=None, *, mask, scale, bq, bk):
+    """dq, dk, dv. Which kernels run is decided here, from shapes alone: one
+    tile a plane -> ``_bwd_fused``; several, and the plane's float32 dQ
+    within ``_ONEPASS_DQ_BYTES`` -> ``_bwd_onepass``; a longer plane ->
+    ``_bwd_pair``. ``paddle_flash_bwd_onepass_traces_total`` /
+    ``paddle_flash_bwd_split_traces_total`` count the traces of the last
+    two."""
     b, h, sq, d = q.shape
     hk = k.shape[1]
     group = h // hk
     sk = k.shape[2]
     sq_p = math.ceil(sq / bq) * bq
     sk_p = math.ceil(sk / bk) * bk
-    drop_mode = 0 if drop is None else (1 if drop[0] == "mask" else 2)
-    drop_p = drop[2] if drop_mode == 2 else 0.0
+    drop_mode = _drop_mode(drop)
 
     def drop_arg():
         if drop_mode == 1:
@@ -645,92 +832,18 @@ def _bwd(q, k, v, o, lse, do, drop=None, *, mask, scale, bq, bk):
         return jnp.pad(x, ((0, 0), (0, 0), (0, sk_p - sk), (0, 0))) \
             if sk_p != sk else x
 
-    q_, do_ = padq(q), padq(do)
-    k_, v_ = padk(k), padk(v)
-    lse_, delta_ = padq(lse), padq(delta)
-
     if sq_p == bq and sk_p == bk:
-        # whole slice is one block: fused dq/dk/dv kernel (no S/dP
-        # recompute, single read of q/k/v/do); more blocks take the
-        # split dKV + dQ pair below
-        dq, dk, dv = _bwd_fused(
-            q_, k_, v_, do_, lse_, delta_, drop, drop_arg,
-            mask=mask, scale=scale, sq=sq, sk=sk, group=group)
-        dq = dq[:, :, :sq]
-        dk = dk[:, :, :sk]
-        dv = dv[:, :, :sk]
-        if group > 1:
-            dk = dk.reshape(b, hk, group, sk, d).sum(axis=2)
-            dv = dv.reshape(b, hk, group, sk, d).sum(axis=2)
-        return dq, dk.astype(k.dtype), dv.astype(v.dtype)
-
-    _count_tiles(mask, 2 * b * h, bq, bk, sq, sk)    # dK/dV and dQ
-    # grid (b, h, K tile j, Q tile i): the Q side is scanned
-    qspec = _scanned_spec(mask, bq, d, None, bq, bk, False)
-    kspec = pl.BlockSpec((1, 1, bk, d),
-                         lambda b_, h_, j, i, g=group: (b_, h_ // g, j, 0))
-    rowspec = _scanned_spec(mask, bq, 1, None, bq, bk, False)
-
-    # dK/dV: one [bk,d] accumulator pair per KV block; Q scanned innermost.
-    # GQA: compute per-Q-head dk/dv (shape [B,H,...]) and segment-sum to
-    # [B,Hk,...] outside the kernel — XLA turns that into a cheap reshape-sum.
-    dkv_in = [qspec, kspec, kspec, qspec, rowspec, rowspec]
-    dkv_args = [q_, k_, v_, do_, lse_, delta_]
-    if drop_mode == 1:
-        dkv_in.append(pl.BlockSpec((1, 1, bq, bk),
-                                   lambda b_, h_, j, i: (b_, h_, i, j)))
-        dkv_args.append(drop_arg())
-    elif drop_mode == 2:
-        dkv_in.append(pl.BlockSpec(memory_space=pltpu.SMEM))
-        dkv_args.append(drop_arg())
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale=scale, mask=mask,
-                          sq=sq, sk=sk, bq=bq, bk=bk, drop_mode=drop_mode,
-                          dropout_p=drop_p),
-        grid=(b, h, sk_p // bk, sq_p // bq),
-        in_specs=dkv_in,
-        out_specs=[
-            pl.BlockSpec((1, 1, bk, d), lambda b_, h_, j, i: (b_, h_, j, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda b_, h_, j, i: (b_, h_, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, h, sk_p, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, h, sk_p, d), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bk, d), jnp.float32),
-            pltpu.VMEM((bk, d), jnp.float32),
-        ],
-        name="flash_attention_bwd_dkv",
-        interpret=_pallas._interpret(),
-    )(*dkv_args)
-
-    qspec2 = pl.BlockSpec((1, 1, bq, d), lambda b_, h_, i, j: (b_, h_, i, 0))
-    kspec2 = _scanned_spec(mask, bk, d, group, bq, bk, True)
-    rowspec2 = pl.BlockSpec((1, 1, bq, 1), lambda b_, h_, i, j: (b_, h_, i, 0))
-    dq_in = [qspec2, kspec2, kspec2, qspec2, rowspec2, rowspec2]
-    dq_args = [q_, k_, v_, do_, lse_, delta_]
-    if drop_mode == 1:
-        dq_in.append(pl.BlockSpec((1, 1, bq, bk),
-                                  lambda b_, h_, i, j: (b_, h_, i, j)))
-        dq_args.append(drop_arg())
-    elif drop_mode == 2:
-        dq_in.append(pl.BlockSpec(memory_space=pltpu.SMEM))
-        dq_args.append(drop_arg())
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, mask=mask,
-                          sq=sq, sk=sk, bq=bq, bk=bk, drop_mode=drop_mode,
-                          dropout_p=drop_p),
-        grid=(b, h, sq_p // bq, sk_p // bk),
-        in_specs=dq_in,
-        out_specs=pl.BlockSpec((1, 1, bq, d),
-                               lambda b_, h_, i, j: (b_, h_, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, h, sq_p, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        name="flash_attention_bwd_dq",
-        interpret=_pallas._interpret(),
-    )(*dq_args)
-
+        # whole slice is one block: dq, dk and dv at once, no tile to scan
+        run = _bwd_fused
+    elif sq_p * _lanes(d) * 4 <= _ONEPASS_DQ_BYTES:
+        run = functools.partial(_bwd_onepass, bq=bq, bk=bk)
+        runtime_counter("paddle_flash_bwd_onepass_traces_total", 1)
+    else:
+        run = functools.partial(_bwd_pair, bq=bq, bk=bk)
+        runtime_counter("paddle_flash_bwd_split_traces_total", 1)
+    dq, dk, dv = run(
+        padq(q), padk(k), padk(v), padq(do), padq(lse), padq(delta), drop,
+        drop_arg, mask=mask, scale=scale, sq=sq, sk=sk, group=group)
     dq = dq[:, :, :sq]
     dk = dk[:, :, :sk]
     dv = dv[:, :, :sk]

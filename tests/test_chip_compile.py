@@ -203,24 +203,28 @@ def _dense_decode():
 _QKV = (B, 1024, H, D)     # the gpt2_124m train step: 8 x 1024 x 12 x 64
 
 
-def _flash(grad, dropout_p=0.0, qkv=_QKV, kv_heads=None, block=None):
+def _flash(grad, dropout_p=0.0, qkv=_QKV, kv_heads=None, block=None,
+           backward=(), dtype=BF, causal=True):
     """``block``: under the block-diffusion mask over ``qkv``'s positions,
     in blocks of that many tokens, instead of the causal one. The kernels
     keep their names: which mask an event ran under is the program's to
-    know (``paddle_flash_mask_kernel_traces_total``), not the trace's."""
+    know (``paddle_flash_mask_kernel_traces_total``), not the trace's.
+    ``backward``: the backward's kernels the compiled program must name
+    (``fused``: one tile a plane; ``onepass``: several; ``dkv`` and ``dq``:
+    a plane past the one-pass kernel's VMEM budget)."""
     from paddle_tpu.ops.pallas.flash_attention import (block_diffusion_mask,
                                                        flash_attention)
-    fn = functools.partial(flash_attention, causal=block is None,
+    fn = functools.partial(flash_attention,
+                           causal=causal and block is None,
                            dropout_p=dropout_p,
                            mask=block and block_diffusion_mask(
                                qkv[1] // 2, block))
     if grad:
         fn = _sum_grad(fn, (0, 1, 2))
     kv = qkv if kv_heads is None else qkv[:2] + (kv_heads,) + qkv[3:]
-    names = ("flash_attention_fwd",) + (
-        ("flash_attention_bwd_dkv", "flash_attention_bwd_dq")
-        if grad and block else ())
-    return (fn, (_one(qkv, BF), _one(kv, BF), _one(kv, BF))) + names
+    return (fn, (_one(qkv, dtype), _one(kv, dtype), _one(kv, dtype)),
+            "flash_attention_fwd") + tuple(
+                "flash_attention_bwd_" + n for n in backward)
 
 
 def _ring_chunk(grad):
@@ -371,17 +375,30 @@ _CASES = {
     "decode_attention_dense": lambda mp: _dense_decode(),
     # the gpt2_124m train step
     "flash_fwd": lambda mp: _flash(False),
-    "flash_fwd_bwd": lambda mp: _flash(True),
-    "flash_fwd_bwd_dropout": lambda mp: _flash(True, 0.1),
-    # past one block a side the backward is two kernels, dk/dv then dq
-    "flash_fwd_bwd_seq4096": lambda mp: _flash(True, qkv=(2, 4096, H, D)),
+    "flash_fwd_bwd": lambda mp: _flash(True, backward=("fused",)),
+    "flash_fwd_bwd_dropout": lambda mp: _flash(True, 0.1,
+                                               backward=("fused",)),
+    # past one block a side the backward is ONE kernel still: dQ is summed
+    # in a plane-sized VMEM accumulator beside dK and dV, with the VMEM it
+    # asks for (``_onepass_vmem_bytes``); also with the in-kernel dropout
+    "flash_fwd_bwd_seq4096": lambda mp: _flash(
+        True, qkv=(2, 4096, H, D), backward=("onepass",)),
+    "flash_fwd_bwd_dropout_seq4096": lambda mp: _flash(
+        True, 0.1, qkv=(2, 4096, H, D), backward=("onepass",)),
+    # the budget's edge, ``_ONEPASS_DQ_BYTES``: float32 at 32,768 positions
+    # of head 128 asks for the most the one-pass kernel ever does (85 MiB;
+    # the compiler allocates 63.1); a longer plane takes the dK/dV + dQ pair
+    "flash_fwd_bwd_f32_seq32768": lambda mp: _flash(
+        True, qkv=(1, 32768, 2, 128), dtype=F32, backward=("onepass",)),
+    "flash_fwd_bwd_seq33792": lambda mp: _flash(
+        True, qkv=(1, 33792, 2, 128), backward=("dkv", "dq")),
     # Qwen3-Next's gated attention at the benchmark's 2 x 8192: head 256
-    # (512-wide tiles: 1024 outgrow VMEM in the dk/dv kernel), 16 query
-    # heads on 2 KV heads, per-query-head dK / dV summed over 8
+    # (512-wide tiles: 1024 outgrow the default VMEM in the forward), 16
+    # query heads on 2 KV heads, per-query-head dK / dV summed over 8
     "flash_fwd_gqa_d256_seq8192": lambda mp: _flash(
         False, qkv=(2, 8192, 16, 256), kv_heads=2),
     "flash_fwd_bwd_gqa_d256_seq8192": lambda mp: _flash(
-        True, qkv=(2, 8192, 16, 256), kv_heads=2),
+        True, qkv=(2, 8192, 16, 256), kv_heads=2, backward=("onepass",)),
     # SDAR's attention at the benchmark's 1 x 8192 data tokens: 16,384
     # positions of [noisy ; clean] under the block-diffusion mask, blocks
     # of 4, 32 query heads of 128 on 4 KV heads; and blocks that are no
@@ -389,9 +406,11 @@ _CASES = {
     "flash_fwd_blockdiff_d128_seq16384": lambda mp: _flash(
         False, qkv=(1, 16384, 32, 128), kv_heads=4, block=4),
     "flash_fwd_bwd_blockdiff_d128_seq16384": lambda mp: _flash(
-        True, qkv=(1, 16384, 32, 128), kv_heads=4, block=4),
+        True, qkv=(1, 16384, 32, 128), kv_heads=4, block=4,
+        backward=("onepass",)),
     "flash_fwd_bwd_blockdiff_block12_seq3000": lambda mp: _flash(
-        True, qkv=(2, 3000, 8, 64), kv_heads=1, block=12),
+        True, qkv=(2, 3000, 8, 64), kv_heads=1, block=12,
+        backward=("onepass",)),
     # Qwen3-Next's gated delta rule at the benchmark's 2 x 8192
     "gdn_rule_fwd_seq8192": lambda mp: _gdn_rule(False),
     "gdn_rule_fwd_bwd_seq8192": lambda mp: _gdn_rule(True),
@@ -517,12 +536,14 @@ def test_sdar_layer_compiles_with_the_masked_kernels(topo, monkeypatch):
     experts held, on the benchmark's 1 x 8192 data tokens (16,384 positions),
     bf16, in train mode: forward, the replay under ``recompute`` and the
     backward pass in one program, as the cell's step holds six of. The
-    three flash kernels are in it under the structured mask (no dense
+    two flash kernels are in it under the structured mask (no dense
     [16384, 16384] mask is: the composite's scores alone would be 34 GB),
     each ONCE: the region keeps the forward kernel's ``o`` and ``lse`` and
-    the replay does not run it again (twice before PR 33). Its temporaries
-    (1.77 GiB; 1.90 with the replayed forward) leave the step room beside
-    its state. BYTES of a compile, not times."""
+    the replay does not run it again (twice before PR 33), and the backward
+    is the one-pass kernel (the dK/dV + dQ pair before PR 38). Its
+    temporaries (1.7714 GiB; 1.7715 with the pair, 1.90 with the replayed
+    forward) leave the step room beside its state. BYTES of a compile, not
+    times."""
     import paddle_tpu as paddle
     import paddle_tpu.ops.pallas as pallas
     from paddle_tpu.inference import telemetry
@@ -556,13 +577,60 @@ def test_sdar_layer_compiles_with_the_masked_kernels(topo, monkeypatch):
             p._data, p.grad = a, None
         layer.mlp.counts._data = counts
     kernels = _KERNEL_INSTR.findall(compiled.as_text())
-    for name in ("flash_attention_fwd", "flash_attention_bwd_dkv",
-                 "flash_attention_bwd_dq"):
-        assert sum(name in k for k in kernels) == 1, (name, kernels)
+    for name, calls in (("flash_attention_fwd", 1),
+                        ("flash_attention_bwd_onepass", 1),
+                        ("flash_attention_bwd_dkv", 0),
+                        ("flash_attention_bwd_dq", 0)):
+        assert sum(name in k for k in kernels) == calls, (name, kernels)
     # the first forward and its replay each traced the dispatch once
     assert telemetry.runtime_counter(
         "paddle_flash_mask_kernel_traces_total") == kernel + 2
-    assert compiled.memory_analysis().temp_size_in_bytes / (1 << 30) <= 2.1
+    assert compiled.memory_analysis().temp_size_in_bytes / (1 << 30) <= 1.78
+
+
+def _mosaic_texts(fn, args, monkeypatch):
+    """The Mosaic text (no locations; the serialised bytecode carries line
+    numbers and always differs) of every kernel ``fn`` lowers, in order."""
+    from jax._src import tpu_custom_call
+    real = tpu_custom_call._lower_mosaic_module_to_asm
+    texts = []
+
+    def spy(module, **kw):
+        texts.append(module.operation.get_asm(enable_debug_info=False))
+        return real(module, **kw)
+    monkeypatch.setattr(tpu_custom_call, "_lower_mosaic_module_to_asm", spy)
+    jax.jit(fn).lower(*args)
+    return texts
+
+
+# sha256 of the kernels' Mosaic text on PR 36's tree, before the backward
+# over several tiles became one kernel: the forward kernels under each mask
+# and the one-tile backward (GPT-2's) are that tree's, byte for byte
+_MOSAIC = {
+    "causal_one_tile": (lambda: _flash(True), ["14d95a379edd9d13", "e74f95c6994c2d8f"]),
+    "causal_one_tile_dropout": (lambda: _flash(True, 0.1),
+                                ["aa62095e94136647", "fcd46e1c1f68ca0a"]),
+    "unmasked_one_tile": (lambda: _flash(True, causal=False),
+                          ["a16edb10c9c2698a", "1dc9e654deca9f0c"]),
+    "causal_seq4096": (lambda: _flash(False, qkv=(2, 4096, H, D)),
+                       ["6fbf8654bb67b14e"]),
+    "unmasked_seq4096": (lambda: _flash(False, qkv=(2, 4096, H, D),
+                                        causal=False), ["e0af01ae03d20bca"]),
+    "causal_gqa_d256_seq8192": (lambda: _flash(
+        False, qkv=(2, 8192, 16, 256), kv_heads=2), ["1dcbd2ccb529554c"]),
+    "blockdiff_d128_seq16384": (lambda: _flash(
+        False, qkv=(1, 16384, 32, 128), kv_heads=4, block=4), ["ea67dfbda186f4db"]),
+}
+
+
+@pytest.mark.parametrize("case", list(_MOSAIC))
+def test_untouched_kernels_keep_their_mosaic_text(case, monkeypatch):
+    import hashlib
+    build, want = _MOSAIC[case]
+    fn, args, *_ = build()
+    got = [hashlib.sha256(t.encode()).hexdigest()[:16]
+           for t in _mosaic_texts(fn, args, monkeypatch)]
+    assert got == want
 
 
 @pytest.mark.parametrize("m,k,o,says", [

@@ -1,8 +1,11 @@
 """The block-diffusion mask inside the flash kernels (interpret mode on the
 CPU): values and dq, dk, dv against the dense-mask composite; the count of
 visited tiles against a brute-force count; the causal and unmasked paths
-bit-equal to what they gave before the mask existed; the one dispatch."""
+bit-equal to what they gave before the mask existed; the one dispatch; and
+the one-pass backward (several tiles a plane, dQ summed in VMEM beside dK and
+dV) bit-equal to the dK/dV + dQ pair it replaces."""
 import hashlib
+import re
 
 import numpy as np
 import pytest
@@ -50,7 +53,7 @@ def test_dense_mask_is_the_rule(seq, b):
 
 
 # seq a multiple of the tile and not; one tile a side (the fused backward)
-# and several (the dK/dV + dQ pair); 8 query heads on one KV head
+# and several (the one-pass backward); 8 query heads on one KV head
 @pytest.mark.parametrize("seq,b,h,hk,d,tiles", [
     (64, 4, 4, 4, 64, None),            # one 128-wide tile: fused backward
     (96, 32, 8, 1, 64, (64, 64)),       # 3 x 3 tiles, GQA 8:1
@@ -148,6 +151,116 @@ def _digest(causal, s, h, hk, d, tiles, dtype, monkeypatch):
 def test_causal_and_unmasked_paths_are_bit_equal_to_the_parents(
         case, want, monkeypatch):
     assert _digest(*case, monkeypatch) == want
+
+
+def _dense(q, k, v, causal, mask, keep=None):
+    """Dense float32 attention on [B, S, H, D]; ``keep`` the scaled dropout
+    multiplier [B, H, Sq, Sk] on the probabilities."""
+    qt, kt, vt = (jnp.swapaxes(x.astype(jnp.float32), 1, 2)
+                  for x in (q, k, v))
+    group = qt.shape[1] // kt.shape[1]
+    kt, vt = jnp.repeat(kt, group, 1), jnp.repeat(vt, group, 1)
+    s = jnp.einsum("bhqd,bhkd->bhqk", qt, kt) * q.shape[-1] ** -0.5
+    sq, sk = s.shape[-2:]
+    if causal:
+        s = jnp.where(jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq), s,
+                      -jnp.inf)
+    if mask is not None:
+        s = jnp.where(fa.dense_mask(mask), s, -jnp.inf)
+    p = jax.nn.softmax(s, axis=-1)
+    if keep is not None:
+        p = p * keep
+    return jnp.swapaxes(jnp.einsum("bhqk,bhkd->bhqd", p, vt), 1, 2)
+
+
+_ONEPASS_CASES = {
+    # sq, sk, query heads, KV heads, head size, causal, block, dtype, dropout
+    "unmasked_f32": (192, 192, 4, 4, 64, False, None, "float32", 0.0),
+    "causal_f32_gqa8": (192, 192, 8, 1, 64, True, None, "float32", 0.0),
+    "blockdiff_f32_gqa8": (192, 192, 8, 1, 128, False, 4, "float32", 0.0),
+    "causal_f32_ragged": (200, 200, 4, 2, 32, True, None, "float32", 0.0),
+    "blockdiff_f32_ragged": (200, 200, 2, 2, 64, False, 4, "float32", 0.0),
+    "causal_f32_sq_lt_sk": (100, 200, 4, 2, 64, True, None, "float32", 0.0),
+    "unmasked_f32_sq_gt_sk": (256, 128, 2, 2, 64, False, None, "float32", 0.0),
+    "unmasked_f32_cross": (128, 200, 4, 4, 64, False, None, "float32", 0.0),
+    "unmasked_bf16": (192, 192, 4, 4, 64, False, None, "bfloat16", 0.0),
+    "causal_bf16_gqa8": (192, 192, 8, 1, 128, True, None, "bfloat16", 0.0),
+    "blockdiff_bf16_gqa8": (192, 192, 8, 1, 128, False, 4, "bfloat16", 0.0),
+    "causal_f32_dropout": (192, 192, 4, 2, 64, True, None, "float32", 0.3),
+    "unmasked_bf16_ragged_dropout": (200, 200, 4, 4, 64, False, None,
+                                     "bfloat16", 0.1),
+}
+
+
+@pytest.mark.parametrize("case", list(_ONEPASS_CASES))
+def test_one_pass_backward_is_the_pair_bit_for_bit(case, monkeypatch):
+    """3-4 tiles of 64 a side: dq, dk, dv of ``flash_attention_bwd_onepass``
+    are the bits of the dK/dV + dQ pair (every sum in the same order) and
+    stand within the file's tolerance of the dense composite's; the dropout
+    cases draw the keep mask the kernels get (the array form, which
+    interpret mode takes), and the kernel counts ONE grid's tiles."""
+    sq, sk, h, hk, d, causal, block, dtype, dropout_p = _ONEPASS_CASES[case]
+    monkeypatch.setattr(fa, "_block_sizes", lambda sq, sk, d=64: (64, 64))
+    rng = np.random.default_rng(5)
+    q, k, v = (jnp.asarray(rng.standard_normal((2, s, n, d)), dtype)
+               for s, n in ((sq, h), (sk, hk), (sk, hk)))
+    w = jnp.asarray(rng.standard_normal((2, sq, h, d)), jnp.float32)
+    mask = block and fa.block_diffusion_mask(sq // 2, block)
+    seed = jnp.int32(9)
+
+    def kernel(q, k, v):
+        return fa.flash_attention(q, k, v, causal=causal, mask=mask,
+                                  dropout_p=dropout_p, dropout_seed=seed)
+
+    def grads(f):
+        return jax.grad(lambda *a: jnp.sum(f(*a).astype(jnp.float32) * w),
+                        (0, 1, 2))(q, k, v)
+
+    names = [f"paddle_flash_bwd_{n}_traces_total" for n in ("onepass",
+                                                            "split")]
+    before = [telemetry.runtime_counter(n) for n in names] + list(_counts())
+    got = grads(kernel)
+    moved = [a - b for a, b in zip(
+        [telemetry.runtime_counter(n) for n in names] + list(_counts()),
+        before)]
+    nq, nk = -(-sq // 64), -(-sk // 64)
+    # the forward's grid and the backward's ONE grid, batch 2
+    assert moved[:2] == [1, 0] and moved[3] == 2 * 2 * h * nq * nk
+    monkeypatch.setattr(fa, "_bwd_onepass", fa._bwd_pair)
+    before = _counts()[1]
+    two = grads(kernel)
+    assert _counts()[1] - before == 3 * 2 * h * nq * nk   # forward + the pair
+    for a, b in zip(got, two):
+        assert a.dtype == jnp.dtype(dtype)
+        np.testing.assert_array_equal(np.asarray(a.astype(jnp.float32)),
+                                      np.asarray(b.astype(jnp.float32)))
+    keep = None
+    if dropout_p:
+        keep = fa._dropout_mask(seed, (2, h, nq * 64, nk * 64),
+                                dropout_p)[:, :, :sq, :sk]
+    want = grads(lambda q, k, v: _dense(q, k, v, causal, mask, keep))
+    for a, b in zip(got, want):
+        if dtype == "float32":
+            np.testing.assert_allclose(a, b, atol=5e-5)
+        else:
+            a, b = (np.asarray(x.astype(jnp.float32)) for x in (a, b))
+            assert np.linalg.norm(a - b) < 0.01 * np.linalg.norm(b)
+
+
+def test_one_pass_backward_traces_the_in_kernel_dropout(monkeypatch):
+    """On the chip the keep mask is drawn inside the kernels from ``(seed,
+    b, h, q tile, k tile)``; the hardware generator has no CPU form, so what
+    can be held here is that the one-pass kernel traces with it, one seed
+    word in SMEM, the tile's pair as the forward folds it."""
+    monkeypatch.setattr(pallas, "_interpret", lambda: False)
+    q = jax.ShapeDtypeStruct((2, 4096, 12, 64), jnp.bfloat16)
+    jaxpr = str(jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(
+        fa.flash_attention(q, k, v, causal=True, dropout_p=0.1).astype(
+            jnp.float32)), (0, 1, 2)))(q, q, q))
+    assert re.findall(r"flash_attention_\w+", jaxpr) == [
+        "flash_attention_fwd", "flash_attention_bwd_onepass"]
+    # one draw a kernel: the forward's and the backward's
+    assert jaxpr.count("prng_seed") == 2 == jaxpr.count("prng_random_bits")
 
 
 def test_what_the_kernel_refuses():

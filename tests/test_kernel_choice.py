@@ -328,17 +328,69 @@ def test_delta_rule_dispatch_moves_its_counter(on, dk, chunk, mesh, kernel,
 
 
 # -------------------------------------------- the backward by block count
-@pytest.mark.parametrize("seq,kernels", [
-    (1024, {"flash_attention_fwd", "flash_attention_bwd_fused"}),
-    (2048, {"flash_attention_fwd", "flash_attention_bwd_dkv",
-            "flash_attention_bwd_dq"}),
-], ids=["one_block", "two_blocks"])
-def test_backward_by_block_count(seq, kernels):
+@pytest.mark.parametrize("seq,kernels,moved", [
+    (1024, {"flash_attention_fwd", "flash_attention_bwd_fused"}, [0, 0]),
+    (2048, {"flash_attention_fwd", "flash_attention_bwd_onepass"}, [1, 0]),
+    # 32,768 positions of head 64 (a row of 128 lanes in VMEM) are the
+    # budget, ``_ONEPASS_DQ_BYTES``: the plane's float32 dQ at its edge...
+    (32768, {"flash_attention_fwd", "flash_attention_bwd_onepass"}, [1, 0]),
+    # ... and past it, where the pair remains
+    (32768 + 1024, {"flash_attention_fwd", "flash_attention_bwd_dkv",
+                    "flash_attention_bwd_dq"}, [0, 1]),
+], ids=["one_block", "two_blocks", "at_the_vmem_budget",
+        "past_the_vmem_budget"])
+def test_backward_by_block_count(seq, kernels, moved):
+    """One tile a plane: the fused kernel. Several: ONE kernel while the
+    plane's dQ fits the stated VMEM budget, the dK/dV + dQ pair past it;
+    from the shapes alone, and the two counters say which."""
+    from paddle_tpu.inference.telemetry import runtime_counter
+    names = [f"paddle_flash_bwd_{n}_traces_total" for n in ("onepass",
+                                                            "split")]
+    before = [runtime_counter(n) for n in names]
     q = jax.ShapeDtypeStruct((1, seq, 1, 64), jnp.float32)
     jaxpr = jax.make_jaxpr(jax.grad(
         lambda q, k, v: jnp.sum(fa.flash_attention(q, k, v, causal=True)),
         (0, 1, 2)))(q, q, q)
     assert set(re.findall(r"flash_attention_\w+", str(jaxpr))) == kernels
+    assert [runtime_counter(n) - b for n, b in zip(names, before)] == moved
+    assert fa._ONEPASS_DQ_BYTES == 32768 * 128 * 4
+
+
+def test_flash_bwd_onepass_pct_reads_the_two_counters(monkeypatch):
+    """``benchmark/layer_metrics/flash_bwd_onepass_pct.py``: nothing where
+    neither counter moved (one tile a plane; a program from before them, as
+    the parent commit is), else the share of the multi-tile backward traces
+    that took the one kernel; both are in ``runtime_prometheus()``; the
+    manifest names the reader once for each cell that runs the kernel."""
+    import json
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.syspath_prepend(repo)
+    from benchmark import harness
+    from paddle_tpu.inference import telemetry
+    reader = harness.load_part("layer_metrics", "flash_bwd_onepass_pct")
+    monkeypatch.setattr(telemetry, "_runtime_counters", {})
+    assert reader.read({}) is None
+    def trace(seq):     # a shape of its own each: JAX keeps a trace it made
+        q = jax.ShapeDtypeStruct((1, seq, 1, 64), jnp.float32)
+        jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(
+            fa.flash_attention(q, k, v, causal=True)), (0, 1, 2)))(q, q, q)
+    trace(2048)
+    assert reader.read({}) == 100.0
+    monkeypatch.setattr(fa, "_ONEPASS_DQ_BYTES", 0)
+    trace(3072)
+    trace(5120)
+    assert reader.read({}) == pytest.approx(100.0 / 3)
+    lines = telemetry.runtime_prometheus()
+    assert "paddle_flash_bwd_onepass_traces_total 1" in lines
+    assert "paddle_flash_bwd_split_traces_total 2" in lines
+    with open(os.path.join(repo, "BENCHMARK.json")) as f:
+        entries = [m for m in json.load(f)["per_layer"]
+                   if m["name"].startswith("flash_bwd_onepass_pct.")]
+    assert [(m["name"].split(".")[1], m["workloads"], m["layer"],
+             m["moves"]) for m in entries] == [
+        ("sdar", ["sdar_30b.blockdiff_8k"], "kernels", "train_tok_s"),
+        ("qwen3next", ["qwen3next_80b.pretrain_8k"], "kernels",
+         "train_tok_s")]
 
 
 # ------------------------- the composites that took over, against float64

@@ -471,5 +471,5 @@ def test_every_pallas_call_is_named(site):
 
 def test_pallas_call_names_are_unique():
     names = [name for _, _, name in _SITES]
-    assert len(names) == 19
+    assert len(names) == 20
     assert len(set(names)) == len(names), sorted(names)
