@@ -1,5 +1,5 @@
-"""Model zoo: the five BASELINE config families, Qwen3-Next, SDAR and Kimi
-Linear."""
+"""Model zoo: the five BASELINE config families, Qwen3-Next, SDAR, Kimi
+Linear and JoyAI-LLM-Flash."""
 from . import gpt
 from . import bert
 from . import llama
@@ -8,3 +8,4 @@ from . import moe
 from . import qwen3_next
 from . import sdar
 from . import kimi_linear
+from . import joyai_flash

@@ -41,6 +41,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..core.rng import next_key
 from ..incubate.distributed.models.moe.dropless import DroplessMoELayer
@@ -63,12 +64,15 @@ _F32 = jnp.float32
 class KimiLinearConfig:
     """The published keys under the published names' meaning (the
     ``linear_attn_config`` group's as ``kda_*``); the defaults are the
-    48B-A3B model's. ``experts_held`` (None: all), ``chunk_size`` and
+    48B-A3B model's (``q_lora_rank`` None and ``rope_theta`` None: no query
+    compression and no rotary, ``mla_use_nope``; ``KimiMLA`` says what a
+    number means). ``experts_held`` (None: all), ``chunk_size`` and
     ``recompute`` are this program's."""
 
     def __init__(self, vocab_size=163840, hidden_size=2304, num_layers=27,
                  num_heads=32, kv_lora_rank=512, qk_nope_head_dim=128,
-                 qk_rope_head_dim=64, v_head_dim=128,
+                 qk_rope_head_dim=64, v_head_dim=128, q_lora_rank=None,
+                 rope_theta=None,
                  full_attn_layers=(4, 8, 12, 16, 20, 24, 27),
                  kda_num_heads=32, kda_head_dim=128,
                  short_conv_kernel_size=4, intermediate_size=9216,
@@ -85,6 +89,8 @@ class KimiLinearConfig:
         self.qk_nope_head_dim = qk_nope_head_dim
         self.qk_rope_head_dim = qk_rope_head_dim
         self.v_head_dim = v_head_dim
+        self.q_lora_rank = q_lora_rank
+        self.rope_theta = rope_theta
         self.full_attn_layers = tuple(int(i) for i in full_attn_layers)
         self.kda_num_heads = kda_num_heads
         self.kda_head_dim = kda_head_dim
@@ -193,31 +199,82 @@ class KimiDeltaAttention(Layer):
         return self.o_proj(y)
 
 
+def _rope_interleaved(a, theta):
+    """The rotation of the last axis (``d`` channels) of ``a`` [B, T, ...,
+    d] by position, pairs ``(2j, 2j + 1)`` turned by ``pos * theta^(-2j /
+    d)`` in place; ``pos`` is the index along T. The angles, their cosines
+    and sines and the products in float32; the result in ``a``'s dtype. Each
+    channel meets its partner through shifts of the last axis by one (no
+    axis of 2)."""
+    d, s = a.shape[-1], a.shape[1]
+    inv = jnp.asarray(theta ** (-np.arange(0, d, 2) / d), _F32)
+    ang = jnp.arange(s, dtype=_F32)[:, None] * jnp.repeat(inv, 2)
+    ang = ang.reshape((s,) + (1,) * (a.ndim - 3) + (d,))
+    even = jnp.arange(d) % 2 == 0
+    x = a.astype(_F32)
+    partner = jnp.where(even, jnp.roll(x, -1, -1), jnp.roll(x, 1, -1))
+    sin = jnp.where(even, -jnp.sin(ang), jnp.sin(ang))
+    return (x * jnp.cos(ang) + partner * sin).astype(a.dtype)
+
+
 class KimiMLA(Layer):
-    def __init__(self, c: KimiLinearConfig):
+    """Latent attention, with or without query compression and positions.
+    ``q_lora_rank`` None: ``q = x W_q``; a rank: ``q = RMSNorm(x W_qa)
+    W_qb``. ``rope_theta`` None: no positions (Kimi's ``mla_use_nope``); a
+    base: the last ``qk_rope_head_dim`` channels of every query head and the
+    one ``k_r`` the heads share turn by ``_rope_interleaved`` (DeepSeek-V3's
+    decoupled rotary, ``rope_interleave``), the softmax scale staying
+    ``1 / sqrt(qk_nope + qk_rope)``."""
+
+    def __init__(self, c):
         super().__init__()
         self.h, self.r = c.num_heads, c.kv_lora_rank
         self.dn, self.dr, self.dv = (c.qk_nope_head_dim, c.qk_rope_head_dim,
                                      c.v_head_dim)
-        self.q_proj = _linear(c, c.hidden_size, self.h * (self.dn + self.dr))
+        self.q_rank, self.theta = c.q_lora_rank, c.rope_theta
+        if self.q_rank is None:
+            self.q_proj = _linear(c, c.hidden_size,
+                                  self.h * (self.dn + self.dr))
+        else:
+            self.q_a_proj = _linear(c, c.hidden_size, self.q_rank)
+            self.q_a_layernorm = RMSNorm(self.q_rank, c.rms_eps)
+            self.q_b_proj = _linear(c, self.q_rank,
+                                    self.h * (self.dn + self.dr))
         self.kv_a_proj_with_mqa = _linear(c, c.hidden_size, self.r + self.dr)
         self.kv_a_layernorm = RMSNorm(self.r, c.rms_eps)
         self.kv_b_proj = _linear(c, self.r, self.h * (self.dn + self.dv))
         self.o_proj = _linear(c, self.h * self.dv, c.hidden_size)
 
+    def _query(self, x):
+        if self.q_rank is None:
+            return self.q_proj(x)
+        with jax.named_scope("mla.q_compress"):
+            return self.q_b_proj(self.q_a_layernorm(self.q_a_proj(x)))
+
     def forward(self, x):
         b, s = x.shape[0], x.shape[1]
         h, r, dn, dr, dv = self.h, self.r, self.dn, self.dr, self.dv
+        theta = self.theta
         with jax.named_scope("attn.mla"):
-            q = reshape(self.q_proj(x), [b, s, h, dn + dr])
+            q = reshape(self._query(x), [b, s, h, dn + dr])
+            if theta is not None:
+                with jax.named_scope("mla.rope"):
+                    q = apply_op(lambda a: jnp.concatenate(
+                        [a[..., :dn], _rope_interleaved(a[..., dn:],
+                                                        theta)], -1), q)
             with jax.named_scope("mla.kv_compress"):
                 ckr = self.kv_a_proj_with_mqa(x)
                 kv = reshape(self.kv_b_proj(self.kv_a_layernorm(
                     ckr[:, :, :r])), [b, s, h, dn + dv])
+                k_r = ckr[:, :, r:]
+                if theta is not None:
+                    with jax.named_scope("mla.rope"):
+                        k_r = apply_op(lambda a: _rope_interleaved(
+                            a, theta), k_r)
                 k = apply_op(lambda a, kr: jnp.concatenate(
                     [a[..., :dn], jnp.broadcast_to(
                         kr[:, :, None, :], (b, s, h, dr))], -1),
-                    kv, ckr[:, :, r:])
+                    kv, k_r)
             out = F.scaled_dot_product_attention(q, k, kv[:, :, :, dn:],
                                                  is_causal=True)
             return self.o_proj(reshape(out, [b, s, h * dv]))

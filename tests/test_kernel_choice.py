@@ -470,7 +470,9 @@ def test_flash_bwd_onepass_pct_reads_the_two_counters(monkeypatch):
         ("sdar", ["sdar_30b.blockdiff_8k"], "kernels", "train_tok_s"),
         ("qwen3next", ["qwen3next_80b.pretrain_8k"], "kernels",
          "train_tok_s"),
-        ("kimi", ["kimi_linear_48b.pretrain_16k"], "kernels", "train_tok_s")]
+        ("kimi", ["kimi_linear_48b.pretrain_16k"], "kernels", "train_tok_s"),
+        ("joyai", ["joyai_flash_48b.mtp_pretrain_8k"], "kernels",
+         "train_tok_s")]
 
 
 # ------------------------- the composites that took over, against float64
